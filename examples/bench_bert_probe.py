@@ -7,8 +7,10 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import bench
+from paddle_tpu.utils import compile_cache
 
 if __name__ == "__main__":
+    compile_cache.configure()
     p = argparse.ArgumentParser()
     p.add_argument("--batch", type=int, default=32)
     p.add_argument("--k", type=int, default=12)

@@ -13,9 +13,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import bench
 from paddle_tpu.models import GPTConfig
+from paddle_tpu.utils import compile_cache
 
 
 def main():
+    compile_cache.configure()
     specs = sys.argv[1:] or ["12,0,2048,1024"]
     for spec in specs:
         parts = spec.split(",")

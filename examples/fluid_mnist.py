@@ -19,6 +19,7 @@ import numpy as np
 
 import paddle_tpu as paddle
 import paddle_tpu.fluid as fluid
+from paddle_tpu.utils import compile_cache
 
 
 def convolutional_neural_network(img, label):
@@ -36,6 +37,7 @@ def convolutional_neural_network(img, label):
 
 
 def main():
+    compile_cache.configure()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=30)
     p.add_argument("--batch", type=int, default=64)

@@ -18,6 +18,7 @@ import numpy as np
 
 import paddle_tpu as paddle
 import paddle_tpu.fluid as fluid
+from paddle_tpu.utils import compile_cache
 
 EMBED_SIZE = 32
 HIDDEN_SIZE = 64
@@ -65,6 +66,7 @@ def synthetic_corpus_reader(seed=0, n_sent=400):
 
 
 def main():
+    compile_cache.configure()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=60)
     p.add_argument("--batch", type=int, default=64)

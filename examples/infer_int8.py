@@ -15,10 +15,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu import inference as paddle_infer  # noqa: E402
 from paddle_tpu import jit, nn, optimizer as opt  # noqa: E402
+from paddle_tpu.utils import compile_cache  # noqa: E402
 from paddle_tpu.incubate.quant import ImperativePTQ  # noqa: E402
 
 
 def main():
+    compile_cache.configure()
     paddle.seed(0)
     rng = np.random.RandomState(0)
     x = rng.randn(256, 16).astype("float32")

@@ -43,6 +43,9 @@ def main():
         BertConfig, BertForPretraining, BertPretrainingCriterion,
         ErnieConfig, ErnieForPretraining, ErniePretrainingCriterion,
     )
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     paddle.seed(args.seed)
     if args.model == "ernie":

@@ -166,6 +166,9 @@ def main():
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
     from paddle_tpu.serving import FaultPlan, ServingEngine
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=args.vocab, hidden_size=args.hidden,

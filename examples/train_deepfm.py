@@ -41,6 +41,9 @@ def main():
     from paddle_tpu.metric import Auc
     from paddle_tpu.models import (
         DeepFM, RecConfig, WideDeep, synthetic_click_batch)
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     paddle.seed(args.seed)
     cfg = RecConfig(

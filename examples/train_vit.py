@@ -32,6 +32,9 @@ def main():
     import paddle_tpu as paddle
     from paddle_tpu import nn, optimizer as opt
     from paddle_tpu.vision import models as vm
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     paddle.seed(args.seed)
     ctor = getattr(vm, args.arch)

@@ -29,7 +29,8 @@ from typing import Iterable, Iterator, List, Set, Tuple
 
 __all__ = [
     "iter_eqns", "collect_primitives", "count_primitive",
-    "count_primitives", "assert_no_primitive", "assert_no_transpose",
+    "count_primitives", "pallas_kernels", "assert_no_primitive",
+    "assert_no_transpose",
     "assert_jaxpr_identical", "find_f64", "assert_no_f64",
     "find_dtype_upcasts", "DEFAULT_STOP_INSIDE",
 ]
@@ -92,6 +93,18 @@ def count_primitives(jaxpr,
     shifts."""
     return Counter(eqn.primitive.name
                    for eqn in iter_eqns(jaxpr, stop_inside))
+
+
+def pallas_kernels(jaxpr) -> Counter:
+    """Pallas kernel dispatches in the program, counted by
+    ``(kernel name, interpreted)`` — the readout of which path a dispatcher
+    (``sdpa``, ``w8a8_apply``, ``ring_attention``) traced: a program that
+    took a jnp reference holds no entry for that kernel, and one that ran
+    through the Pallas interpreter shows ``interpreted=True``.  Descends
+    into shard_map/scan/remat bodies like every walker here."""
+    return Counter(
+        (eqn.params["name"], bool(eqn.params["interpret"]))
+        for eqn in iter_eqns(jaxpr) if eqn.primitive.name == "pallas_call")
 
 
 def assert_no_primitive(jaxpr, name: str, context: str = "",

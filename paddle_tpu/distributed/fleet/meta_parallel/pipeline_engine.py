@@ -98,23 +98,12 @@ def spmd_pipeline(stage_fn: Callable, num_stages: int, axis: str = "pp"):
         outputs = lax.psum(jnp.where(stage == S - 1, outputs, jnp.zeros_like(outputs)), axis)
         return outputs
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     def apply(stacked_params, microbatches):
         param_specs = jax.tree_util.tree_map(lambda _: P(axis), stacked_params)
-        try:
-            fn = shard_map(
-                per_device, mesh=mesh, in_specs=(param_specs, P()), out_specs=P(),
-                check_vma=False,
-            )
-        except TypeError:
-            fn = shard_map(
-                per_device, mesh=mesh, in_specs=(param_specs, P()), out_specs=P(),
-                check_rep=False,
-            )
+        fn = jax.shard_map(
+            per_device, mesh=mesh, in_specs=(param_specs, P()), out_specs=P(),
+            check_vma=False,
+        )
         return fn(stacked_params, microbatches)
 
     return apply

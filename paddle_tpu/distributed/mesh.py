@@ -23,6 +23,12 @@ _MESH: Optional[Mesh] = None
 # matching the reference's HybridCommunicateGroup order (topology.py:36).
 HYBRID_AXES = ("dp", "pp", "sharding", "mp")
 
+# the axes a global batch is split over: ZeRO's sharding group IS a
+# data-parallel group (each sharding rank consumes different data; only
+# optimizer state/grads/params are partitioned — reference
+# fleet/meta_optimizers/sharding_optimizer.py semantics)
+BATCH_AXES = ("dp", "sharding")
+
 
 def set_mesh(mesh: Mesh) -> Mesh:
     global _MESH
@@ -70,13 +76,8 @@ def replicate(x):
     return jax.device_put(x, NamedSharding(mesh, P()))
 
 
-def shard_batch(x, axis_names: Tuple[str, ...] = ("dp", "sharding")):
-    """Shard the leading (batch) dim over the given mesh axes.
-
-    'sharding' is included by default: ZeRO's sharding group IS a
-    data-parallel group (each sharding rank consumes different data; only
-    optimizer state/grads/params are partitioned — reference
-    fleet/meta_optimizers/sharding_optimizer.py semantics)."""
+def shard_batch(x, axis_names: Tuple[str, ...] = BATCH_AXES):
+    """Shard the leading (batch) dim over the given mesh axes."""
     mesh = ensure_default_mesh()
     names = tuple(a for a in axis_names if a in mesh.axis_names and mesh.shape[a] > 1)
     if not names:

@@ -80,11 +80,7 @@ def init_parallel_env() -> ParallelEnv:
         plats = (jax.config.jax_platforms or os.environ.get(
             "JAX_PLATFORMS", "")).split(",")
         if plats and plats[0].strip() == "cpu":
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:
-                pass  # older/newer jax without the option: keep defaults
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
         port = os.environ.get("MASTER_PORT", "8476")
         jax.distributed.initialize(

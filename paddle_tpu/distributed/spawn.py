@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import multiprocessing as mp
 import os
+import subprocess
+import sys
 import traceback
 from typing import Optional, Sequence
 
@@ -71,17 +73,25 @@ def _worker(func, args, env, error_queue):
         raise
 
 
+def _local_device_count() -> int:
+    """Count this host's devices in a short-lived child.  The parent must
+    stay off JAX: a process that has initialised the backend holds the
+    chip, and the ranks it then starts could not open it."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.local_device_count())"],
+        check=True, capture_output=True, text=True)
+    return int(out.stdout.strip().splitlines()[-1])
+
+
 def spawn(func, args: Sequence = (), nprocs: int = -1, join: bool = True,
           daemon: bool = False, **options):
     """Spawn ``nprocs`` processes running ``func(*args)`` with the PADDLE_*
-    env protocol installed (reference spawn.py semantics)."""
+    env protocol installed (reference spawn.py semantics).  Proven on CPU
+    ranks only: nothing here limits which chips a rank opens, so N ranks
+    on a multi-chip host would each reach for every chip."""
     if nprocs == -1:
-        try:
-            import jax
-
-            nprocs = max(jax.local_device_count(), 1)
-        except Exception:
-            nprocs = 1
+        nprocs = max(_local_device_count(), 1)
     cluster = Cluster(ips=["127.0.0.1"], nproc_per_node=nprocs,
                       master="127.0.0.1",
                       master_port=int(options.get("master_port")

@@ -109,22 +109,10 @@ def set_inline_kernels(flag: bool) -> bool:
     return old
 
 
-_HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-
-
-def _in_manual_mesh_context(ins, rng) -> bool:
-    """True inside a shard_map manual region (axis_types carry Manual).
-
-    Older jax without get_abstract_mesh: fall back to treating ANY traced
-    input as manual-context — conservative (loses the inner-jit fusion win
-    under plain jit there) but never reuses an inner-jit trace across
-    Manual/Auto contexts."""
-    if _HAS_ABSTRACT_MESH:
-        m = jax.sharding.get_abstract_mesh()
-        return any("Manual" in str(t) for t in getattr(m, "axis_types", ()))
-    return (any(isinstance(a, jax.core.Tracer)
-                for vs in ins.values() for a in vs)
-            or isinstance(rng, jax.core.Tracer))
+def in_manual_mesh_context() -> bool:
+    """True inside a shard_map manual region (axis_types carry Manual)."""
+    m = jax.sharding.get_abstract_mesh()
+    return any("Manual" in str(t) for t in m.axis_types)
 
 
 def run_eager_kernel(op_type: str, ins: Dict[str, List[Any]], attrs: Dict[str, Any], rng=None):
@@ -138,7 +126,7 @@ def run_eager_kernel(op_type: str, ins: Dict[str, List[Any]], attrs: Dict[str, A
     # Under plain jit/grad the inner-jit wrapper is KEPT deliberately: the
     # nested pjit boundaries guide XLA's fusion grouping — measured +4.4 MFU
     # points on the GPT bench vs inlining every op into one flat jaxpr.
-    if _INLINE_KERNELS or _in_manual_mesh_context(ins, rng):
+    if _INLINE_KERNELS or in_manual_mesh_context():
         return registry.run_kernel(op_def, ins, attrs, rng=rng)
     try:
         key = (op_type, registry._freeze(attrs))

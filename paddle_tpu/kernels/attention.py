@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..ops.registry import register_op
 
@@ -69,10 +70,47 @@ def _sdpa_reference(q, k, v, mask=None, scale=None, is_causal=False,
     return jnp.einsum("...qk,...kd->...qd", probs, v)
 
 
+def _flash_per_shard(mesh, q, k, v, layout, **kw):
+    """Run the flash kernel on each device's LOCAL shard of a GSPMD mesh.
+
+    GSPMD cannot partition a Pallas custom call: left bare inside a
+    partitioned jit it all-gathers q/k/v and every device computes every
+    head of every batch row.  Attention is independent per (batch row,
+    head), so ``shard_map`` over the axes that shard batch
+    (``mesh.BATCH_AXES``) and heads (``'mp'``) is exact.  An axis whose
+    size does not divide its dim stays out of the spec (that dim is then
+    gathered, as before)."""
+    from . import flash
+    from ..distributed import mesh as mesh_mod
+
+    def call(ql, kl, vl):
+        return flash.flash_attention(ql, kl, vl, layout=layout, **kw)
+
+    if mesh is None or q.ndim != 4:
+        return call(q, k, v)
+    b_dim, h_dim = {"bnsd": (0, 1), "bsnd": (0, 2), "sbnd": (1, 2)}[layout]
+    batch = tuple(a for a in mesh_mod.BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    if q.shape[b_dim] % math.prod(mesh.shape[a] for a in batch):
+        batch = ()
+    mp = mesh.shape.get("mp", 1)
+    heads = "mp" if mp > 1 and not (
+        q.shape[h_dim] % mp or k.shape[h_dim] % mp) else None
+    spec = [None] * 4
+    spec[b_dim] = batch or None
+    spec[h_dim] = heads
+    spec = P(*spec)
+    return jax.shard_map(call, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def sdpa(q, k, v, mask=None, scale=None, is_causal=False, dropout_p=0.0,
-         rng=None, layout="bnsd", window=None):
+         rng=None, layout="bnsd", window=None, mesh=None):
     """Dispatch to the Pallas flash kernel on TPU when profitable, else the
-    XLA-fused reference (dropout always takes the reference path).
+    XLA-fused reference (dropout always takes the reference path).  Which
+    one a program took is readable from its jaxpr
+    (``analysis.jaxpr_audit.pallas_kernels``).  ``mesh``: the multi-device
+    GSPMD mesh the caller is being partitioned over, if any — the kernel
+    then runs per shard (:func:`_flash_per_shard`).
 
     ``layout="bsnd"`` ([b, s, nh, d], the model-natural layout after a QKV
     projection) feeds the seq-major kernel specs directly — no materialized
@@ -89,8 +127,8 @@ def sdpa(q, k, v, mask=None, scale=None, is_causal=False, dropout_p=0.0,
             and flash.available() and q.shape[s_axis] >= 512
             and flash.supported(q, k, mask=mask, dropout_p=dropout_p,
                                 layout=layout)):
-        return flash.flash_attention(q, k, v, causal=is_causal, scale=scale,
-                                     layout=layout, window=window)
+        return _flash_per_shard(mesh, q, k, v, layout, causal=is_causal,
+                                scale=scale, window=window)
     if layout in ("bsnd", "sbnd"):
         if q.ndim != 4:
             raise ValueError(
@@ -122,6 +160,7 @@ def sdpa_kernel(ins, attrs, rng=None):
         dropout_p=p, rng=rng,
         layout=attrs.get("layout", "bnsd"),
         window=attrs.get("window"),
+        mesh=attrs.get("mesh"),
     )
     return {"Out": out}
 
@@ -129,16 +168,22 @@ def sdpa_kernel(ins, attrs, rng=None):
 def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.0,
                                  is_causal=False, training=True,
                                  layout="bnsd", window=None):
+    from ..distributed import mesh as mesh_mod
+    from ..dygraph import tracer
+    from ..framework import program as fw
     from ..ops.dispatch import dispatch, single
 
     ins = {"Q": [query], "K": [key], "V": [value]}
     if attn_mask is not None:
         ins["Mask"] = [attn_mask]
-    return single(
-        dispatch(
-            "scaled_dot_product_attention",
-            ins,
-            {"dropout_p": dropout_p, "is_causal": is_causal,
-             "is_test": not training, "layout": layout, "window": window},
-        )
-    )
+    attrs = {"dropout_p": dropout_p, "is_causal": is_causal,
+             "is_test": not training, "layout": layout, "window": window}
+    # The mesh rides in the attrs, not in a trace-time read of the global:
+    # attrs key the per-op jit cache, so a program traced under one mesh is
+    # never replayed under another.  Inside a shard_map region the arrays
+    # are already per-device.
+    mesh = mesh_mod.get_mesh()
+    if (mesh is not None and mesh.devices.size > 1 and fw.in_dygraph_mode()
+            and not tracer.in_manual_mesh_context()):
+        attrs["mesh"] = mesh
+    return single(dispatch("scaled_dot_product_attention", ins, attrs))

@@ -2,11 +2,12 @@
 
 Role parity: the reference's TensorRT int8 GEMM engines
 (``inference/tensorrt/trt_int8_calibrator.h``) and the fused dequant
-epilogues of its int8 CUDA kernels.  BENCH_r05 measured the plain
-``quantized_matmul`` int8 path at 1.50x (4096^3) / 1.65x (8192^3) over
-bf16 on the v5e MXU; this kernel is what lets the GPT flagship's linears
-ride that headroom (GPTConfig.int8) without paying a separate
-quantize-pass over the activations in HBM.
+epilogues of its int8 CUDA kernels.  The plain ``quantized_matmul`` int8
+path read 1.50x (4096^3) / 1.65x (8192^3) over bf16 on the v5e MXU
+(2026-07-31, jax 0.4.37, retired transport; not re-measured); this kernel
+is what lets the GPT flagship's linears ride that headroom
+(GPTConfig.int8) without paying a separate quantize-pass over the
+activations in HBM.
 
 Design (pallas_guide.md):
   * grid = (M blocks, N blocks); each program holds one [bm, K] activation
@@ -112,6 +113,7 @@ def w8a8_gemm(x2, wq, ws, *, block_m: int | None = None,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             interpret=interpret,
+            name="w8a8_gemm",
         )(x2, wq, ws2)
     return out
 

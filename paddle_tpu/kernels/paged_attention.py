@@ -141,15 +141,14 @@ def _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0].astype(jnp.float32)                       # (H, D)
     h, d = q.shape
     nkv = n_kv or h
-    if nkv != h:
-        g = h // nkv
-        qg = q.reshape(nkv, g, d)
-        s = jnp.einsum("ngd,nsd->ngs", qg, k,
-                       preferred_element_type=jnp.float32) * scale
-        s = s.reshape(h, page_size)                        # (H, ps)
-    else:
-        s = jnp.einsum("hd,hsd->hs", q, k,
-                       preferred_element_type=jnp.float32) * scale  # (H, ps)
+    # one contraction for MHA and GQA: query heads grouped over their K/V
+    # head (g == 1 under MHA).  Mosaic has no batched mat-VEC — the flat
+    # "hd,hsd->hs" form leaves the lhs without a free dimension and does
+    # not lower — so the group axis doubles as the matmul's M dimension.
+    g = h // nkv
+    s = jnp.einsum("ngd,nsd->ngs", q.reshape(nkv, g, d), k,
+                   preferred_element_type=jnp.float32) * scale
+    s = s.reshape(h, page_size)                            # (H, ps)
     base = p * jnp.int32(page_size)
     pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
     keep = pos < len_ref[b]
@@ -162,14 +161,8 @@ def _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     alpha = jnp.exp(m_prev - m_new)
     pexp = jnp.exp(s - m_new)
     l_new = l_ref[:, :1] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
-    if nkv != h:
-        g = h // nkv
-        pg = pexp.reshape(nkv, g, page_size)
-        upd = jnp.einsum("ngs,nsd->ngd", pg, v,
-                         preferred_element_type=jnp.float32).reshape(h, d)
-    else:
-        upd = jnp.einsum("hs,hsd->hd", pexp, v,
-                         preferred_element_type=jnp.float32)
+    upd = jnp.einsum("ngs,nsd->ngd", pexp.reshape(nkv, g, page_size), v,
+                     preferred_element_type=jnp.float32).reshape(h, d)
     acc_ref[...] = acc_ref[...] * alpha + upd
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -273,6 +266,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
             interpret=interpret,
+            name="paged_attention",
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
 
 
@@ -445,6 +439,7 @@ def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, tp, h, d), q.dtype),
             interpret=interpret,
+            name="paged_attention_mq",
         )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
     return out[:, :t]
 

@@ -227,6 +227,7 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((c, h, d), q.dtype),
             interpret=interpret,
+            name="paged_prefill",
         )(block_table.astype(jnp.int32),
           jnp.asarray(start, jnp.int32).reshape(1), *args)
 

@@ -173,17 +173,8 @@ def ring_attention(q, k, v, axis: str = "mp", causal: bool = False,
         out = (o / l[..., None]).astype(ql.dtype)
         return jnp.moveaxis(out, 2, 0) if seq_first else out
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
-    try:
-        fn = shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
-    except TypeError:  # pragma: no cover - older shard_map signature
-        fn = shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
     return fn(q, k, v)
 
 
@@ -210,8 +201,6 @@ def ring_flash_attention(q, k, v, axis: str = "mp", causal: bool = False,
     exact partial dq / dk / dv sums; dk/dv accumulators travel the ring
     WITH their K/V blocks and arrive home after the full cycle.
     """
-    import functools
-
     from . import flash as _fl
 
     mesh = mesh_mod.get_mesh()
@@ -242,9 +231,7 @@ def ring_flash_attention(q, k, v, axis: str = "mp", causal: bool = False,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        from .flash import _backend_is_tpu
-
-        interpret = not _backend_is_tpu()
+        interpret = not _fl._backend_is_tpu()
 
     spec = P(axis) if seq_first else P(None, None, axis, None)
     sharded = NamedSharding(mesh, spec)
@@ -338,15 +325,6 @@ def ring_flash_attention(q, k, v, axis: str = "mp", causal: bool = False,
         out = _pd(*(jnp.moveaxis(a, 0, 2) for a in (ql, kl, vl)))
         return jnp.moveaxis(out, 2, 0)
 
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
-    try:
-        fn = shard_map(_pd_entry, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(_pd_entry, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
-    except TypeError:  # pragma: no cover - older shard_map signature
-        fn = shard_map(_pd_entry, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
     return fn(q, k, v)

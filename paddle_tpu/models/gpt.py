@@ -674,7 +674,10 @@ def build_functional_train_step(model: GPTForPretraining, lr: float = 1e-4,
     opt_state = {
         "m": [_zeros_like_f32(p, sp) for p, sp in zip(flat_params, opt_specs)],
         "v": [_zeros_like_f32(p, sp) for p, sp in zip(flat_params, opt_specs)],
-        "t": jnp.zeros((), jnp.int32),
+        # on the mesh like every other leaf: a single-device counter comes
+        # back replicated, and the changed input sharding would compile the
+        # whole step a second time on the second call
+        "t": _mesh_put(jnp.zeros((), jnp.int32)),
     }
     if low_precision:
         masters = [p.astype(jnp.float32) for p in flat_params]

@@ -113,6 +113,48 @@ def test_sdpa_dispatch_uses_flash_seamlessly(monkeypatch):
     np.testing.assert_allclose(out.numpy(), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+def test_sdpa_flash_runs_per_shard_under_a_gspmd_mesh(monkeypatch):
+    """GSPMD cannot partition a Pallas custom call: bare inside a dp x mp
+    partitioned jit it would gather q/k/v and run every head of every batch
+    row on every device.  Under a multi-device mesh the dispatcher wraps the
+    kernel in shard_map over the batch and head axes, so the train step's
+    pallas_calls see the LOCAL (batch/dp * heads/mp, seq, d) shard, and the
+    loss equals the single-device one."""
+    from paddle_tpu.analysis.jaxpr_audit import iter_eqns, pallas_kernels
+    from paddle_tpu.distributed import mesh as mesh_mod
+    import paddle_tpu as paddle
+    from paddle_tpu.models import GPTForPretraining
+    from paddle_tpu.models.gpt import GPTConfig, build_functional_train_step
+
+    monkeypatch.setattr(flash, "available", lambda: True)
+    dims = dict(vocab_size=256, hidden_size=64, num_layers=1, num_heads=4,
+                max_seq_len=512, dropout=0.0)
+    rng = np.random.RandomState(0)
+    ids = rng.randint(0, 256, (4, 512)).astype("int32")
+    labels = rng.randint(0, 256, (4, 512)).astype("int64")
+
+    def first_loss(parallel):
+        paddle.seed(0)
+        model = GPTForPretraining(GPTConfig(**dims, use_parallel=parallel))
+        step, params, opt = build_functional_train_step(
+            model, lr=1e-3, remat=False, ce_chunk_rows=0)
+        feed = [mesh_mod.shard_batch(a) if parallel else a
+                for a in (ids, labels)]
+        jaxpr = step.trace(params, opt, *feed).jaxpr
+        assert sorted(n for n, _ in pallas_kernels(jaxpr)) == [
+            "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        shapes = {tuple(e.invars[0].aval.shape) for e in iter_eqns(jaxpr)
+                  if e.primitive.name == "pallas_call"}
+        return float(step(params, opt, *feed)[2]), shapes
+
+    ref, global_shapes = first_loss(False)
+    assert global_shapes == {(4 * 4, 512, 16)}
+    mesh_mod.build_hybrid_mesh(dp=2, mp=2)
+    loss, local_shapes = first_loss(True)
+    assert local_shapes == {(2 * 2, 512, 16)}
+    np.testing.assert_allclose(loss, ref, rtol=1e-5)
+
+
 def test_sdpa_dispatch_falls_back_on_unsupported_shape(monkeypatch):
     """Odd seq lens must take the reference path, not crash (supported() gate)."""
     import paddle_tpu as paddle
