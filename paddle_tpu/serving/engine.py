@@ -137,6 +137,43 @@ class FinishedRequest:
         return self.finish_reason in ("eos", "length")
 
 
+#: the four phases of a step: their spans also fill ``_phase_s``, which
+#: feeds ``stats["<phase>_s"]``, the registry and the TraceRecorder
+_PHASE_OF_SPAN = {f"engine.{ph}": ph
+                  for ph in ("admit", "prefill", "handoff", "decode")}
+#: decode dispatches by the prefill chunks dispatched before them in
+#: the same step (each chunk is device time in front of the decode)
+_DECODE_AFTER = ("decode_calls_after_0_chunks", "decode_calls_after_1_chunk",
+                 "decode_calls_after_2plus_chunks")
+
+
+class _Span:
+    """The engine's one span site: a ``jax.profiler.TraceAnnotation``
+    (the profiler's clock, next to the device trace; an inert TraceMe
+    outside a profiler session) that also keeps its ``perf_counter``
+    start ``t0`` and duration ``dur`` for the stats, and files a phase's
+    pair under ``phases``.  Closes under an exception like the
+    ``finally`` it replaces, so an aborted phase still records the time
+    it burned."""
+
+    __slots__ = ("_phases", "_phase", "_ann", "t0", "dur")
+
+    def __init__(self, phases: Dict[str, tuple], name: str, args: dict):
+        self._phases, self._phase = phases, _PHASE_OF_SPAN.get(name)
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "_Span":
+        self.t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        self.dur = time.perf_counter() - self.t0
+        if self._phase is not None:
+            self._phases[self._phase] = (self.t0, self.dur)
+
+
 def _next_pow2(n: int) -> int:
     p = 1
     while p < n:
@@ -424,6 +461,7 @@ class ServingEngine:
 
         # host mirrors of the decode step's device operands
         self._tokens_this_step = 0
+        self._chunks_this_step = 0
         self._phase_s: Dict[str, tuple] = {}
         self._slots: List[Optional[_Slot]] = [None] * max_slots
         self._tok = np.zeros((max_slots,), np.int32)
@@ -462,6 +500,16 @@ class ServingEngine:
                       # under double_buffer the overlap win shows up as
                       # this staying far below the dispatch wall time
                       "decode_sync_s": 0.0, "last_decode_sync_s": 0.0,
+                      # ... and on a completed prompt's first token
+                      "prefill_sync_s": 0.0,
+                      # the two waits inside TTFT, engine clock, once per
+                      # request: enqueue -> first admission -> first token
+                      "admissions": 0, "queue_wait_s": 0.0,
+                      "first_tokens": 0, "prefill_wait_s": 0.0,
+                      # the step mix (they sum to decode_calls) and the
+                      # context lengths the decode dispatches attended
+                      **dict.fromkeys(_DECODE_AFTER, 0),
+                      "decode_attended_tokens": 0,
                       # disaggregation traffic (r15)
                       "handoffs_out": 0, "handoffs_in": 0,
                       "handoff_bytes": 0, "handoff_faults": 0,
@@ -1255,15 +1303,15 @@ class ServingEngine:
         if req.n_preempted > 0:
             # the uncached remainder of the work prompt is recomputation
             self.stats["recompute_tokens"] += req.work_len - adm.matched
-        now = self._now()
-        if self.metrics is not None:
-            if req.t_admitted is None:        # first admission only: a
-                # re-admission after preemption is not queue wait
-                self._m["queue_wait"].observe(now - req.t_enqueue)
-            if adm.cow is not None:
-                self._m["cow"].inc()
-        if req.t_admitted is None:
-            req.t_admitted = now
+        if req.t_admitted is None:            # first admission only: a
+            # re-admission after preemption is not queue wait
+            req.t_admitted = self._now()
+            self.stats["admissions"] += 1
+            self.stats["queue_wait_s"] += req.t_admitted - req.t_enqueue
+            if self.metrics is not None:
+                self._m["queue_wait"].observe(req.t_admitted - req.t_enqueue)
+        if self.metrics is not None and adm.cow is not None:
+            self._m["cow"].inc()
         if self.flight is not None:
             self.flight.record("admit", self._step_idx, rid=req.rid,
                                slot=idx, matched=adm.matched,
@@ -1307,17 +1355,19 @@ class ServingEngine:
                     self.tracer.begin("prefill_chunk", self._pid_req,
                                       req.rid, {"start": st.prefilled,
                                                 "n": n})
-                t_c = time.perf_counter()
-                self.pool.buffers, tok = self._prefill_fn(
-                    self.params, self.pool.buffers, jnp.asarray(toks),
-                    jnp.int32(st.prefilled), jnp.int32(n),
-                    jnp.asarray(self._table[idx]), jnp.int32(n - 1),
-                    self._next_key())
+                with self._span("engine.prefill_dispatch", rid=req.rid,
+                                start=st.prefilled, n=n) as sp:
+                    self.pool.buffers, tok = self._prefill_fn(
+                        self.params, self.pool.buffers, jnp.asarray(toks),
+                        jnp.int32(st.prefilled), jnp.int32(n),
+                        jnp.asarray(self._table[idx]), jnp.int32(n - 1),
+                        self._next_key())
                 if self.metrics is not None:
-                    self._m["chunk_s"].observe(time.perf_counter() - t_c)
+                    self._m["chunk_s"].observe(sp.dur)
                 if self.tracer is not None:
                     self.tracer.end(self._pid_req, req.rid)
                 self.stats["prefill_calls"] += 1
+                self._chunks_this_step += 1
                 st.prefilled += n
                 budget -= n
                 self._tokens_this_step += n
@@ -1342,13 +1392,18 @@ class ServingEngine:
                     else:
                         nfull = st.base_len // self.page_size
                         self.pool.prefix.insert(work, st.pages[:nfull])
-                tok = int(tok)
+                with self._span("engine.first_token_sync",
+                                rid=req.rid) as sp:
+                    tok = int(tok)
+                self.stats["prefill_sync_s"] += sp.dur
                 st.tokens.append(tok)
                 self._emit_token(req, tok)
                 self._charge_service(req)
                 self.stats["tokens_generated"] += 1
                 now = self._now()
                 if req.t_first_token is None:
+                    self.stats["first_tokens"] += 1
+                    self.stats["prefill_wait_s"] += now - req.t_admitted
                     if self.metrics is not None:
                         self._m["ttft"].observe(now - req.t_enqueue)
                     if self.tracer is not None:
@@ -1649,11 +1704,15 @@ class ServingEngine:
         self._step_idx += 1
         if self.faults is not None:
             self.faults.begin_step(self._step_idx)
+        with self._span("engine.step", step=self._step_idx):
+            return self._step_in_span(t0)
+
+    def _step_in_span(self, t0: float) -> List[FinishedRequest]:
         finished: List[FinishedRequest] = list(self._pending)
         self._pending.clear()
-        self._tokens_this_step = 0
-        # phase -> (start perf-seconds, duration); filled by _run_step's
-        # finally blocks, so a fault aborting a phase still records the
+        self._tokens_this_step = self._chunks_this_step = 0
+        # phase -> (start perf-seconds, duration); filled as _run_step's
+        # phase spans close, so a fault aborting a phase still records the
         # time it burned before aborting.  Carried on the instance (not a
         # parameter) so _run_step keeps its r10 signature.
         phase = self._phase_s = {}
@@ -1753,10 +1812,11 @@ class ServingEngine:
             # OUT (and the gauges decay) even when nothing terminates
             self._slo.sync(self._now())
 
+    def _span(self, name: str, **args) -> _Span:
+        return _Span(self._phase_s, name, args)
+
     def _run_step(self, finished: List[FinishedRequest]) -> None:
-        phase = self._phase_s
-        t_a = time.perf_counter()
-        try:
+        with self._span("engine.admit"):
             self._expire(finished)
             # handoff ingests admit FIRST: their prefill is already paid
             # for, so they take priority over raw admissions for the
@@ -1765,33 +1825,28 @@ class ServingEngine:
             for adm in self.scheduler.schedule_step():
                 self._admit(adm)
             self._fault_point("admit")
-        finally:
-            phase["admit"] = (t_a, time.perf_counter() - t_a)
-        t_p = time.perf_counter()
-        try:
+        with self._span("engine.prefill"):
             self._prefill_chunks(finished)
             self._fault_point("prefill")
-        finally:
-            phase["prefill"] = (t_p, time.perf_counter() - t_p)
 
         if self.role == "prefill":
             # prefill workers never decode: every slot that completed its
             # prompt this step exports (request, block-table order pages,
             # payload + scales) and frees its slot — the router delivers
             # the records to a decode replica
-            t_h = time.perf_counter()
-            try:
+            with self._span("engine.handoff"):
                 self._handoff_started()
-            finally:
-                phase["handoff"] = (t_h, time.perf_counter() - t_h)
             return
 
-        t_d = time.perf_counter()
-        try:
+        with self._span("engine.decode"):
             self._decode_step(finished)
             self._fault_point("decode")
-        finally:
-            phase["decode"] = (t_d, time.perf_counter() - t_d)
+
+    def _note_decode_dispatch(self, run: List[int]) -> None:
+        """Counters of one decode (or verify) dispatch over slots ``run``."""
+        self.stats["decode_calls"] += 1
+        self.stats[_DECODE_AFTER[min(self._chunks_this_step, 2)]] += 1
+        self.stats["decode_attended_tokens"] += int(self._len[run].sum())
 
     def _decode_step(self, finished: List[FinishedRequest]) -> None:
         if self.spec_k:
@@ -1819,16 +1874,16 @@ class ServingEngine:
             remaining = np.zeros((self.max_slots,), np.int32)
             for idx in run:
                 remaining[idx] = self._slots[idx].request.remaining_new
-            t_c = time.perf_counter()
-            self.pool.buffers, toks_all = self._decode_fn(
-                self.params, self.pool.buffers, jnp.asarray(self._tok),
-                jnp.asarray(self._len), jnp.asarray(self._table),
-                jnp.asarray(remaining), self._next_key())
-            self.stats["decode_calls"] += 1
+            with self._span("engine.decode_dispatch", slots=len(run)) as sp:
+                self.pool.buffers, toks_all = self._decode_fn(
+                    self.params, self.pool.buffers, jnp.asarray(self._tok),
+                    jnp.asarray(self._len), jnp.asarray(self._table),
+                    jnp.asarray(remaining), self._next_key())
+            self._note_decode_dispatch(run)
             # stash the DISPATCHED call without syncing; slot objects ride
             # along so retirement can detect cancel/expire/slot-reuse
             self._inflight = ([(idx, self._slots[idx]) for idx in run],
-                              remaining, toks_all, t_c)
+                              remaining, toks_all, sp.t0)
             if not self.double_buffer:
                 self._retire_decode(finished)
 
@@ -1841,9 +1896,9 @@ class ServingEngine:
         (schedule-invariant per request) don't observe."""
         entries, remaining, toks_all, t_c = self._inflight
         self._inflight = None
-        t_s = time.perf_counter()
-        toks_all = np.asarray(jax.block_until_ready(toks_all))
-        sync_s = time.perf_counter() - t_s
+        with self._span("engine.decode_sync") as sp:
+            toks_all = np.asarray(jax.block_until_ready(toks_all))
+        sync_s = sp.dur
         self.stats["decode_sync_s"] += sync_s
         self.stats["last_decode_sync_s"] = sync_s
         if self.metrics is not None:
@@ -1934,16 +1989,18 @@ class ServingEngine:
         # buffers populated; the next step's proposal overwrites them
         # (check_invariants audits their bounds meanwhile)
         self._fault_point("verify")
-        t_c = time.perf_counter()
-        self.pool.buffers, pred = self._verify_fn(
-            self.params, self.pool.buffers, jnp.asarray(self._tok),
-            jnp.asarray(draft), jnp.asarray(n_draft),
-            jnp.asarray(self._len), jnp.asarray(self._table),
-            self._next_key())
-        self.stats["decode_calls"] += 1
-        pred = np.asarray(pred)                      # (max_slots, k+1)
+        with self._span("engine.decode_dispatch", slots=len(run)) as sp:
+            self.pool.buffers, pred = self._verify_fn(
+                self.params, self.pool.buffers, jnp.asarray(self._tok),
+                jnp.asarray(draft), jnp.asarray(n_draft),
+                jnp.asarray(self._len), jnp.asarray(self._table),
+                self._next_key())
+        self._note_decode_dispatch(run)
+        with self._span("engine.decode_sync") as sync:
+            pred = np.asarray(pred)                  # (max_slots, k+1)
+        self.stats["decode_sync_s"] += sync.dur
         if self.metrics is not None:
-            self._m["decode_call_s"].observe(time.perf_counter() - t_c)
+            self._m["decode_call_s"].observe(time.perf_counter() - sp.t0)
         now = self._now()
         for idx in run:
             st = self._slots[idx]
