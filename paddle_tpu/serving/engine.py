@@ -8,8 +8,9 @@ drains.  This engine instead runs serving as TWO reusable jitted
 programs called from a host loop:
 
   * ``chunk prefill``: up to ``chunk_tokens`` of ONE request's prompt
-    per call — embeddings, ``_block_qkv``, the chunk's K/V scattered
-    into the slot's pool pages, then paged attention of the chunk
+    per call — embeddings, ``_block_qkv``, the chunk's K/V written
+    into the slot's pool pages (whole pages at a time, in place: see
+    ``_scatter_kv``), then paged attention of the chunk
     against everything already written (cached prefix pages, earlier
     chunks, itself) via the block table — the Sarathi-Serve chunked
     prefill (kernels/paged_prefill.py).  Chunk widths pad to power-of-two
@@ -20,8 +21,8 @@ programs called from a host loop:
   * ``decode``: ONE token for EVERY started slot — per-slot paged KV
     write at each slot's own position, paged attention through the block
     table (kernels/paged_attention.py), sampling.  Slot count is static;
-    inactive/partially-prefilled lanes compute into the pool's null page
-    and are ignored.
+    inactive/partially-prefilled lanes are routed to the pool's null page,
+    write nothing and are ignored.
 
 Prefix caching (RadixAttention, SGLang) rides on the page pool: at
 admission the scheduler matches the prompt against the pool's
@@ -565,70 +566,139 @@ class ServingEngine:
 
     # -- device programs --------------------------------------------------
 
+    # The programs see the pool as the attention kernels do: every buffer
+    # viewed ``(L * P, Hkv, page_size, d)`` (a bitcast of ``KVPool.buffers``,
+    # taken on entry and undone on exit), layer ``li``'s pages at ids
+    # ``li * P ..``.  It is only ever WRITTEN in whole pages along that
+    # page axis (``_scatter_kv``) and READ through ``table + li * P``
+    # (``_attend_with``), so the donated buffers keep one layout and are
+    # updated in place: an element scatter or a ``[li]`` slice makes XLA
+    # re-lay-out or copy the pool on every dispatch (PERF.md, PR 25).
+
+    @staticmethod
+    def _flat(bufs):
+        return {k: b.reshape((-1,) + b.shape[2:]) for k, b in bufs.items()}
+
+    def _unflat(self, bufs):
+        return {k: b.reshape((self.pool.num_layers, -1) + b.shape[1:])
+                for k, b in bufs.items()}
+
+    def _attend_with(self, fn, q, bufs, li, table, at):
+        """One attention entry (kernel or jnp reference, same signature)
+        for layer ``li``: the flat pool and the layer's page ids."""
+        n, lo = self.pool.num_pages, li * self.pool.num_pages
+        if self.kv_bits is None:
+            return fn(q, bufs["k"], bufs["v"], table + lo, at,
+                      window=self.window)
+        # Quantized pools still read a COPY of the layer's rows.  The
+        # kernels take the scales as (pages, Hkv, page_size, 1) fp32, and
+        # TPU tiling pads that trailing 1 to 128 lanes: handed the flat
+        # planes, every layer would re-lay-out all L * P pages of both
+        # (2 x 8 GB at the benchmark's sizes) where the slice bounds it to
+        # one layer's.  Goes when the kernels take lane-dense scales
+        # (ROADMAP S2, PERF.md section 7).
+        k, ks, v, vs = (bufs[x][lo:lo + n] for x in ("k", "ks", "v", "vs"))
+        return fn(q, k, v, table, at, window=self.window,
+                  k_scales=ks, v_scales=vs)
+
     def _attend(self, q, bufs, li, table, lengths):
         """Paged decode attention for layer ``li`` — kernel or jnp ref."""
-        if self.kv_bits is not None:
-            kw = dict(k_scales=bufs["ks"][li], v_scales=bufs["vs"][li])
-        else:
-            kw = {}
         fn = pa.paged_attention if self._use_kernel else pa.paged_attention_ref
-        return fn(q, bufs["k"][li], bufs["v"][li], table, lengths,
-                  window=self.window, **kw)
+        return self._attend_with(fn, q, bufs, li, table, lengths)
 
     def _attend_prefill(self, q, bufs, li, table_row, start):
         """Paged chunk attention for layer ``li`` — kernel or jnp ref."""
-        if self.kv_bits is not None:
-            kw = dict(k_scales=bufs["ks"][li], v_scales=bufs["vs"][li])
-        else:
-            kw = {}
         fn = (pp.paged_prefill if self._use_prefill_kernel
               else pp.paged_prefill_ref)
-        return fn(q, bufs["k"][li], bufs["v"][li], table_row, start,
-                  window=self.window, **kw)
+        return self._attend_with(fn, q, bufs, li, table_row, start)
 
-    def _scatter_kv(self, bufs, li, rows, offs, k1, v1):
-        """Write per-token K/V (rows of shape (N, Hkv, D)) into layer
-        ``li`` of the page pool at (page ``rows[i]``, offset ``offs[i]``)
-        — quantizing to int8 (or nibble-packed int4) pages + fp32
-        per-token scales when serving quantized KV.  The ONE
-        scatter/quantize sequence shared by the decode and chunk-prefill
-        programs, so the exact-parity contract cannot fork between
-        them."""
+    def _attend_spec(self, q, bufs, li, table, lengths):
+        """Multi-query verify attention for layer ``li`` — kernel or jnp
+        ref.  ``lengths`` counts the positions valid BEFORE the verify
+        block (the paged_attention_mq contract)."""
+        fn = (pa.paged_attention_mq if self._use_spec_kernel
+              else pa.paged_attention_mq_ref)
+        return self._attend_with(fn, q, bufs, li, table, lengths)
+
+    def _page_writes(self, table, pos0, valid):
+        """Where one dispatch writes, in whole pages: each of the G rows
+        of ``table`` (G, max_pages) takes a block of T consecutive
+        positions from ``pos0`` (G,), of which ``valid`` (G, T) are real.
+        T positions touch at most W = ceil((T - 1) / page_size) + 1
+        logical pages, so the plan is static in size:
+
+          * ``ids`` (G * W,): the pool page behind each, or the null page
+            0 where no valid row lands (inactive lanes, padded rows, the
+            block's unused last page, positions past the table) — a live
+            page id therefore occurs at most once;
+          * ``src`` (G, W * page_size): the block row at each offset;
+          * ``put`` (G * W, 1, page_size, 1): offsets that take that row.
+            The rest keep what the page holds, and nothing is ever put on
+            page 0, so its duplicate writes are all the same bytes."""
+        ps, maxp = self.page_size, self.max_pages
+        g, t = valid.shape
+        w = (t + ps - 2) // ps + 1
+        logical = (pos0 // ps)[:, None] + jnp.arange(w, dtype=jnp.int32)
+        row = (logical[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)
+               - pos0[:, None, None]).reshape(g, w * ps)
+        src = jnp.clip(row, 0, t - 1)
+        put = ((row >= 0) & (row < t)
+               & jnp.take_along_axis(valid, src, axis=1)).reshape(g, w, ps)
+        ids = jnp.take_along_axis(table, jnp.minimum(logical, maxp - 1),
+                                  axis=1)
+        ids = jnp.where((logical < maxp) & put.any(-1), ids, 0)
+        put = put & (ids != 0)[:, :, None]
+        return ids.reshape(-1), src, put.reshape(g * w, 1, ps, 1)
+
+    def _scatter_kv(self, bufs, li, writes, k1, v1):
+        """Write a block's K/V (``k1``/``v1`` (G, Hkv, T, D), as
+        ``_block_qkv`` yields them) into layer ``li`` of the flat pool by
+        the plan ``writes`` of :meth:`_page_writes` — quantizing to int8
+        (or nibble-packed int4) rows + fp32 per-token scales when serving
+        quantized KV.  Gathers the pages written, merges the new rows in
+        by the plan's mask and scatters WHOLE pages back, along the page
+        axis only.  The ONE write/quantize sequence shared by the decode,
+        verify and chunk-prefill programs, so the exact-parity contract
+        cannot fork between them."""
+        ids, src, put = writes
+        ids = ids + li * self.pool.num_pages
+        new = {"k": k1, "v": v1}
         if self.kv_bits is not None:
             from ..ops.quant_ops import (quantize_int4_per_token,
                                          quantize_per_token)
 
             qf = (quantize_int4_per_token if self.kv_bits == 4
                   else quantize_per_token)
-            kq, ksc = qf(k1)
-            vq, vsc = qf(v1)
-            bufs["k"] = bufs["k"].at[li, rows, :, offs, :].set(kq)
-            bufs["ks"] = bufs["ks"].at[li, rows, :, offs, :].set(ksc)
-            bufs["v"] = bufs["v"].at[li, rows, :, offs, :].set(vq)
-            bufs["vs"] = bufs["vs"].at[li, rows, :, offs, :].set(vsc)
-        else:
-            bufs["k"] = bufs["k"].at[li, rows, :, offs, :].set(k1)
-            bufs["v"] = bufs["v"].at[li, rows, :, offs, :].set(v1)
-        return bufs
+            new["k"], new["ks"] = qf(k1)
+            new["v"], new["vs"] = qf(v1)
+        out = {}
+        for name, buf in bufs.items():
+            x = new[name]
+            g, n, t, d = x.shape
+            # the row at every offset of every page written: a decode's
+            # one row needs no gather
+            x = (jnp.take_along_axis(x, src[:, None, :, None], axis=2)
+                 if t > 1 else jnp.broadcast_to(x, (g, n, src.shape[1], d)))
+            # (G, Hkv, W * ps, d) -> one (Hkv, ps, d) page per write
+            x = jnp.swapaxes(x.reshape(g, n, -1, self.page_size, d), 1, 2)
+            x = x.reshape((-1,) + buf.shape[1:])
+            out[name] = buf.at[ids].set(jnp.where(put, x, buf[ids]))
+        return out
 
     def _build_decode(self):
-        n_heads, eps, ps = self.n_heads, self.eps, self.page_size
-        maxp, k_steps = self.max_pages, self.decode_block
-        n_kv = self.n_kv_heads
+        n_heads, eps = self.n_heads, self.eps
+        k_steps, n_kv = self.decode_block, self.n_kv_heads
 
         def one_step(p, bufs, table, toks, lengths, active, key):
             s = toks.shape[0]
             x = (p["wte"][toks] + p["wpe"][lengths])[:, None, :]  # (S, 1, h)
-            page_idx = jnp.minimum(lengths // ps, maxp - 1)
-            # exhausted/inactive lanes park their writes on the null page
-            rows = jnp.where(active, table[jnp.arange(s), page_idx], 0)
-            offs = lengths % ps
+            # exhausted/inactive lanes write nothing
+            writes = self._page_writes(table, lengths, active[:, None])
             for li, bp in enumerate(p["blocks"]):
                 q, kb, vb = _block_qkv(bp, x, n_heads, eps,
-                                       n_kv_heads=n_kv)
-                q1, k1, v1 = q[:, :, 0], kb[:, :, 0], vb[:, :, 0]  # (S, H, D)
-                bufs = self._scatter_kv(bufs, li, rows, offs, k1, v1)
-                out = self._attend(q1, bufs, li, table, lengths + 1)
+                                       n_kv_heads=n_kv)      # (S, H, 1, D)
+                bufs = self._scatter_kv(bufs, li, writes, kb, vb)
+                out = self._attend(q[:, :, 0], bufs, li, table, lengths + 1)
                 out = out.reshape(s, -1)[:, None, :].astype(x.dtype)
                 x = _block_finish(bp, x, out, eps)
             logits = _lm_head(p, x[:, 0], eps)                    # (S, V)
@@ -637,11 +707,12 @@ class ServingEngine:
 
         def decode(p, bufs, toks, lengths, table, remaining, key):
             self.stats["decode_traces"] += 1  # python side effect: per trace
+            bufs = self._flat(bufs)
             if k_steps == 1:
                 active = remaining > 0
                 bufs, nxt = one_step(p, bufs, table, toks, lengths,
                                      active, key)
-                return bufs, nxt[None]                             # (1, S)
+                return self._unflat(bufs), nxt[None]               # (1, S)
 
             def body(carry, i):
                 bufs, toks, lengths, remaining, key = carry
@@ -657,41 +728,27 @@ class ServingEngine:
             (bufs, _, _, _, _), toks_all = jax.lax.scan(
                 body, (bufs, toks, lengths, remaining, key),
                 jnp.arange(k_steps))
-            return bufs, toks_all                                  # (k, S)
+            return self._unflat(bufs), toks_all                    # (k, S)
 
         return jax.jit(decode, donate_argnums=(1,))
-
-    def _attend_spec(self, q, bufs, li, table, lengths):
-        """Multi-query verify attention for layer ``li`` — kernel or jnp
-        ref.  ``lengths`` counts the positions valid BEFORE the verify
-        block (the paged_attention_mq contract)."""
-        if self.kv_bits is not None:
-            kw = dict(k_scales=bufs["ks"][li], v_scales=bufs["vs"][li])
-        else:
-            kw = {}
-        fn = (pa.paged_attention_mq if self._use_spec_kernel
-              else pa.paged_attention_mq_ref)
-        return fn(q, bufs["k"][li], bufs["v"][li], table, lengths,
-                  window=self.window, **kw)
 
     def _build_verify(self):
         """The speculative verify program: ONE dispatch embeds each
         slot's ``[carry, draft_0 .. draft_{k-1}]`` block at positions
-        ``len .. len+k``, scatters all rows' K/V into the slot's pages
-        (same quantize/scatter as decode — rows past the slot's draft
-        count and inactive lanes park on the null page), runs multi-query
+        ``len .. len+k``, writes all rows' K/V into the slot's pages
+        (same quantize/write as decode — rows past the slot's draft
+        count and inactive lanes are written nowhere), runs multi-query
         paged attention (each row sees history + earlier block rows,
         causally), projects every row and samples greedily.  The host
         applies the rejection rule to the returned (S, k+1) predictions.
 
         Rejected rows leave stale K/V at positions past the accepted
-        prefix; that is safe by construction: the next step's scatter
+        prefix; that is safe by construction: the next step's write
         REWRITES positions ``len' .. len'+k'`` before attending, and no
         query row ever attends past its own position — the same masking
-        argument that makes null-page garbage harmless."""
-        n_heads, eps, ps = self.n_heads, self.eps, self.page_size
-        maxp, t = self.max_pages, self.spec_k + 1
-        n_kv = self.n_kv_heads
+        argument that makes block-table padding harmless."""
+        n_heads, eps = self.n_heads, self.eps
+        t, n_kv = self.spec_k + 1, self.n_kv_heads
 
         def verify(p, bufs, toks, draft, n_draft, lengths, table, key):
             self.stats["decode_traces"] += 1  # python side effect: per trace
@@ -703,20 +760,16 @@ class ServingEngine:
             x = p["wte"][block] + p["wpe"][
                 jnp.minimum(pos, p["wpe"].shape[0] - 1)]         # (S, T, h)
             # rows beyond the slot's draft count — and every row of a
-            # lane not decoding this step (n_draft == -1) — write to the
-            # null page, exactly like inactive decode lanes
+            # lane not decoding this step (n_draft == -1) — are written
+            # nowhere, exactly like inactive decode lanes
             row_ok = jnp.arange(t, dtype=jnp.int32)[None, :] <= \
                 n_draft[:, None]
-            page_idx = jnp.minimum(pos // ps, maxp - 1)
-            rows = jnp.where(
-                row_ok, jnp.take_along_axis(table, page_idx, axis=1), 0)
-            offs = pos % ps
+            writes = self._page_writes(table, lengths, row_ok)
+            bufs = self._flat(bufs)
             for li, bp in enumerate(p["blocks"]):
                 q, kb, vb = _block_qkv(bp, x, n_heads, eps,
                                        n_kv_heads=n_kv)     # q (S,H,T,D)
-                k1 = jnp.swapaxes(kb, 1, 2)                  # (S, T, Hkv, D)
-                v1 = jnp.swapaxes(vb, 1, 2)
-                bufs = self._scatter_kv(bufs, li, rows, offs, k1, v1)
+                bufs = self._scatter_kv(bufs, li, writes, kb, vb)
                 out = self._attend_spec(jnp.swapaxes(q, 1, 2), bufs, li,
                                         table, lengths)
                 out = out.reshape(s, t, -1).astype(x.dtype)
@@ -724,14 +777,12 @@ class ServingEngine:
             logits = _lm_head(p, x, eps)                     # (S, T, V)
             key, sub = jax.random.split(key)
             pred = self._sample(logits.reshape(s * t, -1), sub)
-            return bufs, pred.reshape(s, t).astype(jnp.int32)
+            return self._unflat(bufs), pred.reshape(s, t).astype(jnp.int32)
 
         return jax.jit(verify, donate_argnums=(1,))
 
     def _build_prefill(self):
-        n_heads, eps, ps = self.n_heads, self.eps, self.page_size
-        maxp = self.max_pages
-        n_kv = self.n_kv_heads
+        n_heads, eps, n_kv = self.n_heads, self.eps, self.n_kv_heads
 
         def prefill(p, bufs, toks, start, n_valid, table_row, sample_idx,
                     key):
@@ -746,20 +797,16 @@ class ServingEngine:
             c = toks.shape[0]
             pos = start + jnp.arange(c, dtype=jnp.int32)
             x = (p["wte"][toks] + p["wpe"][pos])[None]        # (1, C, h)
-            # padded rows scatter into the null page (page 0)
-            valid = jnp.arange(c) < n_valid
-            page_idx = jnp.minimum(pos // ps, maxp - 1)
-            rows = jnp.where(valid, table_row[page_idx], 0)
-            offs = pos % ps
+            # padded rows are written nowhere
+            writes = self._page_writes(table_row[None], start[None],
+                                       (jnp.arange(c) < n_valid)[None])
+            bufs = self._flat(bufs)
             for li, bp in enumerate(p["blocks"]):
                 q, kb, vb = _block_qkv(bp, x, n_heads, eps,
-                                       n_kv_heads=n_kv)
-                # (1, H, C, D) -> (C, H, D): the page-scatter layout
-                q1 = jnp.swapaxes(q[0], 0, 1)
-                k1 = jnp.swapaxes(kb[0], 0, 1)
-                v1 = jnp.swapaxes(vb[0], 0, 1)
-                bufs = self._scatter_kv(bufs, li, rows, offs, k1, v1)
-                out = self._attend_prefill(q1, bufs, li, table_row, start)
+                                       n_kv_heads=n_kv)      # (1, H, C, D)
+                bufs = self._scatter_kv(bufs, li, writes, kb, vb)
+                out = self._attend_prefill(jnp.swapaxes(q[0], 0, 1), bufs,
+                                           li, table_row, start)
                 out = out.reshape(c, -1)[None].astype(x.dtype)
                 x = _block_finish(bp, x, out, eps)
             # only the sample row's logits are ever consumed (and only by
@@ -770,7 +817,7 @@ class ServingEngine:
             last = _lm_head(p, h_row[None, :], eps)           # (1, V)
             key, sub = jax.random.split(key)
             tok = self._sample(last, sub)[0]
-            return bufs, tok.astype(jnp.int32)
+            return self._unflat(bufs), tok.astype(jnp.int32)
 
         return jax.jit(prefill, donate_argnums=(1,))
 

@@ -19,10 +19,10 @@ threads ONE buffer pair):
     carry over to pages unchanged.
 
 Page 0 is RESERVED as the null page: the allocator never hands it out,
-block-table padding points at it, and masked/inactive lanes write their
-garbage there — so no gather in the paged-attention kernel can ever
-index out of the pool, and no active page can be corrupted by an
-inactive lane.
+block-table padding points at it, and the engine's page writes route
+masked/inactive lanes to it with nothing to put (it stays zero) — so no
+gather in the paged-attention kernel can ever index out of the pool, and
+no active page can be corrupted by an inactive lane.
 
 Sharing (r09): every page carries a REFCOUNT of live requests holding it.
 ``alloc`` leases fresh pages at refcount 1; a request matching a cached

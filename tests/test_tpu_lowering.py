@@ -8,11 +8,19 @@ were both in that state (ISSUE 21).  Lowering with ``interpret=False`` for
 LOWERING only — Mosaic's layout and VMEM checks run inside libtpu at
 compile time, which ``python chip_smoke.py`` exercises on the chip with
 the same cases.
+
+The serving engine's programs are also COMPILED here, ahead of time, by
+the libtpu this sandbox has, for a described ``v5e:2x2`` (no chip): the
+optimized HLO and ``memory_analysis()`` show whether a program re-lays
+out, copies or slices the paged KV pool (ISSUE 25).
 """
 
 import os
+import re
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import chip_smoke
@@ -63,3 +71,126 @@ def test_compile_cache_default_is_fixed_in_tree(monkeypatch):
     assert calls == [("jax_compilation_cache_dir", want)]
     with open(os.path.join(repo, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+# ---------------------------------------------------------------------------
+# the serving programs keep the KV pool in place (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One described v5e chip.  ``get_topology_desc`` loads libtpu, which
+    one process at a time may hold: only ever from inside this fixture."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    env = pytest.MonkeyPatch()
+    env.setenv("TPU_LOG_DIR", "disabled")       # or libtpu logs under /tmp
+    env.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        env.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one: keep them out
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+    env.undo()
+
+
+# pools of 4 layers x 128 MiB a side: more than the chip's 128 MiB of VMEM,
+# or the compiler parks the whole toy pool there and its prefetches read as
+# pool copies.  int4 packs two nibbles a byte, so the kernels take it from
+# head size 256 on (``paged_attention.supported``).
+_POOLS = {"bf16": dict(kv_bits=None, heads=4, pages=513),
+          "int8": dict(kv_bits=8, heads=4, pages=1025),
+          "int4": dict(kv_bits=4, heads=2, pages=2049)}
+_SLOTS, _PAGE, _SEQ, _SPEC_K, _LAYERS = 8, 64, 512, 4, 4
+_RESULT = re.compile(r"^\s*(?:ROOT )?\S+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+_MOVES = ("copy", "copy-done", "slice", "slice-done", "dynamic-slice")
+_BYTES = {"bf16": 2, "f16": 2, "s8": 1, "u8": 1, "pred": 1}   # others: 4
+
+
+def _serving_program(monkeypatch, one_chip, pool, program):
+    """(the engine, the program compiled for one v5e chip)."""
+    from paddle_tpu.kernels import paged_attention as pa
+    from paddle_tpu.kernels import paged_prefill as pp
+    from paddle_tpu.models import GPTConfig
+    from paddle_tpu.serving import ServingEngine
+
+    # the engine asks the running backend whether the Pallas kernels exist
+    # and whether to interpret them; here the answer is the chip's
+    monkeypatch.setattr(pa, "_backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pp, "_backend_is_tpu", lambda: True)
+    kind = _POOLS[pool]
+    model = chip_smoke._bf16_model(GPTConfig(
+        vocab_size=1024, hidden_size=512, num_layers=_LAYERS,
+        num_heads=kind["heads"], max_seq_len=_SEQ, dropout=0.0))
+    model.eval()
+    eng = ServingEngine(model, max_slots=_SLOTS, page_size=_PAGE,
+                        num_pages=kind["pages"], spec_k=_SPEC_K,
+                        int8=False, kv_bits=kind["kv_bits"])
+    assert set(eng.attention_paths().values()) == {"kernel"}
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params, bufs, key = jax.tree_util.tree_map(
+        on_chip, (eng.params, eng.pool.buffers, eng._key))
+    s, mp = _SLOTS, eng.max_pages
+    fn, args = {
+        "decode": (eng._decode_fn, (ints(s), ints(s), ints(s, mp), ints(s))),
+        "verify": (eng._verify_fn, (ints(s), ints(s, _SPEC_K), ints(s),
+                                    ints(s), ints(s, mp))),
+        "prefill": (eng._prefill_fn, (ints(eng.chunk_tokens), ints(), ints(),
+                                      ints(mp), ints())),
+    }[program]
+    return eng, fn.lower(params, bufs, *args, key).compile()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+@pytest.mark.parametrize("pool", _POOLS)
+def test_serving_program_keeps_the_pool_in_place(monkeypatch, one_chip,
+                                                 pool, program):
+    """No program re-lays out, copies or slices the pool per dispatch: the
+    donated buffers are updated in place by whole-page scatters and the
+    attention kernels read them through the block table.  The element
+    scatter and the ``[li]`` slices this replaced compiled to 4 copies of
+    the whole pool and a slice plus a re-layout of every layer, and to
+    temporaries the size of the K pool (PERF.md, PR 25)."""
+    eng, compiled = _serving_program(monkeypatch, one_chip, pool, program)
+    sizes = {name: int(np.prod(b.shape[1:])) * b.dtype.itemsize
+             for name, b in eng.pool.buffers.items()}
+    layer = sizes["k"]
+    if eng.kv_bits is None:
+        largest_move, temp_bound = layer - 1, layer
+    else:
+        # Quantized pools: the kernels take their scales as (pages, Hkv,
+        # page_size, 1) fp32, which TPU tiling pads 128 x, so the engine
+        # hands them one layer's rows at a time (``_attend_with``): a
+        # slice of that layer of K, V and both scale planes remains, and
+        # nothing larger.  Tighten to the bf16 rule once the kernels take
+        # lane-dense scales (ROADMAP S2).
+        largest_move = layer
+        temp_bound = 3 * layer + 2 * 128 * sizes["ks"]
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(3) in _MOVES:
+            dtype, dims, op = m.groups()
+            n = _BYTES.get(dtype, 4) * int(np.prod(
+                [int(d) for d in dims.split(",") if d] or [1]))
+            if n > largest_move:
+                moved.append(f"{op} {dtype}[{dims}]")
+    assert not moved, f"pool-sized copies or slices: {sorted(set(moved))}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < temp_bound, (temp, temp_bound, layer)
