@@ -15,7 +15,11 @@ Design (pallas_guide.md, same skeleton as the decode kernel):
     step p is ``block_table[p]`` — the gather IS the BlockSpec index_map,
     i.e. the DMA schedule;
   * the whole (chunk, H, D) query block sits in VMEM across the page
-    grid; each page folds into a flash online-softmax recurrence with
+    grid where it fits; where it does not (128 query heads of 128: 17 MB),
+    the grid gains a leading axis over KV heads and a block is one KV
+    head's group, (chunk, H / Hkv, D), against that head's (page_size, D)
+    rows of the page: :func:`block_heads` decides from the shapes.  Each
+    page folds into a flash online-softmax recurrence with
     per-query m/l/acc scratch.  CAUSALITY is the only mask: page position
     j is visible to chunk row i iff ``j <= start + i`` — global position
     0 is visible to every row, so no row is ever fully masked;
@@ -53,34 +57,106 @@ def available() -> bool:
     return _backend_is_tpu()
 
 
+_VMEM_BUDGET = 8 * 1024 * 1024      # of the core's 16 MB
+
+
+def block_heads(n_heads: int, page_size: int, head_dim: int, chunk: int,
+                n_kv_heads: int | None = None) -> int | None:
+    """Query heads in one block of the kernel, or None where no block
+    fits.  All ``n_heads`` under MHA and under GQA with a group that is not
+    sublane-aligned: q + acc (chunk, H, D) and the K/V pages (Hkv, ps, D)
+    have to fit the VMEM budget.  One KV head's group ``H / Hkv`` under GQA
+    with a group of a multiple of 8 (the grid then also runs over KV
+    heads): the whole block's regrouping of its scores over KV heads does
+    not lower for the chip at such groups, and at 128 query heads it would
+    not fit either."""
+    nkv = n_kv_heads or n_heads
+    group = n_heads // nkv
+    heads, kv = (group, 1) if nkv != n_heads and group % 8 == 0 \
+        else (n_heads, nkv)
+    vmem = 4 * (2 * chunk * heads * head_dim + 2 * kv * page_size * head_dim)
+    return heads if vmem < _VMEM_BUDGET else None
+
+
 def supported(n_heads: int, page_size: int, head_dim: int, chunk: int,
               n_kv_heads: int | None = None,
               kv_bits: int | None = None) -> bool:
     """Shape gate for the fused kernel: lane-aligned head_dim (stored
-    width for int4 pages), a sublane-aligned page and chunk, and a query
-    head count that divides evenly over the KV heads.  Ragged shapes take
-    the jnp reference path instead of failing at lowering."""
+    width for int4 pages), a sublane-aligned page and chunk, a query
+    head count that divides evenly over the KV heads, and a block
+    (:func:`block_heads`) that fits.  Ragged shapes take the jnp
+    reference path instead of failing at lowering."""
     nkv = n_kv_heads or n_heads
     if n_heads % nkv != 0:
         return False
     lane_d = head_dim // 2 if kv_bits == 4 else head_dim
     if lane_d % 128 != 0 or page_size % 32 != 0 or chunk % 8 != 0:
         return False
-    # VMEM: q + acc (chunk, H, D) each, K/V pages (Hkv, ps, D); vs 16MB/core
-    vmem = 4 * (2 * chunk * n_heads * head_dim
-                + 2 * nkv * page_size * head_dim)
-    return vmem < 8 * 1024 * 1024
+    return block_heads(n_heads, page_size, head_dim, chunk, nkv) is not None
+
+
+def _group_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
+                      page_size, scale, window=None):
+    """The page step of the grid over (KV heads, pages): the block is one
+    KV head's group of query heads, ``q_ref`` (C, g, D) against that
+    head's ``k``/``v`` (1, ps, D).  Rows and heads merge into one matmul
+    dimension of C * g (row-major, so row r is chunk row ``r // g``): two
+    plain 2-D products a page, no regrouping of the scores.  Same mask,
+    same recurrence and the same division at the end as
+    :func:`_chunk_recurrence`; m and l are kept lane-broadcast like the
+    decode kernel's."""
+    p = pl.program_id(1)
+
+    @pl.when(p == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    c, g, d = q_ref.shape
+    q = q_ref[...].astype(jnp.float32).reshape(c * g, d)
+    s = jax.lax.dot_general(q, k[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    pos = p * jnp.int32(page_size) + jax.lax.broadcasted_iota(
+        jnp.int32, (1, page_size), 1)
+    qpos = start_ref[0] + jax.lax.broadcasted_iota(
+        jnp.int32, (c * g, 1), 0) // jnp.int32(g)
+    keep = pos <= qpos
+    if window is not None:
+        keep = keep & (pos > qpos - window)
+    s = jnp.where(keep, s, jnp.float32(_NEG_INF))          # (C * g, ps)
+
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    pexp = jnp.exp(s - m_new)
+    l_new = l_ref[:, :1] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        pexp, v[0], preferred_element_type=jnp.float32)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(p == pl.num_programs(1) - 1)
+    def _finish():
+        out = acc_ref[...] / l_ref[:, :1]
+        o_ref[...] = out.reshape(c, g, v.shape[-1]).astype(o_ref.dtype)
 
 
 def _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=None, n_kv=None):
+                      page_size, scale, chunk, window=None, n_kv=None,
+                      page_axis=0):
     """The ONE online-softmax page step shared by the float/int8/int4
     entries (only how k/v materialize in VMEM differs): init scratch on
     the first page, score + causal-mask this page against every chunk
     row (GQA query heads regrouped over the shared KV head, never
     repeating K/V; sliding window drops keys more than ``window`` behind
     each row), fold into the m/l/acc flash recurrence, divide out on the
-    last page."""
+    last page.  ``page_axis`` is the grid axis that runs over pages: 0,
+    or 1 under a leading axis over KV heads (:func:`_group_recurrence`
+    then does the step)."""
+    if page_axis:
+        return _group_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref,
+                                 acc_ref, page_size, scale, window=window)
     p = pl.program_id(0)
 
     @pl.when(p == 0)
@@ -135,32 +211,37 @@ def _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
 
 def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
                     m_ref, l_ref, acc_ref, *, page_size, scale, chunk,
-                    window=None, n_kv=None):
+                    window=None, n_kv=None, page_axis=0):
     k = k_ref[0].astype(jnp.float32)                       # (Hkv, ps, D)
     v = v_ref[0].astype(jnp.float32)
     _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=window, n_kv=n_kv)
+                      page_size, scale, chunk, window=window, n_kv=n_kv,
+                      page_axis=page_axis)
 
 
 # the int8 entry has its own arity (scale refs) but the same recurrence
 def _prefill_kernel_int8(bt_ref, start_ref, q_ref, k_ref, ks_ref, v_ref,
                          vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                         page_size, scale, chunk, window=None, n_kv=None):
+                         page_size, scale, chunk, window=None, n_kv=None,
+                         page_axis=0):
     k = k_ref[0].astype(jnp.float32) * ks_ref[0]           # (Hkv, ps, D)
     v = v_ref[0].astype(jnp.float32) * vs_ref[0]
     _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=window, n_kv=n_kv)
+                      page_size, scale, chunk, window=window, n_kv=n_kv,
+                      page_axis=page_axis)
 
 
 # int4 pages arrive nibble-packed (D//2 bytes per position); the unpack
 # happens in VMEM right after the page DMA — same decision as decode
 def _prefill_kernel_int4(bt_ref, start_ref, q_ref, k_ref, ks_ref, v_ref,
                          vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                         page_size, scale, chunk, window=None, n_kv=None):
+                         page_size, scale, chunk, window=None, n_kv=None,
+                         page_axis=0):
     k = _unpack4_vmem(k_ref[0]) * ks_ref[0]                # (Hkv, ps, D)
     v = _unpack4_vmem(v_ref[0]) * vs_ref[0]
     _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=window, n_kv=n_kv)
+                      page_size, scale, chunk, window=window, n_kv=n_kv,
+                      page_axis=page_axis)
 
 
 def paged_prefill(q, k_pages, v_pages, block_table, start, *,
@@ -191,35 +272,51 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
         interpret = not _backend_is_tpu()
     quant = k_scales is not None
     int4 = quant and d_store != d
-    nkv = None if hkv == h else hkv
     win = None if window is None else int(window)
 
-    q_spec = pl.BlockSpec((c, h, d), lambda p, bt, st: (0, 0, 0))
-    pg_spec = pl.BlockSpec((1, hkv, ps, d_store),
-                           lambda p, bt, st: (bt[p], 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, hkv, ps, 1),
-                           lambda p, bt, st: (bt[p], 0, 0, 0))
+    hb = block_heads(h, ps, d, c, hkv) or h       # query heads in a block
+    if hb == h:
+        # the whole chunk's heads at once; the grid runs over pages
+        nkv, grid, page_axis = (None if hkv == h else hkv), (max_pages,), 0
+        kvb = hkv
+
+        def at(page):
+            return lambda p, bt, st: ((bt[p], 0, 0, 0) if page
+                                      else (0, 0, 0))
+    else:
+        # one KV head's group at a time: grid (KV heads, pages)
+        nkv, grid, page_axis = 1, (hkv, max_pages), 1
+        kvb = 1
+
+        def at(page):
+            return lambda n, p, bt, st: ((bt[p], n, 0, 0) if page
+                                         else (0, n, 0))
+
+    q_spec = pl.BlockSpec((c, hb, d), at(False))
+    pg_spec = pl.BlockSpec((1, kvb, ps, d_store), at(True))
+    sc_spec = pl.BlockSpec((1, kvb, ps, 1), at(True))
     if quant:
         body = _prefill_kernel_int4 if int4 else _prefill_kernel_int8
-        kernel = functools.partial(body, page_size=ps, scale=scale, chunk=c,
-                                   window=win, n_kv=nkv)
         in_specs = [q_spec, pg_spec, sc_spec, pg_spec, sc_spec]
         args = (q, k_pages, k_scales, v_pages, v_scales)
     else:
-        kernel = functools.partial(_prefill_kernel, page_size=ps,
-                                   scale=scale, chunk=c, window=win,
-                                   n_kv=nkv)
+        body = _prefill_kernel
         in_specs = [q_spec, pg_spec, pg_spec]
         args = (q, k_pages, v_pages)
+    kernel = functools.partial(body, page_size=ps, scale=scale, chunk=c,
+                               window=win, n_kv=nkv, page_axis=page_axis)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(max_pages,),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((c, h, d), lambda p, bt, st: (0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((h, c), jnp.float32),     # running max
-                        pltpu.VMEM((h, c), jnp.float32),     # running denom
-                        pltpu.VMEM((h, c, d), jnp.float32)],  # weighted acc
+        out_specs=pl.BlockSpec((c, hb, d), at(False)),
+        # running max, running denominator, weighted accumulator
+        scratch_shapes=([pltpu.VMEM((c * hb, 128), jnp.float32)] * 2
+                        + [pltpu.VMEM((c * hb, d), jnp.float32)]
+                        if page_axis else
+                        [pltpu.VMEM((h, c), jnp.float32)] * 2
+                        + [pltpu.VMEM((h, c, d), jnp.float32)]),
     )
     with _x64_off():
         return pl.pallas_call(

@@ -22,6 +22,9 @@ from .ernie import (  # noqa: F401
 from .generation import (  # noqa: F401
     build_beam_search_fn, build_generate_fn, generate,
 )
+from .cohere2_moe import (  # noqa: F401
+    Cohere2MoeConfig, Cohere2MoeForCausalLM,
+)
 from .rec import (  # noqa: F401
     RecConfig, DeepFM, WideDeep, FusedSparseEmbedding, synthetic_click_batch,
 )

@@ -29,6 +29,7 @@ decodes on a tp mesh with XLA inserting the collectives.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -38,7 +39,70 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .moe import MoESpec, moe_ffn
+
 _tree_map = jax.tree_util.tree_map
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One decoder layer, as data.  The substrate below (``_block_qkv``,
+    ``_block_finish``) and the serving engine's programs read this and the
+    block's parameter names; the default is GPT-2's layer.
+
+    ``norm_bias``: LayerNorm with an offset, or gain only.  ``position``:
+    ``"learned"`` (a table added to the token embedding, nothing in the
+    layer), ``"rope"`` (q and k rotated in interleaved pairs over all of
+    the head's dims, GPT-J style, by ``rope_theta``) or ``"none"``.
+    ``window``: causal sliding window of that many keys, or None for full
+    causal attention.  ``parallel``: ``x + attn(n) + mlp(n)`` with one norm
+    feeding both, instead of GPT-2's two norms in sequence.  ``moe``: the
+    expert layer's description, or None for GPT-2's GELU MLP.
+    ``head_dim``: None means ``hidden // n_heads``."""
+
+    norm_bias: bool = True
+    position: str = "learned"
+    rope_theta: float = 10000.0
+    window: Optional[int] = None
+    parallel: bool = False
+    moe: Optional[MoESpec] = None
+    head_dim: Optional[int] = None
+
+    def __post_init__(self):
+        if self.position not in ("learned", "rope", "none"):
+            raise ValueError(f"position kind {self.position!r}")
+
+
+GPT2_LAYER = LayerSpec()
+
+
+def decoder_layers(model, attn_window=None):
+    """One :class:`LayerSpec` per layer of ``model``: its own
+    ``layer_specs()``, or GPT-2's layer under the one window the model (or
+    the ``attn_window`` override) sets."""
+    if hasattr(model, "layer_specs"):
+        if attn_window is not None:
+            raise ValueError("attn_window overrides GPT's window only; this "
+                             "model states each layer's own")
+        return tuple(model.layer_specs())
+    window = (attn_window if attn_window is not None
+              else getattr(model.cfg, "attn_window", None))
+    return (dataclasses.replace(GPT2_LAYER, window=window),) \
+        * model.cfg.num_layers
+
+
+def rope_interleaved(x, pos, theta):
+    """Rotate ``x`` (B, heads, T, D) in interleaved pairs (2i, 2i + 1), the
+    row at ``pos`` (B, T) by ``pos * theta ** (-2i / D)``; float32 inside."""
+    d = x.shape[-1]
+    inv = 1.0 / (jnp.float32(theta)
+                 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = pos.astype(jnp.float32)[:, None, :, None] * inv      # (B,1,T,D/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 def _block_params(blk, int8=False):
@@ -107,12 +171,16 @@ def _kv_dequant(vals, scale, hd):
 def _ln(x, g, b, eps):
     mu = x.mean(-1, keepdims=True)
     var = x.var(-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + eps) * g + b
+    y = (x - mu) / jnp.sqrt(var + eps) * g
+    return y if b is None else y + b
 
 
-def _block_qkv(p, x, n_heads, eps, seq_major=False, n_kv_heads=None):
+def _block_qkv(p, x, n_heads, eps, seq_major=False, n_kv_heads=None,
+               spec=GPT2_LAYER, pos=None):
     """The block's pre-attention half: LN1 + fused QKV projection + head
-    split.  Returns ``(q, k_blk, v_blk)`` with ``k_blk``/``v_blk`` in the
+    split (+ the rotation of q and k at ``pos`` (B, T) where ``spec`` asks
+    for rotary positions; batch-major only).
+    Returns ``(q, k_blk, v_blk)`` with ``k_blk``/``v_blk`` in the
     cache's (B, Hkv, T, D) layout and ``q`` in the layout the attention
     einsum of the caller's path wants ((T, B, H, D) seq-major, else
     (B, H, T, D)).  Under GQA the fused projection is (H + 2*Hkv)*D wide
@@ -124,10 +192,12 @@ def _block_qkv(p, x, n_heads, eps, seq_major=False, n_kv_heads=None):
         t, b, h = x.shape
     else:
         b, t, h = x.shape
-    hd = h // n_heads
+    hd = spec.head_dim or h // n_heads
     nkv = n_heads if n_kv_heads is None else n_kv_heads
-    hx = _ln(x, p["ln1_g"], p["ln1_b"], eps)
-    qkv = _mm(p, "qkv", hx) + p["qkv_b"]
+    hx = _ln(x, p["ln1_g"], p["ln1_b"] if spec.norm_bias else None, eps)
+    qkv = _mm(p, "qkv", hx)
+    if "qkv_b" in p:
+        qkv = qkv + p["qkv_b"]
     q, k, v = jnp.split(qkv, [n_heads * hd, (n_heads + nkv) * hd], axis=-1)
 
     if seq_major:
@@ -143,6 +213,9 @@ def _block_qkv(p, x, n_heads, eps, seq_major=False, n_kv_heads=None):
 
         q = heads(q, n_heads)
         k_blk, v_blk = heads(k, nkv), heads(v, nkv)
+    if spec.position == "rope":
+        q = rope_interleaved(q, pos, spec.rope_theta)
+        k_blk = rope_interleaved(k_blk, pos, spec.rope_theta)
     return q, k_blk, v_blk
 
 
@@ -150,18 +223,61 @@ def _lm_head(p, x, eps):
     """Final LN + tied-embedding projection to fp32 logits over the last
     axis of ``x``.  Shared by the dense decoder and the serving engine's
     chunk-prefill/decode programs so the logits math cannot fork."""
-    h = _ln(x, p["lnf_g"], p["lnf_b"], eps)
+    h = _ln(x, p["lnf_g"], p.get("lnf_b"), eps)
     return (h @ p["wte"].T).astype(jnp.float32)
 
 
-def _block_finish(p, x, out, eps):
+def _embed(p, toks, pos):
+    """Token embedding, plus the learned position table where the model
+    has one (rotary and position-free layers take positions themselves)."""
+    x = p["wte"][toks]
+    return x + p["wpe"][pos] if "wpe" in p else x
+
+
+def _block_finish(p, x, out, eps, spec=GPT2_LAYER, valid=None, counts=None):
     """The block's post-attention half: output projection residual + MLP
     residual.  ``out`` is the attention output already merged back to the
-    activation layout of ``x``.  Shared with serving/engine.py."""
-    x = x + _mm(p, "proj", out) + p["proj_b"]
-    hx = _ln(x, p["ln2_g"], p["ln2_b"], eps)
-    return x + _mm(p, "fc2", jax.nn.gelu(_mm(p, "fc1", hx) + p["fc1_b"],
-                                         approximate=False)) + p["fc2_b"]
+    activation layout of ``x``.  Shared with serving/engine.py.  One body
+    for every :class:`LayerSpec`: a bias is added where the block has one,
+    the MLP's norm reads the block's input (parallel) or the attention
+    residual (sequential), and the MLP is GPT-2's or the expert layer.  An
+    expert layer appends its per-expert row counts to the list ``counts``
+    (``valid`` masks padded rows and idle lanes out of them)."""
+    def plus(y, name):
+        return y + p[name] if name in p else y
+
+    h_in = x
+    x = plus(x + _mm(p, "proj", out), "proj_b")
+    ln = "ln1" if spec.parallel else "ln2"
+    hx = _ln(h_in if spec.parallel else x, p[ln + "_g"],
+             p[ln + "_b"] if spec.norm_bias else None, eps)
+    if spec.moe is None:
+        mid = jax.nn.gelu(plus(_mm(p, "fc1", hx), "fc1_b"),
+                          approximate=False)
+        return plus(x + _mm(p, "fc2", mid), "fc2_b")
+    mlp, rows = moe_ffn(p, hx, spec.moe, valid)
+    if counts is not None:
+        counts.append(rows)
+    return x + mlp
+
+
+def dense_attention(q, k, v, window=None):
+    """Causal (optionally sliding-window) attention with no cache: ``q``
+    (B, H, T, D), ``k``/``v`` (B, Hkv, T, D) -> (B, T, H * D), float32
+    softmax.  The eager forward of a model that the substrate describes."""
+    b, h, t, d = q.shape
+    nkv = k.shape[1]
+    qg = q.reshape(b, nkv, h // nkv, t, d)
+    s = jnp.einsum("bngtd,bnsd->bngts", qg, k,
+                   preferred_element_type=jnp.float32)
+    s = s / np.sqrt(d).astype(np.float32)
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    seen = j <= i
+    if window is not None:
+        seen = seen & (j > i - window)
+    att = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1).astype(v.dtype)
+    out = jnp.einsum("bngts,bnsd->bngtd", att, v)
+    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
 def _block_fwd(p, x, k_cache, v_cache, pos, n_heads, eps, seq_major=False,

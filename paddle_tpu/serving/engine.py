@@ -92,9 +92,11 @@ from ..models.generation import (
     _block_finish,
     _block_qkv,
     _decoder_setup,
+    _embed,
     _lm_head,
     _make_sampler,
     _resolve_kv_bits,
+    decoder_layers,
     spec_accept_greedy,
 )
 from ..kernels import paged_attention as pa
@@ -102,7 +104,7 @@ from ..kernels import paged_prefill as pp
 from .drafter import NGramDrafter
 from .faults import FaultPlan, InjectedFault
 from .flight_recorder import FlightRecorder
-from .kv_pool import KVPool
+from .kv_pool import KVPool, WindowRing
 from .metrics import MetricsRegistry, SLOTracker
 from .scheduler import FCFSScheduler, Request
 from .tenancy import normalize_tenants
@@ -136,6 +138,12 @@ class FinishedRequest:
     def ok(self) -> bool:
         """True when the request ran to completion (eos/length)."""
         return self.finish_reason in ("eos", "length")
+
+
+class MultiGroupUnsupported(NotImplementedError):
+    """A feature that a model with two page groups (sliding-window layers
+    beside full-attention layers, ``serving/kv_pool.WindowRing``) does not
+    have yet; PERF.md section 7 lists them."""
 
 
 #: the four phases of a step: their spans also fill ``_phase_s``, which
@@ -359,22 +367,50 @@ class ServingEngine:
         self._drafter = drafter if drafter is not None else (
             NGramDrafter(self.spec_k, max_ngram=spec_ngram)
             if self.spec_k else None)
-        self.params, _, self.int8 = _decoder_setup(model, int8=int8)
+        # the model as the programs read it: one LayerSpec a layer (GPT-2's
+        # for a GPT) and the parameter tree
+        self.layers = decoder_layers(model, attn_window)
+        if hasattr(model, "decoder_params"):
+            if int8:
+                raise ValueError("int8 projections are GPT's (_decoder_setup)")
+            self.params, self.int8 = model.decoder_params(), False
+        else:
+            self.params, _, self.int8 = _decoder_setup(model, int8=int8)
         self.n_heads = cfg.num_heads
         self.n_kv_heads = getattr(cfg, "num_kv_heads", None) or cfg.num_heads
-        self.head_dim = cfg.hidden_size // cfg.num_heads
+        self.head_dim = (getattr(cfg, "head_dim", None)
+                         or cfg.hidden_size // cfg.num_heads)
         self.eps = cfg.layer_norm_eps
+        self._moe = next((sp.moe for sp in self.layers if sp.moe), None)
         # KV-capacity knobs (this PR): kv_bits / attn_window override the
         # model config's defaults; the resolved values fix the pool's page
         # layout and every attention dispatch's masking for the engine's
         # whole lifetime (snapshot v5 records them; restore refuses a
         # mismatched layout)
         self.kv_bits = _resolve_kv_bits(cfg, self.int8, kv_bits)
-        win = attn_window if attn_window is not None \
-            else getattr(cfg, "attn_window", None)
-        if win is not None and int(win) < 1:
-            raise ValueError(f"attn_window must be >= 1, got {win}")
-        self.window = None if win is None else int(win)
+        # Attention kind is per layer.  Layers of ONE kind share one page
+        # group (the pool, recycled behind ``self.window`` if they all
+        # slide); a model that mixes sliding and full layers gets two: the
+        # pool for the full layers and a fixed ring for the sliding ones.
+        windows = {sp.window for sp in self.layers}
+        if any(w is not None and int(w) < 1 for w in windows):
+            raise ValueError(f"attn_window must be >= 1, got {windows}")
+        two_groups = len(windows) > 1
+        if two_groups and (None not in windows or len(windows) != 2):
+            raise MultiGroupUnsupported(
+                f"layers with different windows {sorted(windows)}")
+        self.window = None if two_groups else next(iter(windows))
+        if self.window is not None:
+            self.window = int(self.window)
+        if two_groups:
+            refused = {"role": role != "both", "spec_k": self.spec_k > 0,
+                       "decode_block": self.decode_block > 1,
+                       "double_buffer": self.double_buffer,
+                       "kv_bits": self.kv_bits is not None}
+            if any(refused.values()):
+                raise MultiGroupUnsupported(
+                    "not available to a model with two page groups: "
+                    + ", ".join(k for k, v in refused.items() if v))
         self.max_slots = max_slots
         self.page_size = page_size
         self.max_seq_len = max_seq_len or cfg.max_seq_len
@@ -396,12 +432,28 @@ class ServingEngine:
             self._clock = time.monotonic  # graftlint: allow=determinism
         dtype = self.params["wte"].dtype
         n_pages = num_pages or (1 + max_slots * self.max_pages)
-        self.pool = KVPool(cfg.num_layers, cfg.num_heads, self.head_dim,
+        # layer -> (its group, its index among the group's layers); group 0
+        # is the pool: every admission, growth and preemption asks it alone
+        full = [li for li, sp in enumerate(self.layers)
+                if not two_groups or sp.window is None]
+        slide = [li for li in range(len(self.layers)) if li not in full]
+        self._layer_group = {li: (g, j) for g, ls in enumerate((full, slide))
+                             for j, li in enumerate(ls)}
+        self.pool = KVPool(len(full), cfg.num_heads, self.head_dim,
                            n_pages, page_size, dtype=dtype,
-                           prefix_cache=prefix_cache,
+                           prefix_cache=prefix_cache and not two_groups,
                            num_kv_heads=self.n_kv_heads,
                            kv_bits=self.kv_bits, window=self.window)
         self.pool.faults = faults
+        self.ring: Optional[WindowRing] = None
+        if two_groups:
+            self.ring = WindowRing(
+                len(slide), self.n_kv_heads, self.head_dim, max_slots,
+                self.max_pages, page_size,
+                next(w for w in windows if w is not None),
+                self.chunk_tokens, dtype=dtype)
+        self._group_pages = (n_pages,) + (
+            (self.ring.num_pages,) if two_groups else ())
         self.scheduler = FCFSScheduler(max_slots, self.pool,
                                        token_budget=token_budget,
                                        policy=policy, tenants=tenants)
@@ -523,6 +575,22 @@ class ServingEngine:
                       # ordinary decode output, speculation or not)
                       "spec_drafted": 0, "spec_accepted": 0,
                       "spec_rejected": 0}
+        if self._moe is not None:
+            # expert routing, per dispatch and summed over expert layers:
+            # rows x top_k assignments in all, those that fell on the held
+            # experts (which is also the sum of the held experts' rows), the
+            # busiest held expert's rows, the held experts that had a row at
+            # all, and the layer passes
+            self.stats.update(moe_assignments=0, moe_local_assignments=0,
+                              moe_expert_tokens_max=0, moe_experts_active=0,
+                              moe_layer_passes=0)
+        # (device counts, valid rows) of dispatches not yet synced on
+        self._moe_pending: List[tuple] = []
+        if self.ring is not None:
+            self.stats.update(
+                pages_in_use_window=0, window_pages_recycled=0,
+                # prefix_cache=True resolves to no index for two groups
+                prefix_index_refused=int(bool(prefix_cache)))
         # observability (r11/r16): all default OFF — the hot loop pays
         # nothing unless asked to measure itself
         self.metrics: Optional[MetricsRegistry] = None
@@ -575,21 +643,65 @@ class ServingEngine:
     # updated in place: an element scatter or a ``[li]`` slice makes XLA
     # re-lay-out or copy the pool on every dispatch (PERF.md, PR 25).
 
+    # A model with two page groups hands the programs a TUPLE of buffer
+    # dicts and a tuple of tables, (the pool's, the window ring's); each
+    # layer works on its own group's (``_layer_group``), under the same
+    # rules.  One group is passed bare, as it always was.
+
     @staticmethod
     def _flat(bufs):
         return {k: b.reshape((-1,) + b.shape[2:]) for k, b in bufs.items()}
 
-    def _unflat(self, bufs):
-        return {k: b.reshape((self.pool.num_layers, -1) + b.shape[1:])
+    def _unflat(self, bufs, group: int = 0):
+        return {k: b.reshape((-1, self._group_pages[group]) + b.shape[1:])
                 for k, b in bufs.items()}
+
+    def _enter(self, bufs, tables):
+        """(flat buffers by group, tables by group) of a program's pool
+        arguments."""
+        if self.ring is None:
+            bufs, tables = (bufs,), (tables,)
+        return [self._flat(b) for b in bufs], tables
+
+    def _leave(self, groups):
+        out = tuple(self._unflat(b, g) for g, b in enumerate(groups))
+        return out if self.ring is not None else out[0]
+
+    def _device_pool(self):
+        """The programs' buffer argument (donated)."""
+        if self.ring is None:
+            return self.pool.buffers
+        return (self.pool.buffers, self.ring.buffers)
+
+    def _store_pool(self, bufs) -> None:
+        if self.ring is None:
+            self.pool.buffers = bufs
+        else:
+            self.pool.buffers, self.ring.buffers = bufs
+
+    def _device_tables(self, idx: Optional[int] = None):
+        """The block tables (of slot ``idx``, or all), one per group: host
+        COPIES.  A dispatch is asynchronous and the backend may read (on
+        the CPU: alias) the array it was handed after the call returns,
+        while the ring's rows are turned in place before the next chunk."""
+        def pick(t):
+            return jnp.asarray(np.array(t if idx is None else t[idx]))
+
+        if self.ring is None:
+            return pick(self._table)
+        return (pick(self._table), pick(self.ring.table))
 
     def _attend_with(self, fn, q, bufs, li, table, at):
         """One attention entry (kernel or jnp reference, same signature)
-        for layer ``li``: the flat pool and the layer's page ids."""
-        n, lo = self.pool.num_pages, li * self.pool.num_pages
+        for model layer ``li``: its group's flat buffers and tables in,
+        the layer's page ids and its own window to the kernel."""
+        g, lj = self._layer_group[li]
+        bufs, table = bufs[g], table[g]
+        n = self._group_pages[g]
+        lo, window = lj * n, self.layers[li].window
         if self.kv_bits is None:
             return fn(q, bufs["k"], bufs["v"], table + lo, at,
-                      window=self.window)
+                      window=window)
         # Quantized pools still read a COPY of the layer's rows.  The
         # kernels take the scales as (pages, Hkv, page_size, 1) fp32, and
         # TPU tiling pads that trailing 1 to 128 lanes: handed the flat
@@ -598,7 +710,7 @@ class ServingEngine:
         # one layer's.  Goes when the kernels take lane-dense scales
         # (ROADMAP S2, PERF.md section 7).
         k, ks, v, vs = (bufs[x][lo:lo + n] for x in ("k", "ks", "v", "vs"))
-        return fn(q, k, v, table, at, window=self.window,
+        return fn(q, k, v, table, at, window=window,
                   k_scales=ks, v_scales=vs)
 
     def _attend(self, q, bufs, li, table, lengths):
@@ -650,9 +762,16 @@ class ServingEngine:
         put = put & (ids != 0)[:, :, None]
         return ids.reshape(-1), src, put.reshape(g * w, 1, ps, 1)
 
-    def _scatter_kv(self, bufs, li, writes, k1, v1):
+    def _scatter_layer(self, bufs, li, writes, k1, v1):
+        """:meth:`_scatter_kv` for model layer ``li`` of a program: into
+        its group's buffers, by its group's plan."""
+        g, lj = self._layer_group[li]
+        bufs[g] = self._scatter_kv(bufs[g], lj, writes[g], k1, v1, group=g)
+
+    def _scatter_kv(self, bufs, li, writes, k1, v1, group: int = 0):
         """Write a block's K/V (``k1``/``v1`` (G, Hkv, T, D), as
-        ``_block_qkv`` yields them) into layer ``li`` of the flat pool by
+        ``_block_qkv`` yields them) into layer ``li`` of a group's flat
+        buffers by
         the plan ``writes`` of :meth:`_page_writes` — quantizing to int8
         (or nibble-packed int4) rows + fp32 per-token scales when serving
         quantized KV.  Gathers the pages written, merges the new rows in
@@ -661,7 +780,7 @@ class ServingEngine:
         verify and chunk-prefill programs, so the exact-parity contract
         cannot fork between them."""
         ids, src, put = writes
-        ids = ids + li * self.pool.num_pages
+        ids = ids + li * self._group_pages[group]
         new = {"k": k1, "v": v1}
         if self.kv_bits is not None:
             from ..ops.quant_ops import (quantize_int4_per_token,
@@ -691,35 +810,40 @@ class ServingEngine:
 
         def one_step(p, bufs, table, toks, lengths, active, key):
             s = toks.shape[0]
-            x = (p["wte"][toks] + p["wpe"][lengths])[:, None, :]  # (S, 1, h)
+            x = _embed(p, toks, lengths)[:, None, :]              # (S, 1, h)
             # exhausted/inactive lanes write nothing
-            writes = self._page_writes(table, lengths, active[:, None])
-            for li, bp in enumerate(p["blocks"]):
-                q, kb, vb = _block_qkv(bp, x, n_heads, eps,
-                                       n_kv_heads=n_kv)      # (S, H, 1, D)
-                bufs = self._scatter_kv(bufs, li, writes, kb, vb)
+            writes = [self._page_writes(t, lengths, active[:, None])
+                      for t in table]
+            counts = []                     # (held,) a layer, if experts
+            for li, (bp, spec) in enumerate(zip(p["blocks"], self.layers)):
+                q, kb, vb = _block_qkv(bp, x, n_heads, eps, n_kv_heads=n_kv,
+                                       spec=spec,
+                                       pos=lengths[:, None])  # (S, H, 1, D)
+                self._scatter_layer(bufs, li, writes, kb, vb)
                 out = self._attend(q[:, :, 0], bufs, li, table, lengths + 1)
                 out = out.reshape(s, -1)[:, None, :].astype(x.dtype)
-                x = _block_finish(bp, x, out, eps)
+                x = _block_finish(bp, x, out, eps, spec=spec,
+                                  valid=active[:, None], counts=counts)
             logits = _lm_head(p, x[:, 0], eps)                    # (S, V)
             key, sub = jax.random.split(key)
-            return bufs, self._sample(logits, sub).astype(jnp.int32)
+            nxt = self._sample(logits, sub).astype(jnp.int32)
+            return bufs, nxt, ((jnp.stack(counts),) if counts else ())
 
         def decode(p, bufs, toks, lengths, table, remaining, key):
             self.stats["decode_traces"] += 1  # python side effect: per trace
-            bufs = self._flat(bufs)
+            bufs, table = self._enter(bufs, table)
             if k_steps == 1:
                 active = remaining > 0
-                bufs, nxt = one_step(p, bufs, table, toks, lengths,
-                                     active, key)
-                return self._unflat(bufs), nxt[None]               # (1, S)
+                bufs, nxt, extra = one_step(p, bufs, table, toks, lengths,
+                                            active, key)
+                return (self._leave(bufs), nxt[None]) + extra      # (1, S)
 
             def body(carry, i):
                 bufs, toks, lengths, remaining, key = carry
                 active = remaining > 0
                 key, sub = jax.random.split(key)
-                bufs, nxt = one_step(p, bufs, table, toks, lengths,
-                                     active, sub)
+                bufs, nxt, _ = one_step(p, bufs, table, toks, lengths,
+                                        active, sub)
                 toks = jnp.where(active, nxt, toks)
                 lengths = jnp.where(active, lengths + 1, lengths)
                 remaining = jnp.maximum(remaining - 1, 0)
@@ -728,7 +852,7 @@ class ServingEngine:
             (bufs, _, _, _, _), toks_all = jax.lax.scan(
                 body, (bufs, toks, lengths, remaining, key),
                 jnp.arange(k_steps))
-            return self._unflat(bufs), toks_all                    # (k, S)
+            return self._leave(bufs), toks_all                     # (k, S)
 
         return jax.jit(decode, donate_argnums=(1,))
 
@@ -757,27 +881,27 @@ class ServingEngine:
             pos = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
             # pad rows of short drafts can index positions past the table;
             # clamp for the position embedding (their outputs are unused)
-            x = p["wte"][block] + p["wpe"][
-                jnp.minimum(pos, p["wpe"].shape[0] - 1)]         # (S, T, h)
+            x = _embed(p, block, jnp.minimum(
+                pos, self.cfg.max_seq_len - 1))                  # (S, T, h)
             # rows beyond the slot's draft count — and every row of a
             # lane not decoding this step (n_draft == -1) — are written
             # nowhere, exactly like inactive decode lanes
             row_ok = jnp.arange(t, dtype=jnp.int32)[None, :] <= \
                 n_draft[:, None]
-            writes = self._page_writes(table, lengths, row_ok)
-            bufs = self._flat(bufs)
-            for li, bp in enumerate(p["blocks"]):
-                q, kb, vb = _block_qkv(bp, x, n_heads, eps,
-                                       n_kv_heads=n_kv)     # q (S,H,T,D)
-                bufs = self._scatter_kv(bufs, li, writes, kb, vb)
+            bufs, table = self._enter(bufs, table)
+            writes = [self._page_writes(tb, lengths, row_ok) for tb in table]
+            for li, (bp, spec) in enumerate(zip(p["blocks"], self.layers)):
+                q, kb, vb = _block_qkv(bp, x, n_heads, eps, n_kv_heads=n_kv,
+                                       spec=spec, pos=pos)  # q (S,H,T,D)
+                self._scatter_layer(bufs, li, writes, kb, vb)
                 out = self._attend_spec(jnp.swapaxes(q, 1, 2), bufs, li,
                                         table, lengths)
                 out = out.reshape(s, t, -1).astype(x.dtype)
-                x = _block_finish(bp, x, out, eps)
+                x = _block_finish(bp, x, out, eps, spec=spec)
             logits = _lm_head(p, x, eps)                     # (S, T, V)
             key, sub = jax.random.split(key)
             pred = self._sample(logits.reshape(s * t, -1), sub)
-            return self._unflat(bufs), pred.reshape(s, t).astype(jnp.int32)
+            return self._leave(bufs), pred.reshape(s, t).astype(jnp.int32)
 
         return jax.jit(verify, donate_argnums=(1,))
 
@@ -796,19 +920,23 @@ class ServingEngine:
             self.stats["prefill_traces"] += 1
             c = toks.shape[0]
             pos = start + jnp.arange(c, dtype=jnp.int32)
-            x = (p["wte"][toks] + p["wpe"][pos])[None]        # (1, C, h)
+            x = _embed(p, toks, pos)[None]                    # (1, C, h)
             # padded rows are written nowhere
-            writes = self._page_writes(table_row[None], start[None],
-                                       (jnp.arange(c) < n_valid)[None])
-            bufs = self._flat(bufs)
-            for li, bp in enumerate(p["blocks"]):
-                q, kb, vb = _block_qkv(bp, x, n_heads, eps,
-                                       n_kv_heads=n_kv)      # (1, H, C, D)
-                bufs = self._scatter_kv(bufs, li, writes, kb, vb)
+            valid = (jnp.arange(c) < n_valid)[None]
+            bufs, table_row = self._enter(bufs, table_row)
+            writes = [self._page_writes(tb[None], start[None], valid)
+                      for tb in table_row]
+            counts = []                     # (held,) a layer, if experts
+            for li, (bp, spec) in enumerate(zip(p["blocks"], self.layers)):
+                q, kb, vb = _block_qkv(bp, x, n_heads, eps, n_kv_heads=n_kv,
+                                       spec=spec,
+                                       pos=pos[None])        # (1, H, C, D)
+                self._scatter_layer(bufs, li, writes, kb, vb)
                 out = self._attend_prefill(jnp.swapaxes(q[0], 0, 1), bufs,
                                            li, table_row, start)
                 out = out.reshape(c, -1)[None].astype(x.dtype)
-                x = _block_finish(bp, x, out, eps)
+                x = _block_finish(bp, x, out, eps, spec=spec, valid=valid,
+                                  counts=counts)
             # only the sample row's logits are ever consumed (and only by
             # the chunk completing the prompt): project ONE row, not the
             # whole (C, V) chunk — LN + matmul are row-wise, so the
@@ -816,8 +944,9 @@ class ServingEngine:
             h_row = jnp.take(x[0], sample_idx, axis=0)        # (h,)
             last = _lm_head(p, h_row[None, :], eps)           # (1, V)
             key, sub = jax.random.split(key)
-            tok = self._sample(last, sub)[0]
-            return self._unflat(bufs), tok.astype(jnp.int32)
+            tok = self._sample(last, sub)[0].astype(jnp.int32)
+            return (self._leave(bufs), tok) + (
+                (jnp.stack(counts),) if counts else ())
 
         return jax.jit(prefill, donate_argnums=(1,))
 
@@ -1213,6 +1342,9 @@ class ServingEngine:
         resumes token-for-token."""
         from .snapshot import snapshot_engine
 
+        if self.ring is not None:
+            raise MultiGroupUnsupported(
+                "snapshot / restore of a model with two page groups")
         return snapshot_engine(self)
 
     @classmethod
@@ -1252,6 +1384,8 @@ class ServingEngine:
         self._table[idx] = 0
         self._tok[idx] = 0
         self._len[idx] = 0
+        if self.ring is not None:
+            self.ring.release(idx)
         self.scheduler.release(idx, st.pages, st.request)
         self._observe_terminal(st.request, reason)
         return FinishedRequest(
@@ -1270,6 +1404,8 @@ class ServingEngine:
         self._table[idx] = 0
         self._tok[idx] = 0
         self._len[idx] = 0
+        if self.ring is not None:
+            self.ring.release(idx)
         self.scheduler.release(idx, st.pages, st.request)
         st.request.n_preempted += 1
         self.scheduler.requeue(st.request)
@@ -1402,13 +1538,19 @@ class ServingEngine:
                     self.tracer.begin("prefill_chunk", self._pid_req,
                                       req.rid, {"start": st.prefilled,
                                                 "n": n})
+                if self.ring is not None:
+                    # the ring turns in prefill as in decode: a long prompt
+                    # never holds more window pages than a query can see
+                    self.ring.advance(idx, st.prefilled, st.prefilled + n)
                 with self._span("engine.prefill_dispatch", rid=req.rid,
                                 start=st.prefilled, n=n) as sp:
-                    self.pool.buffers, tok = self._prefill_fn(
-                        self.params, self.pool.buffers, jnp.asarray(toks),
+                    bufs, tok, *counts = self._prefill_fn(
+                        self.params, self._device_pool(), jnp.asarray(toks),
                         jnp.int32(st.prefilled), jnp.int32(n),
-                        jnp.asarray(self._table[idx]), jnp.int32(n - 1),
+                        self._device_tables(idx), jnp.int32(n - 1),
                         self._next_key())
+                    self._store_pool(bufs)
+                    self._moe_pending += [(c, n) for c in counts]
                 if self.metrics is not None:
                     self._m["chunk_s"].observe(sp.dur)
                 if self.tracer is not None:
@@ -1443,6 +1585,7 @@ class ServingEngine:
                                 rid=req.rid) as sp:
                     tok = int(tok)
                 self.stats["prefill_sync_s"] += sp.dur
+                self._fold_moe_counts()
                 st.tokens.append(tok)
                 self._emit_token(req, tok)
                 self._charge_service(req)
@@ -1553,6 +1696,8 @@ class ServingEngine:
         self._table[idx] = 0
         self._tok[idx] = 0
         self._len[idx] = 0
+        if self.ring is not None:
+            self.ring.release(idx)
         self.scheduler.release(idx, st.pages, st.request)
         return st
 
@@ -1793,6 +1938,9 @@ class ServingEngine:
         dt = time.perf_counter() - t0
         self._last_step_at = self._now()
         self.stats["pages_in_use"] = self.pool.pages_in_use
+        if self.ring is not None:
+            self.stats["pages_in_use_window"] = self.ring.pages_in_use
+            self.stats["window_pages_recycled"] = self.ring.recycled
         self.stats["queue_depth"] = self.scheduler.n_waiting
         self.stats["step_wall_s"] += dt
         self.stats["last_step_s"] = dt
@@ -1889,6 +2037,22 @@ class ServingEngine:
             self._decode_step(finished)
             self._fault_point("decode")
 
+    def _fold_moe_counts(self) -> None:
+        """Add the expert-routing counts of the dispatches synced on so far
+        to the stats.  Called right after a sync the step makes anyway (a
+        first token, a decode's tokens): the programs that produced the
+        counts ran before the one just waited for, so reading them waits
+        for nothing."""
+        for counts, rows in self._moe_pending:
+            per_layer = np.asarray(counts)                # (layers, held)
+            st = self.stats
+            st["moe_assignments"] += rows * self._moe.top_k * len(per_layer)
+            st["moe_local_assignments"] += int(per_layer.sum())
+            st["moe_expert_tokens_max"] += int(per_layer.max(axis=1).sum())
+            st["moe_experts_active"] += int((per_layer > 0).sum())
+            st["moe_layer_passes"] += len(per_layer)
+        self._moe_pending.clear()
+
     def _note_decode_dispatch(self, run: List[int]) -> None:
         """Counters of one decode (or verify) dispatch over slots ``run``."""
         self.stats["decode_calls"] += 1
@@ -1921,11 +2085,16 @@ class ServingEngine:
             remaining = np.zeros((self.max_slots,), np.int32)
             for idx in run:
                 remaining[idx] = self._slots[idx].request.remaining_new
+                if self.ring is not None:
+                    self.ring.advance(idx, int(self._len[idx]),
+                                      int(self._len[idx]) + 1)
             with self._span("engine.decode_dispatch", slots=len(run)) as sp:
-                self.pool.buffers, toks_all = self._decode_fn(
-                    self.params, self.pool.buffers, jnp.asarray(self._tok),
-                    jnp.asarray(self._len), jnp.asarray(self._table),
+                bufs, toks_all, *counts = self._decode_fn(
+                    self.params, self._device_pool(), jnp.asarray(self._tok),
+                    jnp.asarray(self._len), self._device_tables(),
                     jnp.asarray(remaining), self._next_key())
+                self._store_pool(bufs)
+                self._moe_pending += [(c, len(run)) for c in counts]
             self._note_decode_dispatch(run)
             # stash the DISPATCHED call without syncing; slot objects ride
             # along so retirement can detect cancel/expire/slot-reuse
@@ -1946,6 +2115,7 @@ class ServingEngine:
         with self._span("engine.decode_sync") as sp:
             toks_all = np.asarray(jax.block_until_ready(toks_all))
         sync_s = sp.dur
+        self._fold_moe_counts()
         self.stats["decode_sync_s"] += sync_s
         self.stats["last_decode_sync_s"] = sync_s
         if self.metrics is not None:
@@ -2037,11 +2207,12 @@ class ServingEngine:
         # (check_invariants audits their bounds meanwhile)
         self._fault_point("verify")
         with self._span("engine.decode_dispatch", slots=len(run)) as sp:
-            self.pool.buffers, pred = self._verify_fn(
-                self.params, self.pool.buffers, jnp.asarray(self._tok),
+            bufs, pred = self._verify_fn(
+                self.params, self._device_pool(), jnp.asarray(self._tok),
                 jnp.asarray(draft), jnp.asarray(n_draft),
-                jnp.asarray(self._len), jnp.asarray(self._table),
+                jnp.asarray(self._len), self._device_tables(),
                 self._next_key())
+            self._store_pool(bufs)
         self._note_decode_dispatch(run)
         with self._span("engine.decode_sync") as sync:
             pred = np.asarray(pred)                  # (max_slots, k+1)
@@ -2100,6 +2271,12 @@ class ServingEngine:
         agree with the scheduler's free-slot list.  The serving tests'
         conftest fixture calls this after every step and cancel."""
         self.pool.check()
+        if self.ring is not None:
+            # the window group: no slot over its ring, no live page outside
+            # it, none given away while a later query still sees it
+            self.ring.check({
+                i: (int(self._len[i]) if s.started else s.prefilled)
+                for i, s in enumerate(self._slots) if s is not None})
         refs = sum(len(s.pages) for s in self._slots if s is not None)
         held = sum(self.pool.refcount)
         if held != refs:

@@ -324,3 +324,107 @@ class KVPool:
             "page_size": self.page_size,
             "head_dim": self.head_dim,
         }
+
+
+class WindowRing:
+    """The page group of sliding-window layers: a fixed ring of pages per
+    slot, no allocator.
+
+    A query at position ``q`` of a layer with window ``W`` sees keys
+    ``q - W + 1 .. q``, and one dispatch writes at most ``chunk`` positions
+    of a slot, so between the oldest key its first query can see and the
+    last position it writes lie at most ``R = pages_for(W + chunk) + 1``
+    pages.  Logical page ``j`` of slot ``i`` lives at pool page
+    ``1 + i * R + j % R`` (page 0 is the null page, as in :class:`KVPool`);
+    the slot's table row names it while it is live and the null page
+    otherwise, so the kernels read it exactly as they read the full group.
+    :meth:`advance` turns the ring before every dispatch that writes, in
+    chunked prefill and in decode alike: pages no later query can see go
+    dead, and the pages the dispatch writes go live on the ring positions
+    the dead ones left.  The group is sized from ``max_slots`` alone and
+    can never be why an allocation fails.
+
+    Buffers are ``(L_w, P_w, Hkv, page_size, D)`` like the full group's,
+    so the programs treat both groups alike (viewed ``(L_w * P_w, ...)``,
+    written in whole pages, read through ``table + j * P_w``)."""
+
+    def __init__(self, num_layers: int, kv_heads: int, head_dim: int,
+                 max_slots: int, max_pages: int, page_size: int,
+                 window: int, chunk_tokens: int, dtype=jnp.float32):
+        self.window, self.page_size = int(window), page_size
+        self.ring = math.ceil((self.window + chunk_tokens) / page_size) + 1
+        self.chunk_tokens = chunk_tokens
+        self.num_layers = num_layers
+        self.num_pages = 1 + max_slots * self.ring
+        shape = (num_layers, self.num_pages, kv_heads, page_size, head_dim)
+        self.buffers: Dict[str, jnp.ndarray] = {
+            "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        self.table = np.zeros((max_slots, max_pages), np.int32)
+        # live logical pages of each slot: [lo, hi)
+        self.lo = np.zeros((max_slots,), np.int64)
+        self.hi = np.zeros((max_slots,), np.int64)
+        self.recycled = 0     # ring pages given to a later logical page
+
+    def first_visible_page(self, pos: int) -> int:
+        """The logical page of the oldest key a query at ``pos`` sees."""
+        return max(0, pos - self.window + 1) // self.page_size
+
+    def advance(self, slot: int, start: int, end: int) -> None:
+        """Before a dispatch that writes positions ``[start, end)`` of
+        ``slot`` (its earliest query is at ``start``)."""
+        if end - start > self.chunk_tokens:
+            raise ValueError(f"a dispatch of {end - start} positions turns "
+                             f"a ring sized for {self.chunk_tokens}")
+        lo = self.first_visible_page(start)
+        hi = (end - 1) // self.page_size + 1
+        row, base = self.table[slot], 1 + slot * self.ring
+        row[self.lo[slot]:lo] = 0
+        for j in range(max(int(self.hi[slot]), lo), hi):
+            row[j] = base + j % self.ring
+            self.recycled += j >= self.ring
+        self.lo[slot], self.hi[slot] = lo, max(hi, int(self.hi[slot]))
+
+    def release(self, slot: int) -> None:
+        self.table[slot] = 0
+        self.lo[slot] = self.hi[slot] = 0
+
+    @property
+    def pages_in_use(self) -> int:
+        return int((self.hi - self.lo).sum())
+
+    def hbm_bytes(self) -> int:
+        return sum(b.size * b.dtype.itemsize for b in self.buffers.values())
+
+    def check(self, next_pos: Dict[int, int]) -> None:
+        """``next_pos``: occupied slot -> the next position it writes (and
+        its next query's position).  Every other slot's ring is empty; an
+        occupied slot holds at most the ring, every live page lies in its
+        own ring at its own place, nothing else is named, and every page a
+        later query can still see is live (so no ring page was given away
+        from under it)."""
+        ps = self.page_size
+        for slot in range(self.table.shape[0]):
+            row, lo, hi = self.table[slot], int(self.lo[slot]), \
+                int(self.hi[slot])
+            if slot not in next_pos:
+                if row.any() or lo or hi:
+                    raise AssertionError(
+                        f"window ring of empty slot {slot} is not empty")
+                continue
+            if hi - lo > self.ring:
+                raise AssertionError(
+                    f"slot {slot} holds {hi - lo} window pages; the ring "
+                    f"is {self.ring}")
+            want = np.zeros_like(row)
+            js = np.arange(lo, hi)
+            want[lo:hi] = 1 + slot * self.ring + js % self.ring
+            if not np.array_equal(row, want):
+                raise AssertionError(
+                    f"slot {slot}: window table row names pages outside "
+                    f"its ring or live range [{lo}, {hi})")
+            pos = next_pos[slot]
+            if pos > 0 and (lo > self.first_visible_page(pos)
+                            or hi < -(-pos // ps)):
+                raise AssertionError(
+                    f"slot {slot}: a query at {pos} still sees pages "
+                    f"outside the live range [{lo}, {hi})")

@@ -407,6 +407,47 @@ def test_paged_prefill_kernel_matches_ref_float():
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("window", [None, 40])
+@pytest.mark.parametrize("kv_bits", [None, 8])
+def test_paged_prefill_kernel_tiled_by_kv_head_matches_ref(window, kv_bits):
+    """GQA with a sublane-aligned group (16 query heads over 2 KV heads):
+    the kernel's grid runs over (KV heads, pages) and a block is one KV
+    head's group of 8, rows and heads merged into one matmul dimension
+    (``paged_prefill.block_heads``).  Same answers as the jnp oracle, with
+    and without a sliding window, float and int8 pages."""
+    from paddle_tpu.ops.quant_ops import quantize_per_token
+
+    rng = np.random.RandomState(43)
+    C, H, HKV, D, PS, MAXP, P = 16, 16, 2, 32, 8, 12, 20
+    assert pp.block_heads(H, PS, D, C, HKV) == H // HKV
+    q = jnp.asarray(rng.randn(C, H, D).astype("float32"))
+    kp = jnp.asarray(rng.randn(P, HKV, PS, D).astype("float32"))
+    vp = jnp.asarray(rng.randn(P, HKV, PS, D).astype("float32"))
+    bt = jnp.asarray(rng.permutation(np.arange(1, P))[:MAXP].astype("int32"))
+    kw = {}
+    if kv_bits:
+        (kp, kw["k_scales"]), (vp, kw["v_scales"]) = (
+            quantize_per_token(kp), quantize_per_token(vp))
+    for start in (0, 21, 77):
+        out = pp.paged_prefill(q, kp, vp, bt, start, window=window,
+                               interpret=True, **kw)
+        ref = pp.paged_prefill_ref(q, kp, vp, bt, start, window=window, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_paged_prefill_block_follows_the_shapes():
+    # MHA keeps the whole block (Cerebras-GPT 1.3B: 16 heads of 128)
+    assert pp.block_heads(16, 64, 128, 128, 16) == 16
+    # 128 query heads over 8 KV heads: one KV head's 16 at a time
+    assert pp.block_heads(128, 64, 128, 128, 8) == 16
+    assert pp.supported(128, 64, 128, 128, n_kv_heads=8)
+    # a group that is not sublane-aligned stays whole, if it fits
+    assert pp.block_heads(8, 64, 128, 128, 2) == 8
+    assert pp.block_heads(128, 64, 128, 128, 32) is None
+    assert not pp.supported(128, 64, 128, 128, n_kv_heads=32)
+
+
 def test_paged_prefill_kernel_matches_ref_int8():
     from paddle_tpu.ops.quant_ops import quantize_per_token
 

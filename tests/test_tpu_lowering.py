@@ -194,3 +194,137 @@ def test_serving_program_keeps_the_pool_in_place(monkeypatch, one_chip,
     assert not moved, f"pool-sized copies or slices: {sorted(set(moved))}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < temp_bound, (temp, temp_bound, layer)
+
+
+# ---------------------------------------------------------------------------
+# two page groups and an expert layer (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+def _two_group_engine(monkeypatch):
+    """An engine over a ``cohere2_moe`` model described by shapes alone
+    (no weights are made): 4 layers (sliding x 3, full), 32 query heads
+    over 4 KV heads of 128, 8 of 16 experts held.  Both page groups and
+    each expert stack are larger than the chip's 128 MiB of VMEM, so a
+    copy of one would not be a prefetch."""
+    from paddle_tpu.kernels import paged_attention as pa
+    from paddle_tpu.kernels import paged_prefill as pp
+    from paddle_tpu.models import Cohere2MoeConfig, Cohere2MoeForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    monkeypatch.setattr(pa, "_backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pp, "_backend_is_tpu", lambda: True)
+    cfg = Cohere2MoeConfig(
+        vocab_size=1024, hidden_size=2048, num_layers=4, num_heads=32,
+        num_kv_heads=4, head_dim=128, intermediate_size=4096,
+        num_experts=16, num_experts_per_tok=2, num_shared_experts=1,
+        experts_held=(8, 8), sliding_window=2048, max_seq_len=4096,
+        dtype="bfloat16")
+
+    class ShapesOnly:
+        layer_specs = Cohere2MoeForCausalLM.layer_specs
+
+        def __init__(self):
+            self.cfg = cfg
+
+        def decoder_params(self):
+            def leaf(shape):
+                return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+            return {"wte": leaf((cfg.vocab_size, cfg.hidden_size)),
+                    "lnf_g": leaf((cfg.hidden_size,)),
+                    "blocks": [{n: leaf(s)
+                                for n, (s, _) in cfg.leaf_shapes().items()}
+                               for _ in range(cfg.num_layers)]}
+
+    eng = ServingEngine(ShapesOnly(), max_slots=64, page_size=_PAGE,
+                        max_seq_len=4096, num_pages=2305)
+    assert eng.ring is not None
+    assert set(eng.attention_paths().values()) == {"kernel"}
+    return eng
+
+
+_TWO_GROUP = {}
+
+
+def _two_group_program(monkeypatch, one_chip, program):
+    """(the engine, the program compiled for one v5e chip), compiled once."""
+    if program not in _TWO_GROUP:
+        eng = _two_group_engine(monkeypatch)
+
+        def on_chip(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+        params, bufs, key = jax.tree_util.tree_map(
+            on_chip, (eng.params, eng._device_pool(), eng._key))
+        s, mp = eng.max_slots, eng.max_pages
+        fn, args = {
+            "decode": (eng._decode_fn, (ints(s), ints(s),
+                                        (ints(s, mp), ints(s, mp)), ints(s))),
+            "prefill": (eng._prefill_fn, (ints(eng.chunk_tokens), ints(),
+                                          ints(), (ints(mp), ints(mp)),
+                                          ints())),
+        }[program]
+        _TWO_GROUP[program] = (
+            eng, fn.lower(params, bufs, *args, key).compile())
+    return _TWO_GROUP[program]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_two_group_program_keeps_pools_and_experts_in_place(
+        monkeypatch, one_chip, program):
+    """Both page groups stay under PR 25's invariant (no copy or slice the
+    size of a layer of either group), the expert weights are read where
+    they lie (no copy, slice or transpose of an expert stack), and the
+    temporaries stay under one layer of the smaller group."""
+    eng, compiled = _two_group_program(monkeypatch, one_chip, program)
+    layer = min(int(np.prod(g["k"].shape[1:])) * 2
+                for g in (eng.pool.buffers, eng.ring.buffers))
+    stack = int(np.prod(eng.params["blocks"][0]["gate_w"].shape)) * 2
+    assert layer > 128 << 20 and stack >= 128 << 20
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(3) in _MOVES + ("transpose",):
+            dtype, dims, op = m.groups()
+            n = _BYTES.get(dtype, 4) * int(np.prod(
+                [int(d) for d in dims.split(",") if d] or [1]))
+            if n >= min(layer, stack):
+                moved.append(f"{op} {dtype}[{dims}]")
+    assert not moved, f"group- or expert-sized moves: {sorted(set(moved))}"
+    assert compiled.memory_analysis().temp_size_in_bytes < layer
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_expert_operations_carry_their_scope(monkeypatch, one_chip, program):
+    """``moe_ffn`` computes under ``jax.named_scope("moe_ffn")``, which is
+    how a compiled program's expert operations are told from the rest (the
+    benchmark holds its trace pattern to it): every instruction of the
+    entry computation that reads an expert stack lies in that scope, and
+    the scope holds the stacked products, ``(experts, rows, width)`` either
+    way round, of the held and of the shared experts of every layer."""
+    eng, compiled = _two_group_program(monkeypatch, one_chip, program)
+    text = compiled.as_text()
+    entry = re.search(r"^ENTRY .*?^\}", text, re.S | re.M).group(0)
+    blk = eng.params["blocks"][0]
+    stacks = {"bf16[" + ",".join(map(str, blk[n].shape)) + "]"
+              for n in ("gate_w", "down_w", "sh_gate_w", "sh_down_w")}
+    rows = eng.max_slots if program == "decode" else eng.chunk_tokens
+    held, shared = blk["gate_w"].shape[0], blk["sh_gate_w"].shape[0]
+    products = {n: 0 for n in (held, shared)}
+    for line in entry.splitlines():
+        m = _RESULT.match(line)
+        if not m or m.group(3) in ("parameter", "get-tuple-element",
+                                   "bitcast", "tuple"):
+            continue
+        scoped = re.search(r'op_name="[^"]*moe_ffn', line) is not None
+        operands = line.split("(", 1)[1]
+        if any(st in operands for st in stacks):
+            assert scoped, f"reads an expert stack outside moe_ffn: {line}"
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if scoped and len(dims) == 3 and dims[0] in products \
+                and rows in dims[1:]:
+            products[dims[0]] += 1
+    assert all(n >= _LAYERS for n in products.values()), products
