@@ -210,6 +210,46 @@ def _decode_case(*, required, n_kv=HEADS, kv_bits=None, window=None):
                       (q, kp, vp, tables, lengths, scales))
 
 
+def _cell_decode_case(*, required, slots, heads, n_kv, max_pages,
+                      window=None):
+    """The decode kernel at a benchmark cell's own shape and at ragged
+    lengths: one token, a page, a page and one, a full table, a context
+    past longshort's window, a few chat-sized ones, and the rest idle lanes
+    (nothing to attend, every table entry the null page).  Live entries
+    name pages of a small pool (shared between slots: the kernel only
+    reads); dead ones the null page."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import paged_attention as pa
+
+    rng = np.random.RandomState(0)
+    n_pages = 1 + 256
+    kp, vp = (jnp.asarray(
+        rng.randn(n_pages, n_kv, PAGE, HEAD_DIM).astype("float32"),
+        jnp.bfloat16) for _ in range(2))
+    lengths = np.ones((slots,), np.int32)
+    ragged = [1, PAGE, PAGE + 1, max_pages * PAGE,
+              min(5000, max_pages * PAGE - 1), *rng.randint(100, 700, (6,))]
+    lengths[rng.choice(slots, len(ragged), replace=False)] = ragged
+    live = -(-lengths // PAGE) * (lengths > 1)
+    tables = np.zeros((slots, max_pages), np.int32)
+    for i, n in enumerate(live):
+        tables[i, :n] = 1 + (rng.randint(256) + np.arange(n)) % 256
+    q = jnp.asarray(rng.randn(slots, heads, HEAD_DIM).astype("float32"),
+                    jnp.bfloat16)
+
+    def kernel(q, kp, vp, tables, lengths):
+        return (pa.paged_attention(q, kp, vp, tables, lengths, window=window,
+                                   interpret=False),)
+
+    def oracle(q, kp, vp, tables, lengths):
+        return (pa.paged_attention_ref(q, kp, vp, tables, lengths,
+                                       window=window),)
+
+    return KernelCase(required, TOL_FWD, kernel, oracle,
+                      (q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths)))
+
+
 def _verify_case(*, required, kv_bits=None):
     import jax.numpy as jnp
 
@@ -295,6 +335,15 @@ KERNEL_CASES = {
     "paged_attention_int8": (_decode_case, dict(required=True, kv_bits=8)),
     "paged_prefill_int8": (_prefill_case, dict(required=True, kv_bits=8)),
     "paged_attention_mq_fp": (_verify_case, dict(required=True)),
+    # the serving cells' own decode shapes (cerebras-gpt-1.3b; Command A+'s
+    # full and sliding layers), at ragged lengths
+    "paged_attention_cell_1.3b": (_cell_decode_case, dict(
+        required=True, slots=64, heads=16, n_kv=16, max_pages=32)),
+    "paged_attention_cell_gqa16": (_cell_decode_case, dict(
+        required=True, slots=32, heads=128, n_kv=8, max_pages=256)),
+    "paged_attention_cell_gqa16_window": (_cell_decode_case, dict(
+        required=True, slots=32, heads=128, n_kv=8, max_pages=256,
+        window=4096)),
     "w8a8_gemm_chunk": (_w8a8_case, dict(
         required=True, m=CHUNK, k=_H, n=3 * _H)),
     # attempted and reported

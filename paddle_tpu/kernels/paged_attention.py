@@ -10,16 +10,27 @@ HBM bandwidth; with a PAGED cache the valid positions of a sequence live
 scattered across pool pages, so the kernel must gather them through the
 slot's block table.  Design (pallas_guide.md):
 
-  * grid = (slots, pages-per-slot); the block table and per-slot lengths
-    ride in as SCALAR-PREFETCH args (``pltpu.PrefetchScalarGridSpec``) so
-    the K/V page picked by grid step (b, p) is ``block_table[b, p]`` —
-    the gather happens in the BlockSpec index_map, i.e. it IS the DMA
-    schedule, no materialized gather in HBM;
+  * the grid is the LIVE WORK and nothing else: one axis over the
+    (slot, logical page) pairs some query row of the slot can see, in slot
+    order.  :func:`live_pages` is the one definition of that range (the
+    pages of the visible positions ``[max(0, len - window), len)``);
+    :func:`_page_walk` lists the pairs from ``lengths`` with ``jnp`` inside
+    the program, and the list, its count (the traced grid bound), the
+    block table and the lengths ride in as SCALAR-PREFETCH args
+    (``pltpu.PrefetchScalarGridSpec``).  The K/V page of step ``w`` is
+    ``block_table[slot[w], page[w]]`` — the gather happens in the BlockSpec
+    index_map, i.e. it IS the DMA schedule, no materialized gather in HBM
+    — and the output block is ``slot[w]``'s.  A decode therefore costs the
+    context it attends: no grid step, DMA or score for a table entry past
+    a slot's length or under its window, one step for a lane with nothing
+    to attend (``lengths >= 1`` is the contract; the engine passes
+    ``lengths + 1``);
   * one program holds one (H, page_size, D) K page + V page in VMEM and
-    runs the flash online-softmax recurrence (m/l/acc scratch carried
-    across the sequential page axis), masking positions >= the slot's
-    length — pages past the end contribute nothing, and the pool's
-    reserved null page (page 0) is never read unmasked;
+    runs the flash online-softmax recurrence (m/l/acc scratch initialised
+    on a slot's first pair and divided out on its last), masking the
+    in-page positions outside the visible range — the pool's reserved
+    null page (page 0) is never read unmasked, and a page outside the
+    live range is never read at all (it may hold anything);
   * int8 pages (serving with ``int8=True``) carry fp32 per-position
     scales; the dequant multiply happens in VMEM right after the page
     DMA, fused into the attention compute — HBM streams int8 values +
@@ -119,20 +130,63 @@ def _unpack4_vmem(pk):
     return unpack_int4(pk).astype(jnp.float32)
 
 
-def _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                     page_size, scale, window=None, n_kv=None):
-    """The ONE online-softmax page step shared by the float/int8/int4
-    kernel entries (only how k/v are materialized in VMEM differs): init
-    scratch on the first page, score + length-mask this page (plus the
-    sliding-window lower bound when ``window`` is set), fold it into the
-    m/l/acc flash recurrence, divide out on the last page.  Under GQA
-    (``n_kv`` < q's head count) the query heads regroup over the shared
-    K/V head with leading-dim reshapes — K/V stay at ``n_kv`` heads in
-    VMEM, never repeated."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+def visible_positions(lengths, window=None, rows=1):
+    """Positions ``[lo, hi)`` visible to a block of ``rows`` causal query
+    rows whose FIRST row sees ``lengths`` positions, its own included (a
+    decode is one row): row ``i`` sees ``[max(0, lengths + i - window),
+    lengths + i)``, and this is their union.  THE definition of what paged
+    decode attends — the kernels' masks, the reference's mask, the pages
+    the kernels walk (:func:`live_pages`) and the engine's
+    ``decode_pages_walked`` counter all call it.  Plain arithmetic on
+    numpy values, jax arrays or a kernel's prefetched scalars alike."""
+    xp = jnp if isinstance(lengths, jax.Array) else np
+    lo = 0 if window is None else xp.maximum(lengths - window, 0)
+    return lo, lengths + (rows - 1)
 
-    @pl.when(p == 0)
+
+def live_pages(lengths, page_size, window=None, rows=1, max_pages=None):
+    """Logical pages ``[lo, hi)`` that hold :func:`visible_positions`: all
+    the kernels read of a slot's ``max_pages`` table entries.  Never empty:
+    a lane with nothing to attend keeps one page, so it takes one grid
+    step and its output is defined."""
+    xp = jnp if isinstance(lengths, jax.Array) else np
+    lo, hi = visible_positions(lengths, window, rows)
+    hi = -(-hi // page_size)
+    if max_pages is not None:
+        hi = xp.minimum(hi, max_pages)
+    hi = xp.maximum(hi, 1)
+    return xp.minimum(lo // page_size, hi - 1), hi
+
+
+def _page_walk(seen, page_size, max_pages, window, rows):
+    """The kernels' grid as data: the live (slot, logical page) pairs of
+    :func:`live_pages` in slot order — ``slot_of`` and ``page_of``, padded
+    with valid ids past the static worst case ``slots x max_pages`` — and
+    their count, the grid's traced bound.  Compares and sums only (no
+    gather, no sort), one small fusion per distinct window of a program."""
+    b = seen.shape[0]
+    lo, hi = live_pages(seen, page_size, window, rows, max_pages)
+    ends = jnp.cumsum(hi - lo)
+    starts = ends - (hi - lo)
+    # one entry more than the worst case: the pipeline reads the NEXT
+    # step's ids at every step, the last included
+    w = jnp.arange(b * max_pages + 1, dtype=jnp.int32)[:, None]
+    slot_of = jnp.minimum(jnp.sum(w >= ends, axis=1), b - 1)
+    mine = (w >= starts) & (w < ends)                      # (pairs, slots)
+    page_of = jnp.sum(jnp.where(mine, w - starts + lo, 0), axis=1)
+    return slot_of.astype(jnp.int32), page_of.astype(jnp.int32), ends[-1]
+
+
+def _page_recurrence(seen, p, first, last, q_ref, k, v, o_ref, m_ref, l_ref,
+                     acc_ref, page_size, scale, window=None, n_kv=None):
+    """The ONE online-softmax page step of the single-query kernel: init
+    scratch on the slot's first live page, score this page and mask it to
+    :func:`visible_positions`, fold it into the m/l/acc flash recurrence,
+    divide out on the slot's last page.  Under GQA (``n_kv`` < q's head
+    count) the query heads regroup over the shared K/V head with
+    leading-dim reshapes — K/V stay at ``n_kv`` heads in VMEM, never
+    repeated."""
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -151,9 +205,10 @@ def _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     s = s.reshape(h, page_size)                            # (H, ps)
     base = p * jnp.int32(page_size)
     pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    keep = pos < len_ref[b]
+    lo, hi = visible_positions(seen, window)
+    keep = pos < hi
     if window is not None:
-        keep = keep & (pos >= len_ref[b] - jnp.int32(window))
+        keep = keep & (pos >= lo)
     s = jnp.where(keep, s, jnp.float32(_NEG_INF))
 
     m_prev = m_ref[:, :1]                                  # (H, 1)
@@ -167,40 +222,83 @@ def _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(p == pl.num_programs(1) - 1)
+    @pl.when(last)
     def _finish():
         o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page_size, scale, window=None,
-                  n_kv=None):
-    k = k_ref[0].astype(jnp.float32)                       # (Hkv, ps, D)
-    v = v_ref[0].astype(jnp.float32)
-    _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                     page_size, scale, window=window, n_kv=n_kv)
+def _walk_kernel(bt_ref, len_ref, slot_ref, page_ref, q_ref, *refs, fold,
+                 kv_bits, rows, max_pages, page_size, window, **fold_kw):
+    """The body both kernels share: find this grid step's (slot, logical
+    page) and whether it opens or closes the slot's live range, materialize
+    the K/V page in VMEM — float pages cast; int8 pages times their fp32
+    per-(head, position) scales, the dequant fused right after the page
+    DMA; int4 pages nibble-unpacked first — and hand it to ``fold``, the
+    single-query or the multi-query recurrence."""
+    *kv, o_ref, m_ref, l_ref, acc_ref = refs
+    if kv_bits is None:
+        k = kv[0][0].astype(jnp.float32)                   # (Hkv, ps, D)
+        v = kv[1][0].astype(jnp.float32)
+    else:
+        k_ref, ks_ref, v_ref, vs_ref = kv
+        widen = (_unpack4_vmem if kv_bits == 4
+                 else lambda x: x.astype(jnp.float32))
+        k = widen(k_ref[0]) * ks_ref[0]
+        v = widen(v_ref[0]) * vs_ref[0]
+    w = pl.program_id(0)
+    b, p = slot_ref[w], page_ref[w]
+    seen = len_ref[b]
+    lo, hi = live_pages(seen, page_size, window, rows, max_pages)
+    fold(seen, p, p == lo, p == hi - 1, q_ref, k, v, o_ref, m_ref, l_ref,
+         acc_ref, page_size, window=window, **fold_kw)
 
 
-# the int8 entry has its own arity (scale refs) but the same recurrence
-def _paged_kernel_int8(bt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                       o_ref, m_ref, l_ref, acc_ref, *, page_size, scale,
-                       window=None, n_kv=None):
-    # dequant fused right after the page DMA: int8 values * fp32
-    # per-(head, position) scale, in VMEM
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0]           # (Hkv, ps, D)
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0]
-    _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                     page_size, scale, window=window, n_kv=n_kv)
+def _walk_call(name, fold, q, k_pages, v_pages, block_tables, seen, *,
+               k_scales, v_scales, rows, window, scratch_shapes, interpret,
+               **fold_kw):
+    """One ``pallas_call`` over the live (slot, page) pairs of ``seen``
+    (what each slot's first query row sees) — the grid, the scalar
+    prefetch and the index maps of both paged decode kernels.  ``q`` is
+    (B, ...) and is blocked, like the output, one slot at a time."""
+    h, d = q.shape[-2:]
+    _, hkv, ps, d_store = k_pages.shape
+    max_pages = block_tables.shape[1]
+    if interpret is None:
+        interpret = not _backend_is_tpu()
+    win = None if window is None else int(window)
+    kv = (k_pages, v_pages) if k_scales is None else \
+        (k_pages, k_scales, v_pages, v_scales)
+    kv_bits = None if k_scales is None else (8 if d_store == d else 4)
+    seen = seen.astype(jnp.int32)
+    slot_of, page_of, n_pairs = _page_walk(seen, ps, max_pages, win, rows)
 
+    def at_slot(w, bt, ln, sl, pg):
+        return (sl[w],) + (0,) * (q.ndim - 1)
 
-# the int4 entry: packed nibble pages, unpack + dequant fused after the DMA
-def _paged_kernel_int4(bt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                       o_ref, m_ref, l_ref, acc_ref, *, page_size, scale,
-                       window=None, n_kv=None):
-    k = _unpack4_vmem(k_ref[0]) * ks_ref[0]                # (Hkv, ps, D)
-    v = _unpack4_vmem(v_ref[0]) * vs_ref[0]
-    _page_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                     page_size, scale, window=window, n_kv=n_kv)
+    def at_page(w, bt, ln, sl, pg):
+        return (bt[sl[w], pg[w]], 0, 0, 0)
+
+    q_spec = pl.BlockSpec((1,) + q.shape[1:], at_slot)
+    kernel = functools.partial(
+        _walk_kernel, fold=fold, kv_bits=kv_bits, rows=rows,
+        max_pages=max_pages, page_size=ps, window=win,
+        n_kv=None if hkv == h else hkv, **fold_kw)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_pairs,),
+        in_specs=[q_spec] + [pl.BlockSpec((1,) + a.shape[1:], at_page)
+                             for a in kv],
+        out_specs=q_spec,
+        scratch_shapes=scratch_shapes,
+    )
+    with _x64_off():
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=interpret,
+            name=name,
+        )(block_tables.astype(jnp.int32), seen, slot_of, page_of, q, *kv)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
@@ -213,79 +311,38 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     pages never repeat) — or int8 with ``k_scales``/``v_scales``
     (P, Hkv, page_size, 1) fp32, or PACKED int4 (last dim D // 2, two
     nibbles per byte — detected from the shape) with the same scales
-    layout; ``block_tables`` (B, max_pages) int32 page ids (padding
-    entries must reference a valid page — the pool's null page 0);
-    ``lengths`` (B,) int32 valid-position counts.  ``window`` masks
-    positions below ``lengths - window`` (sliding-window attention — the
-    engine's recycled ring pages point at the null page and fall under
-    this bound).  Returns (B, H, D) in q.dtype.  Callers gate on
+    layout; ``block_tables`` (B, max_pages) int32 page ids — every entry
+    must name a valid page, but only those of :func:`live_pages` are read
+    (padding is the pool's null page 0; a lane with nothing to attend
+    reads its first entry); ``lengths`` (B,) int32 valid-position counts,
+    at least 1 (the engine passes ``lengths + 1``: the row just written).
+    ``window`` masks positions below ``lengths - window`` (sliding-window
+    attention) and the pages wholly under that bound are not walked.
+    Returns (B, H, D) in q.dtype.  Callers gate on
     :func:`available`/:func:`supported` first.
     """
-    b, h, d = q.shape
-    _, hkv, ps, d_store = k_pages.shape
-    max_pages = block_tables.shape[1]
+    h, d = q.shape[1:]
     if scale is None:
         scale = 1.0 / np.sqrt(d)
-    scale = np.float32(scale)
-    if interpret is None:
-        interpret = not _backend_is_tpu()
-    win = None if window is None else int(window)
-    nkv = None if hkv == h else hkv
-    quant = k_scales is not None
-    int4 = quant and d_store != d
-
-    q_spec = pl.BlockSpec((1, h, d), lambda b, p, bt, ln: (b, 0, 0))
-    pg_spec = pl.BlockSpec((1, hkv, ps, d_store),
-                           lambda b, p, bt, ln: (bt[b, p], 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, hkv, ps, 1),
-                           lambda b, p, bt, ln: (bt[b, p], 0, 0, 0))
-    if quant:
-        kern = _paged_kernel_int4 if int4 else _paged_kernel_int8
-        kernel = functools.partial(kern, page_size=ps, scale=scale,
-                                   window=win, n_kv=nkv)
-        in_specs = [q_spec, pg_spec, sc_spec, pg_spec, sc_spec]
-        args = (q, k_pages, k_scales, v_pages, v_scales)
-    else:
-        kernel = functools.partial(_paged_kernel, page_size=ps, scale=scale,
-                                   window=win, n_kv=nkv)
-        in_specs = [q_spec, pg_spec, pg_spec]
-        args = (q, k_pages, v_pages)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda b, p, bt, ln: (b, 0, 0)),
+    return _walk_call(
+        "paged_attention", _page_recurrence, q, k_pages, v_pages,
+        block_tables, lengths, k_scales=k_scales, v_scales=v_scales,
+        rows=1, window=window, interpret=interpret, scale=np.float32(scale),
         scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32),   # running max
                         pltpu.VMEM((h, 128), jnp.float32),   # running denom
-                        pltpu.VMEM((h, d), jnp.float32)],    # weighted acc
-    )
-    with _x64_off():
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-            interpret=interpret,
-            name="paged_attention",
-        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
+                        pltpu.VMEM((h, d), jnp.float32)])    # weighted acc
 
 
-def _mq_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                   page_size, scale, t, window=None, n_kv=None):
+def _mq_recurrence(seen, p, first, last, q_ref, k, v, o_ref, m_ref, l_ref,
+                   acc_ref, page_size, scale, t, window=None, n_kv=None):
     """The online-softmax page step of the MULTI-query (speculative
-    verify) kernel: q_tile rows per slot, row i at global position
-    ``lengths[b] + i``, causally visible to page position j iff
-    ``j <= lengths[b] + i`` — the paged_prefill causal rule with the
-    slot's length as the chunk start, batched over slots like the decode
-    kernel; ``window`` adds the sliding-window lower bound
-    ``j > lengths[b] + i - window``.  GQA (``n_kv``) regroups query heads
-    over the shared K/V head with leading-dim reshapes, like the decode
-    recurrence.  Shared by the float/int8/int4 entries (only how k/v
-    materialize in VMEM differs)."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
+    verify) kernel: ``t`` rows per slot, row i seeing
+    ``visible_positions(seen + i)`` — the paged_prefill causal rule with
+    the slot's length as the chunk start, batched over slots like the
+    decode kernel, with the same sliding-window lower bound.  GQA
+    (``n_kv``) regroups query heads over the shared K/V head with
+    leading-dim reshapes, like the decode recurrence."""
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -305,10 +362,11 @@ def _mq_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                        preferred_element_type=jnp.float32) * scale
     pos = p * jnp.int32(page_size) + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, page_size), 2)
-    qpos = len_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (1, t, 1), 1)
-    keep = pos <= qpos
+    lo, hi = visible_positions(
+        seen + jax.lax.broadcasted_iota(jnp.int32, (1, t, 1), 1), window)
+    keep = pos < hi
     if window is not None:
-        keep = keep & (pos > qpos - jnp.int32(window))
+        keep = keep & (pos >= lo)
     s = jnp.where(keep, s, jnp.float32(_NEG_INF))
 
     m_prev = m_ref[...]                                    # (H, T)
@@ -328,39 +386,10 @@ def _mq_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     acc_ref[...] = acc_ref[...] * alpha[:, :, None] + upd
     m_ref[...] = m_new
 
-    @pl.when(p == pl.num_programs(1) - 1)
+    @pl.when(last)
     def _finish():
         out = acc_ref[...] / l_ref[...][:, :, None]        # (H, T, D)
         o_ref[0] = jnp.einsum("htd->thd", out).astype(o_ref.dtype)
-
-
-def _mq_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref, *, page_size, scale, t, window=None,
-               n_kv=None):
-    k = k_ref[0].astype(jnp.float32)                       # (Hkv, ps, D)
-    v = v_ref[0].astype(jnp.float32)
-    _mq_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                   page_size, scale, t, window=window, n_kv=n_kv)
-
-
-# the int8 entry has its own arity (scale refs) but the same recurrence
-def _mq_kernel_int8(bt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                    o_ref, m_ref, l_ref, acc_ref, *, page_size, scale, t,
-                    window=None, n_kv=None):
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0]           # (Hkv, ps, D)
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0]
-    _mq_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                   page_size, scale, t, window=window, n_kv=n_kv)
-
-
-# the int4 entry: packed nibble pages, unpack + dequant fused after the DMA
-def _mq_kernel_int4(bt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                    o_ref, m_ref, l_ref, acc_ref, *, page_size, scale, t,
-                    window=None, n_kv=None):
-    k = _unpack4_vmem(k_ref[0]) * ks_ref[0]                # (Hkv, ps, D)
-    v = _unpack4_vmem(v_ref[0]) * vs_ref[0]
-    _mq_recurrence(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                   page_size, scale, t, window=window, n_kv=n_kv)
 
 
 def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths, *,
@@ -374,7 +403,9 @@ def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths, *,
     positions valid BEFORE the block (the block's own K/V must already be
     written into the pages, like paged_prefill).  Row i attends to page
     position j iff ``j <= lengths[b] + i``: the history AND the block's
-    earlier rows, causally.  Other operands as :func:`paged_attention`.
+    earlier rows, causally.  Other operands as :func:`paged_attention`,
+    and the same walk: the pages some row of the block sees
+    (``live_pages(lengths + 1, rows=T)``), no others.
     Returns (B, T, H, D) in q.dtype.
 
     T == 1 degenerates exactly to the single-query decode kernel (mask
@@ -390,57 +421,19 @@ def paged_attention_mq(q, k_pages, v_pages, block_tables, lengths, *,
                               v_scales=v_scales, scale=scale,
                               interpret=interpret, window=window)
         return out[:, None]
-    _, hkv, ps, d_store = k_pages.shape
-    max_pages = block_tables.shape[1]
     if scale is None:
         scale = 1.0 / np.sqrt(d)
-    scale = np.float32(scale)
-    if interpret is None:
-        interpret = not _backend_is_tpu()
-    win = None if window is None else int(window)
-    nkv = None if hkv == h else hkv
-    quant = k_scales is not None
-    int4 = quant and d_store != d
-
     tp = _pad_q_tile(t)
     if tp != t:
         q = jnp.pad(q, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
-
-    q_spec = pl.BlockSpec((1, tp, h, d), lambda b, p, bt, ln: (b, 0, 0, 0))
-    pg_spec = pl.BlockSpec((1, hkv, ps, d_store),
-                           lambda b, p, bt, ln: (bt[b, p], 0, 0, 0))
-    sc_spec = pl.BlockSpec((1, hkv, ps, 1),
-                           lambda b, p, bt, ln: (bt[b, p], 0, 0, 0))
-    if quant:
-        kern = _mq_kernel_int4 if int4 else _mq_kernel_int8
-        kernel = functools.partial(kern, page_size=ps, scale=scale, t=tp,
-                                   window=win, n_kv=nkv)
-        in_specs = [q_spec, pg_spec, sc_spec, pg_spec, sc_spec]
-        args = (q, k_pages, k_scales, v_pages, v_scales)
-    else:
-        kernel = functools.partial(_mq_kernel, page_size=ps, scale=scale,
-                                   t=tp, window=win, n_kv=nkv)
-        in_specs = [q_spec, pg_spec, pg_spec]
-        args = (q, k_pages, v_pages)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, max_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tp, h, d),
-                               lambda b, p, bt, ln: (b, 0, 0, 0)),
+    out = _walk_call(
+        "paged_attention_mq", _mq_recurrence, q, k_pages, v_pages,
+        block_tables, lengths + 1, k_scales=k_scales, v_scales=v_scales,
+        rows=t, window=window, interpret=interpret, scale=np.float32(scale),
+        t=tp,
         scratch_shapes=[pltpu.VMEM((h, tp), jnp.float32),    # running max
                         pltpu.VMEM((h, tp), jnp.float32),    # running denom
-                        pltpu.VMEM((h, tp, d), jnp.float32)],  # weighted acc
-    )
-    with _x64_off():
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, tp, h, d), q.dtype),
-            interpret=interpret,
-            name="paged_attention_mq",
-        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
+                        pltpu.VMEM((h, tp, d), jnp.float32)])  # weighted acc
     return out[:, :t]
 
 
@@ -507,9 +500,8 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     else:
         s = s * jnp.float32(scale)
     pos = jnp.arange(s_max, dtype=jnp.int32)[None, :]
-    keep = pos < lengths[:, None]
-    if window is not None:
-        keep = keep & (pos >= lengths[:, None] - window)
+    lo, hi = visible_positions(lengths[:, None], window)
+    keep = (pos >= lo) & (pos < hi)
     bmask = keep[:, None, None] if grouped else keep[:, None]
     s = jnp.where(bmask, s, _NEG_INF)
     att = jax.nn.softmax(s, axis=-1).astype(v_eff.dtype)
@@ -551,11 +543,12 @@ def paged_attention_mq_ref(q, k_pages, v_pages, block_tables, lengths, *,
     else:
         s = s * jnp.float32(scale)
     pos = jnp.arange(s_max, dtype=jnp.int32)[None, None, :]
-    qpos = lengths[:, None, None] + jnp.arange(t, dtype=jnp.int32)[None, :,
-                                                                   None]
-    keep = pos <= qpos
-    if window is not None:
-        keep = keep & (pos > qpos - window)
+    # row i sees what a single query with lengths + 1 + i valid positions
+    # sees: j <= lengths + i, and under a window j > lengths + i - window
+    lo, hi = visible_positions(
+        lengths[:, None, None] + 1
+        + jnp.arange(t, dtype=jnp.int32)[None, :, None], window)
+    keep = (pos >= lo) & (pos < hi)
     bmask = keep[:, None, None] if grouped else keep[:, None]
     s = jnp.where(bmask, s, _NEG_INF)
     att = jax.nn.softmax(s, axis=-1).astype(v_eff.dtype)

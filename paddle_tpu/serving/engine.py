@@ -78,6 +78,7 @@ over to per-page scales unchanged.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import time
@@ -370,6 +371,10 @@ class ServingEngine:
         # the model as the programs read it: one LayerSpec a layer (GPT-2's
         # for a GPT) and the parameter tree
         self.layers = decoder_layers(model, attn_window)
+        # layers per distinct window (None: full attention), for the
+        # pages-walked counter
+        self._window_layers = collections.Counter(
+            spec.window for spec in self.layers)
         if hasattr(model, "decoder_params"):
             if int8:
                 raise ValueError("int8 projections are GPT's (_decoder_setup)")
@@ -563,6 +568,10 @@ class ServingEngine:
                       # context lengths the decode dispatches attended
                       **dict.fromkeys(_DECODE_AFTER, 0),
                       "decode_attended_tokens": 0,
+                      # what the decode kernels walked, summed over lanes
+                      # and layers (the kernels' own live range), beside
+                      # what every table entry of every lane would be
+                      "decode_pages_walked": 0, "decode_pages_in_table": 0,
                       # disaggregation traffic (r15)
                       "handoffs_out": 0, "handoffs_in": 0,
                       "handoff_bytes": 0, "handoff_faults": 0,
@@ -2053,11 +2062,27 @@ class ServingEngine:
             st["moe_layer_passes"] += len(per_layer)
         self._moe_pending.clear()
 
-    def _note_decode_dispatch(self, run: List[int]) -> None:
-        """Counters of one decode (or verify) dispatch over slots ``run``."""
+    def _note_decode_dispatch(self, run: List[int],
+                              remaining: Optional[np.ndarray] = None) -> None:
+        """Counters of one decode (or verify) dispatch over slots ``run``;
+        ``remaining`` is a decode program's argument (a verify has none)."""
         self.stats["decode_calls"] += 1
         self.stats[_DECODE_AFTER[min(self._chunks_this_step, 2)]] += 1
         self.stats["decode_attended_tokens"] += int(self._len[run].sum())
+        # the attention kernels see EVERY lane, each layer under its own
+        # window: a verify once with spec_k + 1 rows, a decode once per
+        # inner step with the lengths as the program advances them
+        if remaining is None:
+            seen, rows = [self._len + 1], self.spec_k + 1
+        else:
+            seen, rows = [self._len + np.minimum(i, remaining) + 1
+                          for i in range(self.decode_block)], 1
+        for window, n_layers in self._window_layers.items():
+            lo, hi = pa.live_pages(np.stack(seen), self.page_size, window,
+                                   rows, self.max_pages)
+            self.stats["decode_pages_walked"] += n_layers * int((hi - lo).sum())
+        self.stats["decode_pages_in_table"] += (
+            len(seen) * self.max_slots * self.max_pages * len(self.layers))
 
     def _decode_step(self, finished: List[FinishedRequest]) -> None:
         if self.spec_k:
@@ -2095,7 +2120,7 @@ class ServingEngine:
                     jnp.asarray(remaining), self._next_key())
                 self._store_pool(bufs)
                 self._moe_pending += [(c, len(run)) for c in counts]
-            self._note_decode_dispatch(run)
+            self._note_decode_dispatch(run, remaining)
             # stash the DISPATCHED call without syncing; slot objects ride
             # along so retirement can detect cancel/expire/slot-reuse
             self._inflight = ([(idx, self._slots[idx]) for idx in run],

@@ -251,3 +251,37 @@ def test_a_dispatch_is_handed_copies_of_the_tables(model):
         assert not np.asarray(handed).any()
     eng._table[:] = 0
     eng.ring.table[:] = 0
+
+
+def test_pages_walked_counter_is_the_kernels_own_range(model):
+    """``stats["decode_pages_walked"]`` over a short run with three window
+    layers and a full one: per decode dispatch, the size of
+    ``paged_attention.live_pages`` (what the kernel's grid is built from)
+    summed over every lane the program was handed and every layer under its
+    own window; ``decode_pages_in_table`` is the whole tables."""
+    from paddle_tpu.kernels import paged_attention as pa
+
+    eng = ServingEngine(model, max_slots=3, page_size=8, max_seq_len=160,
+                        chunk_tokens=16)
+    handed, run = [], eng._decode_fn
+    eng._decode_fn = lambda *a: (handed.append(np.array(a[3])), run(*a))[1]
+    for p in _prompts(6, (70, 9, 33, 100)):
+        eng.add_request(p, 12)
+    eng.run()
+    windows = [s.window for s in model.layer_specs()]
+    assert windows == [16, 16, 16, None] and len(handed) > 12
+    want = 0
+    for lengths in handed:
+        assert lengths.shape == (3,) and (lengths + 1 >= 1).all()
+        for w in windows:
+            lo, hi = pa.live_pages(lengths + 1, 8, w, 1, eng.max_pages)
+            want += int((hi - lo).sum())
+    st = eng.stats
+    assert st["decode_pages_walked"] == want
+    assert st["decode_pages_in_table"] == len(handed) * 3 * eng.max_pages * 4
+    # contexts past the window: the window layers walk fewer pages than the
+    # full one, and all of them far fewer than the tables hold
+    full_only = sum(int(np.subtract(*pa.live_pages(
+        n + 1, 8, None, 1, eng.max_pages)[::-1]).sum()) for n in handed)
+    assert full_only < want < 4 * full_only
+    assert want < st["decode_pages_in_table"] // 2

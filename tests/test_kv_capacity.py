@@ -112,6 +112,131 @@ def test_paged_decode_kernel_matrix(group, window, bits):
                                rtol=1e-5, atol=1e-5)
 
 
+def _ragged(rng, bits, group, rows=1):
+    """Ragged lengths in one batch: 1, a page, a page + 1, a full table
+    (for a block of ``rows`` query rows), an idle lane (nothing to attend,
+    every table entry the null page) and one in between.  Each slot's pages
+    are its own; the pool's last page is spare (``_poison`` takes it)."""
+    B, HKV, D, PS, MAXP = 6, 2, 16, 8, 5
+    H = HKV * group
+    P = 1 + B * MAXP + 1
+    full = MAXP * PS - (rows - 1)
+    lens = np.array([1, PS, PS + 1, full, 1, 19], "int32")
+    kq, vq, ks, vs = _mk_pages(rng, P, HKV, PS, D, bits)
+    bt = 1 + np.arange(B * MAXP, dtype="int32").reshape(B, MAXP)
+    bt[4] = 0
+    return B, H, D, PS, MAXP, P, lens, kq, vq, ks, vs, bt
+
+
+def _poison(bt, kq, vq, ks, vs, live):
+    """``bt`` with every entry outside ``live`` = (lo, hi) naming the last
+    page, and that page all NaN (a quantized pool carries it in the
+    scales)."""
+    lo, hi = live
+    col = np.arange(bt.shape[1])[None, :]
+    dead = (col < np.asarray(lo)[:, None]) | (col >= np.asarray(hi)[:, None])
+    bad = kq.shape[0] - 1
+    nan = lambda a: a.at[bad].set(jnp.nan)
+    if ks is None:
+        kq, vq = nan(kq), nan(vq)
+    else:
+        ks, vs = nan(ks), nan(vs)
+    return jnp.asarray(np.where(dead, bad, bt)), kq, vq, ks, vs, int(dead.sum())
+
+
+@pytest.mark.parametrize("bits", [None, 8, 4], ids=["fp", "int8", "int4"])
+@pytest.mark.parametrize("window", [None, 12], ids=["full", "win"])
+@pytest.mark.parametrize("group", [1, 16])
+def test_paged_decode_kernel_ragged_lengths(group, window, bits):
+    """One batch of every kind of lane, the window (12) crossing a page
+    boundary (8): the walk over live pages equals the dense reference."""
+    rng = np.random.RandomState(5 * group + (bits or 1))
+    B, H, D, PS, MAXP, P, lens, kq, vq, ks, vs, bt = _ragged(rng, bits, group)
+    q = jnp.asarray(rng.randn(B, H, D).astype("float32"))
+    out = pa.paged_attention(q, kq, vq, jnp.asarray(bt), jnp.asarray(lens),
+                             k_scales=ks, v_scales=vs, interpret=True,
+                             window=window)
+    ref = pa.paged_attention_ref(q, kq, vq, jnp.asarray(bt),
+                                 jnp.asarray(lens), k_scales=ks, v_scales=vs,
+                                 window=window)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("bits", [None, 8, 4], ids=["fp", "int8", "int4"])
+@pytest.mark.parametrize("window", [None, 12], ids=["full", "win"])
+@pytest.mark.parametrize("group", [1, 16])
+def test_paged_decode_kernel_never_reads_a_dead_page(group, window, bits):
+    """Every table entry outside a slot's live range names a page of NaN
+    and the result is BIT-equal to the clean run: the kernel reads no page
+    the mask throws away (a grid over the whole table lets the NaN through
+    as 0 x NaN)."""
+    rng = np.random.RandomState(7 * group + (bits or 1))
+    B, H, D, PS, MAXP, P, lens, kq, vq, ks, vs, bt = _ragged(rng, bits, group)
+    q = jnp.asarray(rng.randn(B, H, D).astype("float32"))
+    clean = pa.paged_attention(q, kq, vq, jnp.asarray(bt), jnp.asarray(lens),
+                               k_scales=ks, v_scales=vs, interpret=True,
+                               window=window)
+    bt2, kq, vq, ks, vs, n_dead = _poison(
+        bt, kq, vq, ks, vs, pa.live_pages(lens, PS, window, 1, MAXP))
+    assert n_dead >= B * MAXP // 2
+    out = pa.paged_attention(q, kq, vq, bt2, jnp.asarray(lens), k_scales=ks,
+                             v_scales=vs, interpret=True, window=window)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
+@pytest.mark.parametrize("bits", [None, 8, 4], ids=["fp", "int8", "int4"])
+@pytest.mark.parametrize("window", [None, 12], ids=["full", "win"])
+def test_paged_mq_kernel_ragged_and_never_reads_a_dead_page(window, bits):
+    """The verify kernel shares the walk: ragged lengths against its
+    reference, and bit-equal with every dead table entry poisoned."""
+    rng = np.random.RandomState(11 + (bits or 1))
+    T = 3
+    B, H, D, PS, MAXP, P, lens, kq, vq, ks, vs, bt = _ragged(
+        rng, bits, 4, rows=T)
+    lens = lens - 1                  # positions valid BEFORE the block
+    q = jnp.asarray(rng.randn(B, T, H, D).astype("float32"))
+    out = pa.paged_attention_mq(q, kq, vq, jnp.asarray(bt), jnp.asarray(lens),
+                                k_scales=ks, v_scales=vs, interpret=True,
+                                window=window)
+    ref = pa.paged_attention_mq_ref(q, kq, vq, jnp.asarray(bt),
+                                    jnp.asarray(lens), k_scales=ks,
+                                    v_scales=vs, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    bt2, kq, vq, ks, vs, n_dead = _poison(
+        bt, kq, vq, ks, vs, pa.live_pages(lens + 1, PS, window, T, MAXP))
+    assert n_dead >= B * MAXP // 2
+    again = pa.paged_attention_mq(q, kq, vq, bt2, jnp.asarray(lens),
+                                  k_scales=ks, v_scales=vs, interpret=True,
+                                  window=window)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(out))
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+@pytest.mark.parametrize("window", [None, 1, 12, 40], ids=str)
+def test_live_pages_are_the_pages_of_the_visible_positions(window, rows):
+    """The one range function against a count by hand, for every length a
+    table of 5 pages of 8 can hold; and the contract that keeps a softmax
+    from emptying: a range is never empty, whatever the length."""
+    PS, MAXP = 8, 5
+    lens = np.arange(0, MAXP * PS - rows + 2, dtype=np.int32)
+    lo, hi = pa.live_pages(lens, PS, window, rows, MAXP)
+    assert ((0 <= lo) & (lo < hi) & (hi <= MAXP)).all()
+    for n, a, b in zip(lens, lo, hi):
+        seen = set()
+        for i in range(rows):        # row i sees n + i positions, its own too
+            first = 0 if window is None else max(0, n + i - window)
+            seen |= {pos // PS for pos in range(first, n + i)}
+        seen = {p for p in seen if p < MAXP}
+        assert set(range(a, b)) == (seen or {0}), (n, a, b)
+    # the same arithmetic on jax values (what the program and the kernel use)
+    jlo, jhi = pa.live_pages(jnp.asarray(lens), PS, window, rows, MAXP)
+    np.testing.assert_array_equal(np.asarray(jlo), lo)
+    np.testing.assert_array_equal(np.asarray(jhi), hi)
+
+
 @pytest.mark.parametrize("bits", [None, 8, 4], ids=["fp", "int8", "int4"])
 @pytest.mark.parametrize("window", [None, 7], ids=["full", "win"])
 @pytest.mark.parametrize("group", [1, 2, 4])
@@ -437,6 +562,42 @@ def test_engine_spec_decode_gqa_window_int4_exact():
     assert eng.stats["spec_drafted"] > 0
     for i, rid in enumerate(rids):
         np.testing.assert_array_equal(out[rid].tokens, refs[i])
+
+
+@pytest.mark.parametrize("kw", [{}, {"decode_block": 2}, {"spec_k": 2}],
+                         ids=["decode", "decode_block2", "spec_k2"])
+def test_engine_counts_the_pages_its_decode_kernels_walk(kw):
+    """``decode_pages_walked`` is the kernels' own live range summed over
+    the lanes and layers of every decode or verify dispatch (an inner step
+    of a ``decode_block`` counts as a dispatch: it is one kernel call a
+    layer), recomputed here from the arguments the programs were handed."""
+    m = _model(seed=1, attn_window=24, num_layers=2)
+    eng = ServingEngine(m, max_slots=3, page_size=8, chunk_tokens=8,
+                        use_paged_kernel=False, **kw)
+    handed = []
+    if "spec_k" in kw:
+        run = eng._verify_fn       # (p, bufs, toks, draft, n_draft, lengths, ..)
+        eng._verify_fn = lambda *a: (handed.append(
+            [np.asarray(a[5]) + 1]), run(*a))[1]
+        rows = 3
+    else:
+        run = eng._decode_fn       # (p, bufs, toks, lengths, table, remaining, ..)
+        eng._decode_fn = lambda *a: (handed.append(
+            [np.asarray(a[3]) + np.minimum(i, np.asarray(a[5])) + 1
+             for i in range(eng.decode_block)]), run(*a))[1]
+        rows = 1
+    rng = np.random.RandomState(3)
+    for p in _prompts(rng, (30, 5, 17, 41)):
+        eng.add_request(p, 10)
+    eng.run()
+    calls = [seen for call in handed for seen in call]
+    want = sum(2 * int(np.subtract(*pa.live_pages(
+        seen, 8, 24, rows, eng.max_pages)[::-1]).sum()) for seen in calls)
+    assert len(handed) == eng.stats["decode_calls"] > 4
+    assert eng.stats["decode_pages_walked"] == want
+    assert eng.stats["decode_pages_in_table"] == \
+        len(calls) * 3 * eng.max_pages * 2
+    assert 0 < want < eng.stats["decode_pages_in_table"]
 
 
 def test_engine_tp2_gqa_window_int4_matches_single_device():
