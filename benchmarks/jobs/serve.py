@@ -136,7 +136,7 @@ def drive(server: Server, ctx, schedule: list, seconds: float) -> dict:
     until = cell["drain_until"]
     if until not in ("first_token", "complete"):
         raise ValueError(f"drain_until: {until!r}")
-    end = seconds + float(cell["drain_s"])
+    end, closing = seconds + float(cell["drain_s"]), 0.0
     origin = time.perf_counter() + float(cell["lead_s"])
 
     def now() -> float:
@@ -159,6 +159,10 @@ def drive(server: Server, ctx, schedule: list, seconds: float) -> dict:
             if stats1 is None:
                 stats1 = dict(eng.stats)
                 ctx.tracer.close()
+                # writing a profile takes seconds in which nothing is
+                # stepped: they are not the drain's
+                closing = now() - t
+                end += closing
             waiting = any(req.due >= 0 and rid not in finished
                           and (until == "complete"
                                or rid not in server.token_times)
@@ -181,7 +185,7 @@ def drive(server: Server, ctx, schedule: list, seconds: float) -> dict:
             eng.cancel(rid)
     return dict(sent=sent, finished=finished, pages=pages, depth=depth,
                 stats0=stats0 or dict(eng.stats), stats1=stats1,
-                origin=origin, seconds=seconds, drained_at=now())
+                origin=origin, seconds=seconds, drained_at=now() - closing)
 
 
 def served_rate(server: Server, obs: dict, good: set) -> float | None:
@@ -210,6 +214,23 @@ def _pct(xs: list, q: float) -> float | None:
 
 def _ms(x: float | None) -> float | None:
     return None if x is None else x * 1e3
+
+
+def gap_levels(gaps: list) -> dict | None:
+    """Where the 95th percentile of the gaps stands among their levels (a
+    gap is a decode, the chunks before it and the host's turn): some
+    quantiles in ms, and the share of gaps more than a fifth above it, and
+    more than a sixth below.  A 95th percentile with 2-8 % of the gaps on a
+    level above its own stands on an edge and tips with the arrivals."""
+    if not gaps:
+        return None
+    g = np.asarray(gaps) * 1e3
+    qs = (5, 25, 50, 75, 90, 95, 98, 99.5)
+    out = {f"p{q:g}": float(v) for q, v in zip(qs, np.percentile(g, qs))}
+    p95 = out["p95"]
+    out["over_1.2_p95_pct"] = 100.0 * float((g > 1.2 * p95).mean())
+    out["under_p95_over_1.2_pct"] = 100.0 * float((g < p95 / 1.2).mean())
+    return {k: round(v, 3) for k, v in out.items()}
 
 
 def score(server: Server, ctx, obs: dict) -> dict:
@@ -274,6 +295,7 @@ def score(server: Server, ctx, obs: dict) -> dict:
                        default=0),
         num_pages=ctx.config["engine"]["num_pages"],
         done_requests=done_requests, n_gaps=len(gaps),
+        gap_ms=gap_levels(gaps),
         window_compiles=delta["prefill_traces"] + delta["decode_traces"])
     checks = {"token_counts_in_range": bad_tokens == 0,
               "some_request_completed": done_requests > 0}
@@ -302,7 +324,8 @@ def run(ctx) -> dict:
             f"({r['completed_tokens_per_s']:.0f} tokens/s), served "
             f"{v['serve_tokens_per_s']} tokens/s, ttft p50 "
             f"{r['ttft_p50_ms']} p95 {r['ttft_p95_ms']} ms, gap p95 "
-            f"{v['tbt_p95_ms']} ms over {r['n_gaps']} gaps, generator late "
+            f"{v['tbt_p95_ms']} ms over {r['n_gaps']} gaps ({r['gap_ms']}), "
+            f"generator late "
             f"p95 {r['generator_late_p95_ms']} ms, backlog "
             f"{r['backlog_third']:.1f} a third in and {r['backlog_end']:.1f} "
             f"at the end, drained {r['drain_s']:.1f} s after it")

@@ -23,7 +23,8 @@ import numpy as np
 
 from benchmarks import sut, weights_cohere2
 from benchmarks.jobs import serve
-from benchmarks.jobs.serve import SPANS  # noqa: F401  (run.py reads it)
+# run.py reads SPANS; knee_sweep.py drives any serving job by these names
+from benchmarks.jobs.serve import SPANS, drive, make_schedule, score  # noqa: F401,E501
 from benchmarks.reference import cohere2_moe_ref as ref
 
 #: the five prompts of ``jobs/serve.py`` (their last chunks fall in the five
@@ -227,10 +228,9 @@ def run(ctx) -> dict:
     ctx.mark("check_and_warm")
     server.weights = server.checked = None
     server.token_times.clear()
-    schedule = serve.make_schedule(ctx, float(ctx.cell["rate_rps"]),
-                                   ctx.seconds)
-    obs = serve.drive(server, ctx, schedule, ctx.seconds)
-    out = serve.score(server, ctx, obs)
+    schedule = make_schedule(ctx, float(ctx.cell["rate_rps"]), ctx.seconds)
+    obs = drive(server, ctx, schedule, ctx.seconds)
+    out = score(server, ctx, obs)
     out["checks"].update(checks)
     lo, hi = obs["origin"], obs["origin"] + obs["seconds"]
     before = [v for t, v in server.window_pages if t < lo][-1]
@@ -247,7 +247,8 @@ def run(ctx) -> dict:
             f"({r['completed_tokens_per_s']:.0f} tokens/s), served "
             f"{v['serve_tokens_per_s']} tokens/s, ttft p50 "
             f"{r['ttft_p50_ms']} p95 {r['ttft_p95_ms']} ms, gap p95 "
-            f"{v['tbt_p95_ms']} ms over {r['n_gaps']} gaps, backlog "
+            f"{v['tbt_p95_ms']} ms over {r['n_gaps']} gaps ({r['gap_ms']}), "
+            f"backlog "
             f"{r['backlog_third']:.1f} a third in and {r['backlog_end']:.1f} "
             f"at the end, drained {r['drain_s']:.1f} s after it; pages peak "
             f"{r['pages_peak']} full, {r['window_pages_peak']} window")

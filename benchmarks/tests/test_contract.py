@@ -107,3 +107,22 @@ def test_config_files_hold_the_published_keys():
         assert cfg["n_embd"] // cfg["n_head"] == 128
         assert cfg["source"].startswith("https://huggingface.co/cerebras/")
         assert cfg["reduced"] == [] and cfg["assumed"]
+
+
+def test_a_serving_cells_why_is_one_sentence_that_names_its_rate():
+    """The sentence went stale in silence once (the rates of PR 22 stood in
+    it through three PRs that changed what they meant)."""
+    serving = 0
+    for w in _bench()["workloads"]:
+        with open(os.path.join(BENCH, "workloads", f"{w['name']}.json")) as f:
+            text = f.read()
+        cell = json.loads(text)
+        if "rate_rps" not in cell:
+            continue
+        serving += 1
+        assert cell["why"] == w["why"], w["name"]
+        written = re.search(r'"rate_rps":\s*([0-9.eE+\-]+)', text).group(1)
+        assert float(written) == cell["rate_rps"]
+        assert re.search(rf"(?<![0-9.]){re.escape(written)} req/s", w["why"]), \
+            (w["name"], written, w["why"])
+    assert serving >= 3

@@ -75,3 +75,18 @@ def test_schedule_fixes_the_work_and_draws_the_order():
     assert 200 < np.median(lens(win(a))) < 320
     assert 100 < np.median(outs(win(a))) < 160
     assert all(x.due == y.due for x, y in zip(a, _sched(3, rate=1.0)))
+
+
+def test_arrival_seed_fixes_the_instants_and_leaves_the_order_to_the_seed():
+    params = dict(CHAT, arrival_seed=0)
+    a, b = _sched(3, params, rate=1.0), _sched(4, params, rate=1.0)
+    assert [r.due for r in a] == [r.due for r in b]
+    assert [r.due for r in a] != [r.due for r in _sched(3, rate=1.0)]
+    assert [r.due for r in a] != [
+        r.due for r in _sched(3, dict(CHAT, arrival_seed=1), rate=1.0)]
+    assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
+    assert sorted(len(r.prompt) for r in a if r.due >= 0) == sorted(
+        len(r.prompt) for r in b if r.due >= 0)
+    assert not np.array_equal(a[0].prompt[:8], b[0].prompt[:8])
+    assert sum(r.due < 0 for r in a) == 5 and sum(r.due >= 0 for r in a) == 30
+    assert all(x.due <= y.due for x, y in zip(a, a[1:]))

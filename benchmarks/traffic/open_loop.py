@@ -4,7 +4,12 @@ earlier requests have finished.
 Parameters (the traffic file): ``prompt_len`` / ``output_len``, each
 ``{"dist": "lognormal", "median", "sigma", "min", "max"}`` or ``{"dist":
 "uniform", "min", "max"}``; lengths are whole tokens in [min, max].  The
-rate comes from the cell.
+rate comes from the cell.  Optional ``arrival_seed``: the arrival instants
+are then drawn from it and not from ``--seed``, so every seed offers the
+same instants (the same bursts and lulls) and draws only which request, of
+which lengths and tokens, comes at each: a tail of the gaps between tokens
+follows the bursts, and a free draw of them moved it more from seed to
+seed than a change to the program would.
 
 The amount of work is fixed and only its order and timing are drawn,
 separately for the lead-in (due before 0) and the window (due from 0 on).
@@ -57,10 +62,12 @@ def make(params: dict, *, vocab: int, seed: int, rate: float, start: float,
     """Requests due in [start, end) at ``rate`` per second; ``max_total``
     bounds prompt + output (the engine's positions)."""
     rng = np.random.default_rng([seed, 0x09E7])
+    at = rng if params.get("arrival_seed") is None else \
+        np.random.default_rng([int(params["arrival_seed"]), 0xA771])
     out = []
     for lo, hi in ((start, min(end, 0.0)), (max(start, 0.0), end)):
         k = round((hi - lo) * rate) if hi > lo else 0
-        due = lo + np.sort(rng.random(k)) * (hi - lo)
+        due = lo + np.sort(at.random(k)) * (hi - lo)
         p_len = _lengths(params["prompt_len"], k, rng)
         o_len = _lengths(params["output_len"], k, rng)
         for t, p, new in zip(due, p_len, o_len):
