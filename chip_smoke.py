@@ -132,32 +132,33 @@ def _with_grads(attn):
     return f
 
 
-def _flash_case(*, layout, seq, batch, heads, required):
+def _flash_case(*, layout, seq, batch, heads, required, n_kv=None,
+                window=None):
     import jax.numpy as jnp
 
     from paddle_tpu.kernels import flash
     from paddle_tpu.kernels.attention import _sdpa_reference
 
     rng = np.random.RandomState(0)
-    shape = {"bnsd": (batch, heads, seq, HEAD_DIM),
-             "bsnd": (batch, seq, heads, HEAD_DIM),
-             "sbnd": (seq, batch, heads, HEAD_DIM)}[layout]
-    args = tuple(jnp.asarray(rng.randn(*shape).astype("float32"),
-                             jnp.bfloat16) for _ in range(4))
-    to_bnsd = {"bnsd": lambda a: a,
-               "bsnd": lambda a: jnp.swapaxes(a, 1, 2),
-               "sbnd": lambda a: jnp.transpose(a, (1, 2, 0, 3))}[layout]
-    from_bnsd = {"bnsd": lambda a: a,
-                 "bsnd": lambda a: jnp.swapaxes(a, 1, 2),
-                 "sbnd": lambda a: jnp.transpose(a, (2, 0, 1, 3))}[layout]
+    n_kv = n_kv or heads
+
+    def operand(n):
+        shape = {"bnsd": (batch, n, seq, HEAD_DIM),
+                 "bsnd": (batch, seq, n, HEAD_DIM)}[layout]
+        return jnp.asarray(rng.randn(*shape).astype("float32"), jnp.bfloat16)
+
+    args = (operand(heads), operand(n_kv), operand(n_kv), operand(heads))
+    # to the oracle's bnsd and back: the same swap both ways
+    swap = {"bnsd": lambda a: a,
+            "bsnd": lambda a: jnp.swapaxes(a, 1, 2)}[layout]
 
     def kernel(q, k, v):
         return flash.flash_attention(q, k, v, causal=True, layout=layout,
-                                     interpret=False)
+                                     window=window, interpret=False)
 
     def oracle(q, k, v):
-        f32 = [to_bnsd(a).astype(jnp.float32) for a in (q, k, v)]
-        return from_bnsd(_sdpa_reference(*f32, is_causal=True))
+        f32 = [swap(a).astype(jnp.float32) for a in (q, k, v)]
+        return swap(_sdpa_reference(*f32, is_causal=True, window=window))
 
     return KernelCase(required, TOL_GRAD, _with_grads(kernel),
                       _with_grads(oracle), args)
@@ -357,8 +358,15 @@ KERNEL_CASES = {
         required=False, m=BATCH * SEQ, k=_H, n=3 * _H)),
     "flash_bsnd_seq1024": (_flash_case, dict(
         layout="bsnd", seq=1024, batch=2, heads=HEADS, required=False)),
-    "flash_sbnd_seq1024": (_flash_case, dict(
-        layout="sbnd", seq=1024, batch=2, heads=HEADS, required=False)),
+    "flash_bsnd_gqa4_seq1024": (_flash_case, dict(
+        layout="bsnd", seq=1024, batch=2, heads=HEADS, n_kv=HEADS // 4,
+        required=False)),
+    "flash_bsnd_window_seq1024": (_flash_case, dict(
+        layout="bsnd", seq=1024, batch=2, heads=HEADS, window=256,
+        required=False)),
+    "flash_bnsd_window_seq1024": (_flash_case, dict(
+        layout="bnsd", seq=1024, batch=2, heads=HEADS, window=256,
+        required=False)),
 }
 
 

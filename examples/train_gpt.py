@@ -40,10 +40,6 @@ def main():
     p.add_argument("--sharding-stage", type=int, default=None)
     p.add_argument("--lr", type=float, default=3e-4)
     p.add_argument("--remat", default="0", choices=["0", "1", "dots"])
-    p.add_argument("--seq-major", action="store_true",
-                   help="[S, B, H] activation layout end-to-end "
-                        "(GPTConfig.seq_major; feeds the sbnd flash entry "
-                        "with zero layout transposes)")
     p.add_argument("--int8", action="store_true",
                    help="W8A8 int8 projections (GPTConfig.int8): real "
                         "int8 GEMMs with dynamic per-token activation "
@@ -89,9 +85,8 @@ def main():
     cfg_fn = {"tiny": gpt_mod.gpt_tiny, "small": gpt_mod.gpt_small,
               "medium": gpt_mod.gpt_medium, "1p3b": gpt_mod.gpt_1p3b,
               "13b": gpt_mod.gpt_13b}[args.config]
-    cfg = cfg_fn(use_parallel=args.mp > 1, seq_major=args.seq_major,
-                 int8=args.int8, num_kv_heads=args.kv_heads,
-                 attn_window=args.window)
+    cfg = cfg_fn(use_parallel=args.mp > 1, int8=args.int8,
+                 num_kv_heads=args.kv_heads, attn_window=args.window)
     seq = args.seq or min(cfg.max_seq_len, 512)
 
     paddle.seed(args.seed)

@@ -1,7 +1,7 @@
 """jaxpr_audit: the one walker library behind every jaxpr contract.
 
 Four test files grew near-duplicate jaxpr walkers asserting the layout
-and dtype contracts (seq-major attention reaches the Pallas kernel with
+and dtype contracts (``bsnd`` attention reaches the Pallas kernel with
 ZERO transposes, the mq verify kernel at ``q_tile=1`` is jaxpr-identical
 to the decode kernel, the flagship train step never promotes to f64).
 This module is their single implementation; tests import it instead of
@@ -118,7 +118,7 @@ def assert_no_primitive(jaxpr, name: str, context: str = "",
 
 
 def assert_no_transpose(jaxpr, context: str = "") -> None:
-    """The seq-major layout contract: activations reach the kernel
+    """The in-place (``bsnd``) layout contract: q/k/v reach the kernel
     without a single transpose primitive (kernel-internal VMEM-tile
     transposes excluded by the walk)."""
     assert_no_primitive(jaxpr, "transpose", context)

@@ -272,7 +272,6 @@ class PipelineEngine:
         self.axis = axis
         self.mesh = mesh_mod.get_mesh()
         self.S = pipeline_layer.get_num_stages()
-        self.seq_major = bool(getattr(pipeline_layer, "seq_major", False))
         self.loss_fn = loss_fn or pipeline_layer._loss_fn
         self._funcs = list(pipeline_layer._funcs)
         self._partition()
@@ -502,19 +501,8 @@ class PipelineEngine:
             flat = xs_mb.reshape((-1,) + xs_mb.shape[2:])
             t = self._run_entries(self._pro, Tensor(flat, stop_gradient=True))
             h = t._array if isinstance(t, Tensor) else t
-            if self.seq_major:
-                # prologue emits [S, M*mb, H]: the scan indexes microbatches
-                # on the LEADING dim, so lift the (M, mb) split out of dim 1
-                # — the only layout change on the seq-major pipeline path
-                s_len = h.shape[0]
-                h_mb = jnp.moveaxis(
-                    h.reshape((s_len, M, -1) + h.shape[2:]), 1, 0)
-                y = apply(stacked, h_mb)
-                out = jnp.moveaxis(y, 0, 1).reshape(
-                    (s_len, -1) + y.shape[3:])
-            else:
-                y = apply(stacked, h.reshape((M, -1) + h.shape[1:]))
-                out = y.reshape((-1,) + y.shape[2:])
+            y = apply(stacked, h.reshape((M, -1) + h.shape[1:]))
+            out = y.reshape((-1,) + y.shape[2:])
             return self._run_entries(self._epi, Tensor(out, stop_gradient=True))
         finally:
             tracer.set_grad_enabled(og)

@@ -99,14 +99,10 @@ class PipelineLayer(Layer):
     semantics, used for eval/export and as the autodiff reference)."""
 
     def __init__(self, layers, num_stages=None, topology=None, loss_fn=None,
-                 seg_method="uniform", recompute_interval=0, seq_major=False,
-                 **kwargs):
+                 seg_method="uniform", recompute_interval=0):
         super().__init__()
         self._loss_fn = loss_fn
         self._topo = topology
-        # activations flow [S, B, H] (GPTConfig.seq_major): the engine packs
-        # microbatches on the BATCH dim (dim 1) instead of dim 0
-        self.seq_major = seq_major
         if num_stages is None and topology is not None:
             num_stages = topology.get_dim("pipe")
         self._num_stages = num_stages or max(mesh_mod.axis_size("pp"), 1)

@@ -88,15 +88,15 @@ def _flash_per_shard(mesh, q, k, v, layout, **kw):
 
     if mesh is None or q.ndim != 4:
         return call(q, k, v)
-    b_dim, h_dim = {"bnsd": (0, 1), "bsnd": (0, 2), "sbnd": (1, 2)}[layout]
+    h_dim = flash.LAYOUTS[layout][0] % 4  # batch leads in every layout
     batch = tuple(a for a in mesh_mod.BATCH_AXES if mesh.shape.get(a, 1) > 1)
-    if q.shape[b_dim] % math.prod(mesh.shape[a] for a in batch):
+    if q.shape[0] % math.prod(mesh.shape[a] for a in batch):
         batch = ()
     mp = mesh.shape.get("mp", 1)
     heads = "mp" if mp > 1 and not (
         q.shape[h_dim] % mp or k.shape[h_dim] % mp) else None
     spec = [None] * 4
-    spec[b_dim] = batch or None
+    spec[0] = batch or None
     spec[h_dim] = heads
     spec = P(*spec)
     return jax.shard_map(call, mesh=mesh, in_specs=(spec, spec, spec),
@@ -112,36 +112,32 @@ def sdpa(q, k, v, mask=None, scale=None, is_causal=False, dropout_p=0.0,
     GSPMD mesh the caller is being partitioned over, if any — the kernel
     then runs per shard (:func:`_flash_per_shard`).
 
-    ``layout="bsnd"`` ([b, s, nh, d], the model-natural layout after a QKV
-    projection) feeds the seq-major kernel specs directly — no materialized
-    transposes around the custom call (flash._fwd_call_smajor).
-    ``layout="sbnd"`` ([s, b, nh, d]) is the end-to-end [S, B, H] activation
-    layout (GPTConfig.seq_major), likewise consumed in place.  GQA (k/v with
-    fewer heads) and ``window`` thread through to the kernel's in-kernel
-    group gather / window mask."""
+    ``layout`` is one of ``flash.LAYOUTS`` (ValueError otherwise).
+    ``layout="bsnd"`` ([b, s, nh, d], what a QKV projection yields) feeds
+    the kernel's column-slab specs directly — no materialized transposes
+    around the custom call (flash._fwd_call_smajor).  GQA (k/v with fewer
+    heads) and ``window`` thread through to the kernel's in-kernel group
+    gather / window mask."""
     from . import flash
     from ..framework import flags
 
-    s_axis = flash._layout_s_axis(layout, q.ndim)
+    _, s_axis = flash.layout_axes(layout)
     if (flags.flag("FLAGS_tpu_flash_attention")
             and flash.available() and q.shape[s_axis] >= 512
             and flash.supported(q, k, mask=mask, dropout_p=dropout_p,
                                 layout=layout)):
         return _flash_per_shard(mesh, q, k, v, layout, causal=is_causal,
                                 scale=scale, window=window)
-    if layout in ("bsnd", "sbnd"):
+    if layout == "bsnd":
         if q.ndim != 4:
             raise ValueError(
                 f"layout={layout!r} expects 4-D q/k/v, got {q.shape}")
         # reference path works on [..., s, d]: transpose in/out (CPU tests;
         # perf path is the kernel above)
-        to_bnsd = (lambda a: jnp.transpose(a, (1, 2, 0, 3))) \
-            if layout == "sbnd" else (lambda a: jnp.swapaxes(a, 1, 2))
-        out = _sdpa_reference(to_bnsd(q), to_bnsd(k), to_bnsd(v), mask=mask,
-                              scale=scale, is_causal=is_causal,
+        out = _sdpa_reference(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)),
+                              mask=mask, scale=scale, is_causal=is_causal,
                               dropout_p=dropout_p, rng=rng, window=window)
-        return (jnp.transpose(out, (2, 0, 1, 3)) if layout == "sbnd"
-                else jnp.swapaxes(out, 1, 2))
+        return jnp.swapaxes(out, 1, 2)
     return _sdpa_reference(q, k, v, mask=mask, scale=scale, is_causal=is_causal,
                            dropout_p=dropout_p, rng=rng, window=window)
 

@@ -330,27 +330,23 @@ def _bwd_call(q3, k3, v3, out, lse, do, scale, causal, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# seq-major call variants — q/k/v stay [b, s, nh*d], blocks select one
-# head's 128-wide column slab per program
+# bsnd call variants — q/k/v stay [b, s, nh*d], blocks select one head's
+# 128-wide column slab per program
 # ---------------------------------------------------------------------------
 #
-# Why: the model's natural layout after the QKV projection is seq-major;
-# feeding the (bh, s, d) kernels forces XLA to MATERIALIZE [b, nh, s, d]
-# transposes on both sides of the custom call (measured 34ms/step on the
-# GPT-760M flagship — Pallas custom calls can't absorb layout changes the
-# way XLA fusions do).  Per-head COLUMN blocks over [b, s, nh*d] keep the
-# Mosaic block rules happy (last-two block dims = (block_q, d), both
-# aligned) where a squeezed-head 4-D spec does not; the kernel bodies are
-# the same ones the bnsd path runs, and lse keeps its (b*nh, 1, s) shape
-# with a computed head index.
+# Why: a QKV projection yields [b, s, nh*d]; feeding the (bh, s, d) kernels
+# makes XLA MATERIALIZE [b, nh, s, d] transposes on both sides of the custom
+# call (Pallas custom calls can't absorb layout changes the way XLA fusions
+# do).  Per-head COLUMN blocks over [b, s, nh*d] keep the Mosaic block rules
+# happy (last-two block dims = (block_q, d), both aligned) where a
+# squeezed-head 4-D spec does not; the kernel bodies are the same ones the
+# bnsd path runs, and lse keeps its (b*nh, 1, s) shape with a computed head
+# index.
 
 
-def _smajor_specs(b, s_len, nh, d, block, what, seq_first=False, nkv=None):
+def _smajor_specs(b, s_len, nh, d, block, what, nkv=None):
     """BlockSpecs for [b, s, nh*d] arrays (one head-column slab per
     program) and (b*nh, 1, s) lse/delta rows; grid = (b, nh, blocks).
-    ``seq_first=True`` selects [s, b, nh*d] arrays instead — the model's
-    end-to-end [S, B, H] activation layout — with the same squeezed
-    (block, d) kernel blocks, so the kernel bodies are shared.
 
     GQA: ``kv_tile``/``kv_full`` address [.., .., nkv*d] K/V arrays with the
     head index mapped through the query-head group (h -> h // (nh//nkv)) —
@@ -359,16 +355,10 @@ def _smajor_specs(b, s_len, nh, d, block, what, seq_first=False, nkv=None):
     g = 1 if nkv is None else nh // nkv
     if what in ("tile", "kv_tile"):
         hmap = (lambda h: h) if what == "tile" else (lambda h: h // g)
-        if seq_first:
-            return pl.BlockSpec((block, None, d),
-                                lambda b_, h, i: (i, b_, hmap(h)))
         return pl.BlockSpec((None, block, d),
                             lambda b_, h, i: (b_, i, hmap(h)))
     if what in ("full", "kv_full"):
         hmap = (lambda h: h) if what == "full" else (lambda h: h // g)
-        if seq_first:
-            return pl.BlockSpec((s_len, None, d),
-                                lambda b_, h, i: (0, b_, hmap(h)))
         return pl.BlockSpec((None, s_len, d),
                             lambda b_, h, i: (b_, 0, hmap(h)))
     if what == "row":
@@ -381,19 +371,13 @@ def _smajor_specs(b, s_len, nh, d, block, what, seq_first=False, nkv=None):
 
 
 def _fwd_call_smajor(q3, k3, v3, nh, scale, causal, block_q, block_k,
-                     interpret, seq_first=False, nkv=None, window=None):
-    if seq_first:
-        s_len, b, H = q3.shape
-        act_shape = (s_len, b, H)
-    else:
-        b, s_len, H = q3.shape
-        act_shape = (b, s_len, H)
+                     interpret, nkv=None, window=None):
+    b, s_len, H = q3.shape
     d = H // nh
     nq = s_len // block_q
 
     def sp(what, block):
-        return _smajor_specs(b, s_len, nh, d, block, what,
-                             seq_first=seq_first, nkv=nkv)
+        return _smajor_specs(b, s_len, nh, d, block, what, nkv=nkv)
 
     with _x64_off():
         out, lse = pl.pallas_call(
@@ -410,7 +394,7 @@ def _fwd_call_smajor(q3, k3, v3, nh, scale, causal, block_q, block_k,
                 sp("row", block_q),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct(act_shape, q3.dtype),
+                jax.ShapeDtypeStruct(q3.shape, q3.dtype),
                 jax.ShapeDtypeStruct((b * nh, 1, s_len), jnp.float32),
             ],
             interpret=interpret,
@@ -420,27 +404,18 @@ def _fwd_call_smajor(q3, k3, v3, nh, scale, causal, block_q, block_k,
 
 
 def _bwd_call_smajor(q3, k3, v3, out, lse, do, nh, scale, causal, block_q,
-                     block_k, interpret, seq_first=False, nkv=None,
-                     window=None):
-    if seq_first:
-        s_len, b, H = q3.shape
-        act_shape = (s_len, b, H)
-    else:
-        b, s_len, H = q3.shape
-        act_shape = (b, s_len, H)
+                     block_k, interpret, nkv=None, window=None):
+    b, s_len, H = q3.shape
     d = H // nh
 
     def sp(what, block):
-        return _smajor_specs(b, s_len, nh, d, block, what,
-                             seq_first=seq_first, nkv=nkv)
+        return _smajor_specs(b, s_len, nh, d, block, what, nkv=nkv)
 
     with _x64_off():
         dsum = jnp.sum((do.astype(jnp.float32) * out.astype(jnp.float32))
-                       .reshape(act_shape[:2] + (nh, d)), axis=-1)
-        # rows of the (b*nh, 1, s) delta: (b, nh, s) from either layout
-        delta = jnp.transpose(
-            dsum, (1, 2, 0) if seq_first else (0, 2, 1)
-        ).reshape(b * nh, 1, s_len)
+                       .reshape(b, s_len, nh, d), axis=-1)
+        # rows of the (b*nh, 1, s) delta
+        delta = jnp.transpose(dsum, (0, 2, 1)).reshape(b * nh, 1, s_len)
 
         nq = s_len // block_q
         dq = pl.pallas_call(
@@ -456,7 +431,7 @@ def _bwd_call_smajor(q3, k3, v3, out, lse, do, nh, scale, causal, block_q,
                 sp("row", block_q),
             ],
             out_specs=sp("tile", block_q),
-            out_shape=jax.ShapeDtypeStruct(act_shape, q3.dtype),
+            out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
             interpret=interpret,
             name="flash_bwd_dq",
         )(q3, k3, v3, do, lse, delta)
@@ -483,46 +458,43 @@ def _bwd_call_smajor(q3, k3, v3, out, lse, do, nh, scale, causal, block_q,
                 sp("tile", block_k),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct(act_shape, k3.dtype),
-                jax.ShapeDtypeStruct(act_shape, v3.dtype),
+                jax.ShapeDtypeStruct(q3.shape, k3.dtype),
+                jax.ShapeDtypeStruct(q3.shape, v3.dtype),
             ],
             interpret=interpret,
             name="flash_bwd_dkv",
         )(q3, k3, v3, do, lse, delta)
         if nkv is not None and nkv != nh:
             g = nh // nkv
-            red = act_shape[:2] + (nkv, g, d)
-            kv_shape = act_shape[:2] + (nkv * d,)
+            red = (b, s_len, nkv, g, d)
             dk = dk.astype(jnp.float32).reshape(red).sum(axis=3) \
-                .reshape(kv_shape).astype(k3.dtype)
+                .reshape(k3.shape).astype(k3.dtype)
             dv = dv.astype(jnp.float32).reshape(red).sum(axis=3) \
-                .reshape(kv_shape).astype(v3.dtype)
+                .reshape(v3.shape).astype(v3.dtype)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
 def _flash_smajor(nh, nkv, causal, scale, window, block_q, block_k,
-                  interpret, seq_first, q3, k3, v3):
+                  interpret, q3, k3, v3):
     out, _ = _fwd_call_smajor(q3, k3, v3, nh, scale, causal, block_q,
-                              block_k, interpret, seq_first=seq_first,
-                              nkv=nkv, window=window)
+                              block_k, interpret, nkv=nkv, window=window)
     return out
 
 
 def _flash_smajor_fwd(nh, nkv, causal, scale, window, block_q, block_k,
-                      interpret, seq_first, q3, k3, v3):
+                      interpret, q3, k3, v3):
     out, lse = _fwd_call_smajor(q3, k3, v3, nh, scale, causal, block_q,
-                                block_k, interpret, seq_first=seq_first,
-                                nkv=nkv, window=window)
+                                block_k, interpret, nkv=nkv, window=window)
     return out, (q3, k3, v3, out, lse)
 
 
 def _flash_smajor_bwd(nh, nkv, causal, scale, window, block_q, block_k,
-                      interpret, seq_first, res, do):
+                      interpret, res, do):
     q3, k3, v3, out, lse = res
     return _bwd_call_smajor(q3, k3, v3, out, lse, do, nh, scale, causal,
-                            block_q, block_k, interpret,
-                            seq_first=seq_first, nkv=nkv, window=window)
+                            block_q, block_k, interpret, nkv=nkv,
+                            window=window)
 
 
 _flash_smajor.defvjp(_flash_smajor_fwd, _flash_smajor_bwd)
@@ -558,28 +530,38 @@ def _flash_bwd_rule(causal, scale, window, block_q, block_k, interpret,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _layout_s_axis(layout, ndim=4):
-    if layout == "bsnd":
-        return -3
-    if layout == "sbnd":
-        return -ndim  # seq leads: [s, b, nh, d]
-    return -2
+# The q/k/v layouts the flash entries take, each with its (heads, seq) axes.
+# This is the one statement of which layouts exist: ``flash_attention``,
+# ``supported`` and the sdpa dispatcher (kernels/attention.py) read it.
+#   "bnsd": [..., heads, seq, head_dim] — the GPT model's path;
+#   "bsnd": [batch, seq, heads, head_dim] — consumed in place, takes GQA.
+LAYOUTS = {"bnsd": (-3, -2), "bsnd": (-2, -3)}
+
+
+def layout_axes(layout):
+    """(heads axis, seq axis) of ``layout``; ValueError for a layout no
+    kernel implements (never a silent fall-through to another one)."""
+    if layout not in LAYOUTS:
+        raise ValueError(
+            f"unknown attention layout {layout!r}: accepted layouts are "
+            f"{sorted(LAYOUTS)}")
+    return LAYOUTS[layout]
 
 
 def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
                     block_q=None, block_k=None, layout="bnsd", window=None):
     """Flash attention.  ``layout="bnsd"``: [..., seq, head_dim] (q/k same
     length); ``layout="bsnd"``: [batch, seq, heads, head_dim] — consumed
-    seq-major IN PLACE, so the caller pays no materialized [b,nh,s,d]
-    transposes around the custom call; ``layout="sbnd"``: [seq, batch,
-    heads, head_dim] — the model's end-to-end [S, B, H] activation layout
-    (GPTConfig.seq_major), also consumed in place.  The seq-major layouts
-    accept GQA (k/v with fewer heads, a divisor of q's) — query-head groups
-    are gathered onto the shared K/V head inside the BlockSpec index maps.
+    IN PLACE, so the caller pays no materialized [b,nh,s,d] transposes
+    around the custom call, and accepts GQA (k/v with fewer heads, a
+    divisor of q's): query-head groups are gathered onto the shared K/V
+    head inside the BlockSpec index maps.  Any other ``layout`` raises
+    ValueError.
     ``window`` (causal only) masks keys older than ``window`` positions and
     skips fully-masked blocks.  Raises ValueError on unsupported shapes —
     callers should gate on :func:`supported` first (the sdpa dispatcher
     does)."""
+    h_axis, s_axis = layout_axes(layout)
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
@@ -587,7 +569,6 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
     if window is not None and not causal:
         raise ValueError("flash_attention: window requires causal=True")
     win = None if window is None else int(window)
-    s_axis = _layout_s_axis(layout, q.ndim)
     s_len = q.shape[s_axis]
     bq = block_q or _pick_block(s_len)
     bk = block_k or _pick_block(s_len)
@@ -595,32 +576,24 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
         raise ValueError(
             f"flash_attention: unsupported seq len {s_len} (needs a power-of-"
             f"two-ish divisor >= 8) or cross-attention q/k lengths")
-    if layout in ("bsnd", "sbnd"):
-        assert q.ndim == 4, f"{layout} layout expects 4-D q/k/v"
-        seq_first = layout == "sbnd"
-        if seq_first:
-            _, b, nh, d = q.shape
-            nkv = k.shape[2]
-            flat = (s_len, b, nh * d)
-            kv_flat = (s_len, b, nkv * d)
-        else:
-            b, _, nh, d = q.shape
-            nkv = k.shape[2]
-            flat = (b, s_len, nh * d)
-            kv_flat = (b, s_len, nkv * d)
+    if layout == "bsnd":
+        assert q.ndim == 4, "bsnd layout expects 4-D q/k/v"
+        b, _, nh, d = q.shape
+        nkv = k.shape[h_axis]
         if nh % nkv != 0:
             raise ValueError(
                 f"flash_attention: q heads {nh} not a multiple of kv heads "
                 f"{nkv}")
+        kv_flat = (b, s_len, nkv * d)
         out = _flash_smajor(int(nh), int(nkv), causal, float(scale), win,
-                            int(bq), int(bk), bool(interpret), seq_first,
-                            q.reshape(flat), k.reshape(kv_flat),
-                            v.reshape(kv_flat))
+                            int(bq), int(bk), bool(interpret),
+                            q.reshape((b, s_len, nh * d)),
+                            k.reshape(kv_flat), v.reshape(kv_flat))
         return out.reshape(q.shape)
-    if q.ndim >= 3 and q.shape[-3] != k.shape[-3]:
+    if q.ndim >= 3 and q.shape[h_axis] != k.shape[h_axis]:
         raise ValueError(
-            "flash_attention: GQA (mismatched head counts) requires a "
-            "seq-major layout (bsnd/sbnd)")
+            "flash_attention: GQA (mismatched head counts) requires "
+            "layout='bsnd'")
     lead = q.shape[:-2]
     d = q.shape[-1]
     q3 = q.reshape((-1, s_len, d))
@@ -633,21 +606,18 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
 
 def supported(q, k, mask=None, dropout_p=0.0, layout="bnsd") -> bool:
     """Shape/feature gate used by the sdpa dispatcher."""
-    if mask is not None or dropout_p != 0.0:
+    if layout not in LAYOUTS or mask is not None or dropout_p != 0.0:
         return False
-    s_axis = _layout_s_axis(layout, q.ndim)
-    if layout in ("bsnd", "sbnd") and q.ndim != 4:
+    h_axis, s_axis = LAYOUTS[layout]
+    if layout == "bsnd" and q.ndim != 4:
         return False
     if q.ndim < 3 or q.shape[s_axis] != k.shape[s_axis]:
         return False
-    # GQA: only the seq-major layouts gather query-head groups in their
-    # index maps; the bnsd flat (-1, s, d) reshape can't express it
-    if q.ndim >= 3:
-        h_axis = 2 if layout in ("bsnd", "sbnd") else -3
-        nh, nkv = q.shape[h_axis], k.shape[h_axis]
-        if nh != nkv:
-            if layout not in ("bsnd", "sbnd") or nkv == 0 or nh % nkv != 0:
-                return False
+    # GQA: only bsnd gathers query-head groups in its index maps; the bnsd
+    # flat (-1, s, d) reshape can't express it
+    nh, nkv = q.shape[h_axis], k.shape[h_axis]
+    if nh != nkv and (layout != "bsnd" or nkv == 0 or nh % nkv != 0):
+        return False
     # head_dim gate: Mosaic wants lane-aligned (multiple-of-8) head dims in a
     # validated range; odd geometries (80, 12, ...) take the XLA sdpa path
     # instead of failing at lowering (ADVICE round 2)

@@ -85,7 +85,7 @@ def _ring_block(q, k, v, o, m, l, q_off, kv_off, scale, causal,
 
 def ring_attention(q, k, v, axis: str = "mp", causal: bool = False,
                    scale: Optional[float] = None,
-                   use_flash: Optional[bool] = None, layout: str = "bnsd",
+                   use_flash: Optional[bool] = None,
                    window: Optional[int] = None):
     """Attention over sequence-sharded Q/K/V (global arrays, (B, H, S, D)).
 
@@ -99,21 +99,14 @@ def ring_attention(q, k, v, axis: str = "mp", causal: bool = False,
     merging — see :func:`ring_flash_attention`) or the einsum online-
     softmax fallback.  The single-device fallback dispatches through
     ``sdpa`` and therefore also runs flash on TPU.
-
-    ``layout="sbnd"`` accepts the model's end-to-end seq-major activations
-    (S, B, NH, D) (GPTConfig.seq_major): the ring dim is then dim 0, shards
-    travel the ring in the sharded layout, and only the device-LOCAL block
-    engine restrides its shard (absorbed by XLA fusion, no global DMA).
     """
     mesh = mesh_mod.get_mesh()
     if mesh is None or axis not in mesh.axis_names or mesh.shape[axis] <= 1:
         # single chip: the sdpa dispatcher picks the flash kernel on TPU
         from .attention import sdpa
 
-        return sdpa(q, k, v, scale=scale, is_causal=causal, layout=layout,
-                    window=window)
-    h_axis = 2 if layout == "sbnd" else 1
-    grouped = q.shape[h_axis] != k.shape[h_axis]
+        return sdpa(q, k, v, scale=scale, is_causal=causal, window=window)
+    grouped = q.shape[1] != k.shape[1]
     if grouped or window is not None:
         # the flash ring composition merges heads into the flat (bh, s, d)
         # block engine and gates visiting blocks whole — GQA grouping and
@@ -122,33 +115,25 @@ def ring_attention(q, k, v, axis: str = "mp", causal: bool = False,
     if use_flash is None:
         from . import flash as _fl
 
-        use_flash = _fl.available() and _fl.supported(q, k, layout=layout)
+        use_flash = _fl.available() and _fl.supported(q, k)
     if use_flash:
         return ring_flash_attention(q, k, v, axis=axis, causal=causal,
-                                    scale=scale, layout=layout)
+                                    scale=scale)
     ring = int(mesh.shape[axis])
-    seq_first = layout == "sbnd"
-    if seq_first:
-        s, b, h, d = q.shape
-    else:
-        b, h, s, d = q.shape
+    b, h, s, d = q.shape
     if s % ring:
         raise ValueError(f"seq len {s} must divide the ring size {ring}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     s_local = s // ring
 
-    spec = P(axis) if seq_first else P(None, None, axis, None)
+    spec = P(None, None, axis, None)
     sharded = NamedSharding(mesh, spec)
     q = jax.device_put(jnp.asarray(q), sharded)
     k = jax.device_put(jnp.asarray(k), sharded)
     v = jax.device_put(jnp.asarray(v), sharded)
 
     def per_device(ql, kl, vl):
-        if seq_first:
-            # device-local restride of the (s_local, B, H, D) shard only;
-            # the ppermute ring below still moves shards, not transposes
-            ql, kl, vl = (jnp.moveaxis(a, 0, 2) for a in (ql, kl, vl))
         i = lax.axis_index(axis)
         q_off = i * s_local
         o = jnp.zeros(ql.shape[:3] + (vl.shape[-1],), jnp.float32)
@@ -170,8 +155,7 @@ def ring_attention(q, k, v, axis: str = "mp", causal: bool = False,
         (o, m, l, _, _), _ = lax.scan(step, (o, m, l, kl, vl),
                                       jnp.arange(ring))
         l = jnp.where(l == 0.0, 1.0, l)
-        out = (o / l[..., None]).astype(ql.dtype)
-        return jnp.moveaxis(out, 2, 0) if seq_first else out
+        return (o / l[..., None]).astype(ql.dtype)
 
     fn = jax.shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
@@ -186,7 +170,6 @@ def ring_attention(q, k, v, axis: str = "mp", causal: bool = False,
 def ring_flash_attention(q, k, v, axis: str = "mp", causal: bool = False,
                          scale: Optional[float] = None,
                          interpret: Optional[bool] = None,
-                         layout: str = "bnsd",
                          window: Optional[int] = None):
     """Ring attention whose per-device block engine is the Pallas flash
     kernel (kernels/flash.py) instead of the einsum online-softmax.
@@ -207,19 +190,12 @@ def ring_flash_attention(q, k, v, axis: str = "mp", causal: bool = False,
     if mesh is None or axis not in mesh.axis_names or mesh.shape[axis] <= 1:
         from .attention import sdpa
 
-        return sdpa(q, k, v, scale=scale, is_causal=causal, layout=layout,
-                    window=window)
-    h_axis = 2 if layout == "sbnd" else 1
-    if q.shape[h_axis] != k.shape[h_axis] or window is not None:
+        return sdpa(q, k, v, scale=scale, is_causal=causal, window=window)
+    if q.shape[1] != k.shape[1] or window is not None:
         return ring_attention(q, k, v, axis=axis, causal=causal,
-                              scale=scale, use_flash=False, layout=layout,
-                              window=window)
+                              scale=scale, use_flash=False, window=window)
     ring = int(mesh.shape[axis])
-    seq_first = layout == "sbnd"
-    if seq_first:
-        s, b, h, d = q.shape
-    else:
-        b, h, s, d = q.shape
+    b, h, s, d = q.shape
     if s % ring:
         raise ValueError(f"seq len {s} must divide the ring size {ring}")
     s_local = s // ring
@@ -227,13 +203,13 @@ def ring_flash_attention(q, k, v, axis: str = "mp", causal: bool = False,
     if blk is None or d % 8 != 0 or not (16 <= d <= 256):
         # shapes the Mosaic kernel can't take: einsum engine
         return ring_attention(q, k, v, axis=axis, causal=causal,
-                              scale=scale, use_flash=False, layout=layout)
+                              scale=scale, use_flash=False)
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
         interpret = not _fl._backend_is_tpu()
 
-    spec = P(axis) if seq_first else P(None, None, axis, None)
+    spec = P(None, None, axis, None)
     sharded = NamedSharding(mesh, spec)
     q = jax.device_put(jnp.asarray(q), sharded)
     k = jax.device_put(jnp.asarray(k), sharded)
@@ -317,14 +293,6 @@ def ring_flash_attention(q, k, v, axis: str = "mp", causal: bool = False,
 
     _pd.defvjp(_pd_fwd, _pd_bwd)
 
-    def _pd_entry(ql, kl, vl):
-        if not seq_first:
-            return _pd(ql, kl, vl)
-        # device-local restride of the shard into the (b, h, s_local, d)
-        # block engine; the ring ppermutes inside _pd move shards untouched
-        out = _pd(*(jnp.moveaxis(a, 0, 2) for a in (ql, kl, vl)))
-        return jnp.moveaxis(out, 2, 0)
-
-    fn = jax.shard_map(_pd_entry, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(_pd, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -175,23 +175,18 @@ def _ln(x, g, b, eps):
     return y if b is None else y + b
 
 
-def _block_qkv(p, x, n_heads, eps, seq_major=False, n_kv_heads=None,
-               spec=GPT2_LAYER, pos=None):
+def _block_qkv(p, x, n_heads, eps, n_kv_heads=None, spec=GPT2_LAYER,
+               pos=None):
     """The block's pre-attention half: LN1 + fused QKV projection + head
     split (+ the rotation of q and k at ``pos`` (B, T) where ``spec`` asks
-    for rotary positions; batch-major only).
-    Returns ``(q, k_blk, v_blk)`` with ``k_blk``/``v_blk`` in the
-    cache's (B, Hkv, T, D) layout and ``q`` in the layout the attention
-    einsum of the caller's path wants ((T, B, H, D) seq-major, else
-    (B, H, T, D)).  Under GQA the fused projection is (H + 2*Hkv)*D wide
+    for rotary positions).
+    Returns ``(q, k_blk, v_blk)``: ``q`` (B, H, T, D) and ``k_blk``/
+    ``v_blk`` in the cache's (B, Hkv, T, D) layout.  Under GQA the fused projection is (H + 2*Hkv)*D wide
     and the split is uneven — K/V carry only ``n_kv_heads`` heads.  Shared
     by the dense-cache decoder below and the paged-cache serving engine
     (serving/engine.py) so the two decode substrates cannot fork
     numerically."""
-    if seq_major:
-        t, b, h = x.shape
-    else:
-        b, t, h = x.shape
+    b, t, h = x.shape
     hd = spec.head_dim or h // n_heads
     nkv = n_heads if n_kv_heads is None else n_kv_heads
     hx = _ln(x, p["ln1_g"], p["ln1_b"] if spec.norm_bias else None, eps)
@@ -200,19 +195,11 @@ def _block_qkv(p, x, n_heads, eps, seq_major=False, n_kv_heads=None,
         qkv = qkv + p["qkv_b"]
     q, k, v = jnp.split(qkv, [n_heads * hd, (n_heads + nkv) * hd], axis=-1)
 
-    if seq_major:
-        q = q.reshape(t, b, n_heads, hd)
-        k = k.reshape(t, b, nkv, hd)
-        v = v.reshape(t, b, nkv, hd)
-        # cache blocks are tiny in decode (T=1): einsum to the cache layout
-        k_blk = jnp.einsum("tbhd->bhtd", k)
-        v_blk = jnp.einsum("tbhd->bhtd", v)
-    else:
-        def heads(z, n):  # (B, T, n*hd) -> (B, n, T, hd)
-            return z.reshape(b, t, n, hd).transpose(0, 2, 1, 3)
+    def heads(z, n):  # (B, T, n*hd) -> (B, n, T, hd)
+        return z.reshape(b, t, n, hd).transpose(0, 2, 1, 3)
 
-        q = heads(q, n_heads)
-        k_blk, v_blk = heads(k, nkv), heads(v, nkv)
+    q = heads(q, n_heads)
+    k_blk, v_blk = heads(k, nkv), heads(v, nkv)
     if spec.position == "rope":
         q = rope_interleaved(q, pos, spec.rope_theta)
         k_blk = rope_interleaved(k_blk, pos, spec.rope_theta)
@@ -280,16 +267,13 @@ def dense_attention(q, k, v, window=None):
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
-def _block_fwd(p, x, k_cache, v_cache, pos, n_heads, eps, seq_major=False,
-               n_kv_heads=None, window=None):
-    """One decoder block over ``x`` with cache write at ``pos``.
+def _block_fwd(p, x, k_cache, v_cache, pos, n_heads, eps, n_kv_heads=None,
+               window=None):
+    """One decoder block over ``x`` (B, T, h) with cache write at ``pos``.
 
-    ``x`` is (B, T, h) batch-major or (T, B, h) when ``seq_major`` — the
-    model's [S, B, H] activation layout (GPTConfig.seq_major).  The KV cache
-    keeps its (B, Hkv, S, D) layout in both modes (Hkv < H under GQA; the
-    attention einsums group query heads over the shared K/V head by a
-    reshape, never by repeating the cache); the attention einsums
-    consume/produce the seq-major activations in place.  A quantized cache
+    The KV cache is (B, Hkv, S, D) (Hkv < H under GQA; the attention
+    einsums group query heads over the shared K/V head by a reshape, never
+    by repeating the cache).  A quantized cache
     arrives as a ``(values, scales)`` tuple per side — int8 values, or
     packed int4 nibbles (last dim D//2, detected from the shape); the new
     K/V block is quantized at the write and the whole cache dequantizes
@@ -301,14 +285,10 @@ def _block_fwd(p, x, k_cache, v_cache, pos, n_heads, eps, seq_major=False,
 
     Works for prefill (T = prompt len, pos = 0) and decode (T = 1,
     pos = current length).  Returns (y, k_cache, v_cache)."""
-    if seq_major:
-        t, b, h = x.shape
-    else:
-        b, t, h = x.shape
+    b, t, h = x.shape
     hd = h // n_heads
     nkv = n_heads if n_kv_heads is None else n_kv_heads
-    q, k_blk, v_blk = _block_qkv(p, x, n_heads, eps, seq_major=seq_major,
-                                 n_kv_heads=n_kv_heads)
+    q, k_blk, v_blk = _block_qkv(p, x, n_heads, eps, n_kv_heads=n_kv_heads)
     quant_kv = isinstance(k_cache, tuple)
     if quant_kv:
         kq, ksc = k_cache
@@ -332,15 +312,12 @@ def _block_fwd(p, x, k_cache, v_cache, pos, n_heads, eps, seq_major=False,
     grouped = nkv != n_heads
     if grouped:
         g = n_heads // nkv
-        qg = (q.reshape(t, b, nkv, g, hd) if seq_major
-              else q.reshape(b, nkv, g, t, hd))
-        scores = jnp.einsum(
-            "tbngd,bnsd->bngts" if seq_major else "bngtd,bnsd->bngts",
-            qg, k_eff, preferred_element_type=jnp.float32)
+        qg = q.reshape(b, nkv, g, t, hd)
+        scores = jnp.einsum("bngtd,bnsd->bngts", qg, k_eff,
+                            preferred_element_type=jnp.float32)
     else:
-        scores = jnp.einsum(
-            "tbhd,bhsd->bhts" if seq_major else "bhtd,bhsd->bhts",
-            q, k_eff, preferred_element_type=jnp.float32)
+        scores = jnp.einsum("bhtd,bhsd->bhts", q, k_eff,
+                            preferred_element_type=jnp.float32)
     scores = scores / np.sqrt(hd).astype(np.float32)
     # causal + cache-validity mask over global positions
     q_pos = pos + jnp.arange(t)[:, None]
@@ -352,19 +329,11 @@ def _block_fwd(p, x, k_cache, v_cache, pos, n_heads, eps, seq_major=False,
     scores = jnp.where(bmask, scores, -1e30)
     att = jax.nn.softmax(scores, axis=-1).astype(v_eff.dtype)
     if grouped:
-        if seq_major:
-            out = jnp.einsum("bngts,bnsd->tbngd", att, v_eff) \
-                .reshape(t, b, h)
-        else:
-            out = jnp.einsum("bngts,bnsd->bngtd", att, v_eff) \
-                .reshape(b, n_heads, t, hd)
-            out = out.transpose(0, 2, 1, 3).reshape(b, t, h)
-    elif seq_major:
-        out = jnp.einsum("bhts,bhsd->tbhd", att, v_eff).reshape(t, b, h)
+        out = jnp.einsum("bngts,bnsd->bngtd", att, v_eff) \
+            .reshape(b, n_heads, t, hd)
     else:
         out = jnp.einsum("bhts,bhsd->bhtd", att, v_eff)
-        out = out.transpose(0, 2, 1, 3).reshape(b, t, h)
-    out = out.astype(x.dtype)
+    out = out.transpose(0, 2, 1, 3).reshape(b, t, h).astype(x.dtype)
     return _block_finish(p, x, out, eps), k_cache, v_cache
 
 
@@ -403,7 +372,6 @@ def _decoder_setup(model, int8=None, attn_window=None):
     n_kv_heads = getattr(cfg, "num_kv_heads", None) or n_heads
     window = (attn_window if attn_window is not None
               else getattr(cfg, "attn_window", None))
-    seq_major = bool(getattr(cfg, "seq_major", False))
     params = {
         "wte": gpt.embeddings.word_embeddings.weight._array,
         "wpe": gpt.embeddings.position_embeddings.weight._array,
@@ -418,26 +386,18 @@ def _decoder_setup(model, int8=None, attn_window=None):
         def run(tokens, pos, kc, vc):
             t = tokens.shape[1]
             pe = p["wpe"][pos + jnp.arange(t)]
-            if seq_major:
-                # [T, B, h] through the blocks (cfg.seq_major decode)
-                x = p["wte"][tokens.T] + pe[:, None, :]
-            else:
-                x = p["wte"][tokens] + pe
+            x = p["wte"][tokens] + pe
             new_k, new_v = [], []
             for li, bp in enumerate(p["blocks"]):
                 # per-layer cache slice / re-stack via tree ops so the int8
                 # (values, scales) tuple caches thread the same code path
                 x, k1, v1 = _block_fwd(bp, x, _tree_map(lambda a: a[li], kc),
                                        _tree_map(lambda a: a[li], vc), pos,
-                                       n_heads, eps, seq_major=seq_major,
-                                       n_kv_heads=n_kv_heads, window=window)
+                                       n_heads, eps, n_kv_heads=n_kv_heads,
+                                       window=window)
                 new_k.append(k1)
                 new_v.append(v1)
-            logits = logits_from(x)
-            if seq_major:
-                # callers index logits[:, -1]: keep the (B, T, V) contract
-                logits = jnp.swapaxes(logits, 0, 1)
-            return (logits, _tree_map(lambda *xs: jnp.stack(xs), *new_k),
+            return (logits_from(x), _tree_map(lambda *xs: jnp.stack(xs), *new_k),
                     _tree_map(lambda *xs: jnp.stack(xs), *new_v))
 
         return run

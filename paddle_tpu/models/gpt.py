@@ -39,12 +39,6 @@ class GPTConfig:
     layer_norm_eps: float = 1e-5
     initializer_range: float = 0.02
     use_parallel: bool = False  # TP layers over the 'mp' axis
-    # seq_major: thread a [S, B, H] activation layout from the embedding to
-    # the logits so the flash kernel's seq-major entry (layout="sbnd",
-    # kernels/flash._fwd_call_smajor) sees the model-natural layout with ZERO
-    # transposes at either end.  Batch-major stays the default until the
-    # seq-major flagship point is benched (bench.py flagship_seq_major).
-    seq_major: bool = False
     # int8: W8A8 execution for the QKV/output/MLP projections — REAL int8
     # GEMMs (per-output-channel weight quant + dynamic per-token activation
     # quant, int32 MXU accumulation via ops/quant_ops.w8a8_matmul ->
@@ -144,7 +138,6 @@ class GPTAttention(nn.Layer):
         self.head_dim = cfg.hidden_size // cfg.num_heads
         self.window = cfg.attn_window
         self.dropout = cfg.dropout
-        self.seq_major = cfg.seq_major
         self.int8 = cfg.int8
         init = nn.initializer.Normal(0.0, cfg.initializer_range)
         wa = nn.ParamAttr(initializer=init)
@@ -174,38 +167,16 @@ class GPTAttention(nn.Layer):
 
     def forward(self, x):
         hd = self.head_dim
-        if self.seq_major:
-            # [S, B, H] in, [S, B, H] out — q/k/v reach the kernel through
-            # reshapes and last-dim slices only (NO transposes; the sbnd
-            # kernel entry consumes the layout in place, and GQA only
-            # changes the split widths — K/V stay num_kv_heads wide all the
-            # way into the kernel)
-            s, b, h = x.shape
-            qkv = self._run_qkv(x)
-            w = qkv.shape[-1]
-            nkv = self.num_kv_heads * w // (
-                (self.num_heads + 2 * self.num_kv_heads) * hd)
-            nh = (w - 2 * nkv * hd) // hd
-            q, k, v = T.split(qkv, [nh * hd, nkv * hd, nkv * hd], axis=-1)
-            out = F.scaled_dot_product_attention(
-                T.reshape(q, [s, b, nh, hd]), T.reshape(k, [s, b, nkv, hd]),
-                T.reshape(v, [s, b, nkv, hd]),
-                is_causal=True, dropout_p=self.dropout,
-                training=self.training, layout="sbnd", window=self.window)
-            return self._run_proj(T.reshape(out, [s, b, nh * hd]))
         b, s, h = x.shape
         qkv = self._run_qkv(x)
         w = qkv.shape[-1]
         nkv = self.num_kv_heads * w // (
             (self.num_heads + 2 * self.num_kv_heads) * hd)
         nh = (w - 2 * nkv * hd) // hd
-        # measured (flagship, v5e): the [b,nh,s,hd] transposes around the
-        # flash call cost ~34ms/step, but the seq-major kernel variant
-        # (layout="bsnd", kernels/flash._fwd_call_smajor) loses MORE to
-        # strided K/V DMA (55.0% vs 57.1% MFU) — contiguous (bh, s, d)
-        # tiles + XLA transposes win, so batch-major stays bnsd; the
-        # END-TO-END seq-major layout is cfg.seq_major (the [S, B, H] branch
-        # above), which removes the transposes without restriding K/V.
+        # bnsd: the flash kernel reads contiguous (bh, s, d) tiles and XLA
+        # does the [b,nh,s,hd] transposes around it.  The in-place entry
+        # (layout="bsnd", kernels/flash._fwd_call_smajor) saves those
+        # transposes but DMAs K/V strided, which costs more than it saves.
         q, k, v = T.split(qkv, [nh * hd, nkv * hd, nkv * hd], axis=-1)
         q = T.transpose(T.reshape(q, [b, s, nh, hd]), [0, 2, 1, 3])
         k = T.transpose(T.reshape(k, [b, s, nkv, hd]), [0, 2, 1, 3])
@@ -275,20 +246,12 @@ class GPTEmbeddings(nn.Layer):
             cfg.max_seq_len, cfg.hidden_size,
             weight_attr=nn.ParamAttr(initializer=init))
         self.dropout = nn.Dropout(cfg.dropout)
-        self.seq_major = cfg.seq_major
 
     def forward(self, ids):
         b, s = ids.shape
         pos = T.arange(0, s, 1, dtype="int64")
         pe = self.position_embeddings(pos)
-        if self.seq_major:
-            # transpose the int32 [B, S] ids ONCE at the entry; everything
-            # downstream (blocks, LN, logits) stays [S, B, H]
-            x = self.word_embeddings(T.transpose(ids, [1, 0])) \
-                + T.unsqueeze(pe, [1])
-        else:
-            x = self.word_embeddings(ids) + pe
-        return self.dropout(x)
+        return self.dropout(self.word_embeddings(ids) + pe)
 
 
 class GPTModel(nn.Layer):
@@ -328,21 +291,9 @@ class GPTForPretraining(nn.Layer):
 
 
 class GPTPretrainingCriterion(nn.Layer):
-    """Next-token CE (vocab-parallel when logits are mp-sharded).
-
-    ``seq_major``: logits arrive [S, B, V] while labels stay in the data
-    layout [B, S] — the cheap int label transpose happens HERE so the big
-    logits tensor never changes layout."""
-
-    def __init__(self, seq_major: bool = False):
-        super().__init__()
-        self.seq_major = seq_major
+    """Next-token CE (vocab-parallel when logits are mp-sharded)."""
 
     def forward(self, logits, labels, loss_mask=None):
-        if self.seq_major:
-            labels = T.transpose(labels, [1, 0])
-            if loss_mask is not None:
-                loss_mask = T.transpose(loss_mask, [1, 0])
         loss = F.softmax_with_cross_entropy(logits, T.unsqueeze(labels, [-1]))
         loss = T.squeeze(loss, [-1])
         if loss_mask is not None:
@@ -384,8 +335,7 @@ def GPTForPretrainingPipe(cfg: GPTConfig, num_stages: Optional[int] = None,
     ]
     return PipelineLayer(
         layers=descs, num_stages=num_stages,
-        loss_fn=GPTPretrainingCriterion(seq_major=cfg.seq_major),
-        seq_major=cfg.seq_major, **kw)
+        loss_fn=GPTPretrainingCriterion(), **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -452,8 +402,6 @@ def build_functional_train_step(model: GPTForPretraining, lr: float = 1e-4,
     mesh = mesh_mod.get_mesh()
     pp = mesh_mod.axis_size("pp")
     shd = mesh_mod.axis_size("sharding")
-    # seq-major activations put batch on dim 1; ids/labels stay [B, S]
-    seq_major = bool(getattr(model.cfg, "seq_major", False))
     if sharding_stage is None:
         # honor DistributedStrategy.sharding_configs["stage"] when fleet is up
         try:
@@ -544,8 +492,8 @@ def build_functional_train_step(model: GPTForPretraining, lr: float = 1e-4,
 
     def _constrain_dp(x):
         if mesh is not None and mesh_mod.axis_size(dp_axis) > 1:
-            spec = P(None, dp_axis) if seq_major else P(dp_axis)
-            return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+            return lax.with_sharding_constraint(
+                x, NamedSharding(mesh, P(dp_axis)))
         return x
 
     def fwd(params_tree, ids):
@@ -631,13 +579,10 @@ def build_functional_train_step(model: GPTForPretraining, lr: float = 1e-4,
 
     def loss_fn(params_tree, ids, labels):
         x, w = fwd(params_tree, ids)
-        if seq_major:
-            # x is [S, B, H]; align the (cheap, int) labels to it
-            labels = jnp.swapaxes(labels, 0, 1)
-        d0, d1, h = x.shape
+        b, s, h = x.shape
         if ce_chunk_rows:
-            return _chunked_softmax_xent(x.reshape(d0 * d1, h), w,
-                                         labels.reshape(d0 * d1),
+            return _chunked_softmax_xent(x.reshape(b * s, h), w,
+                                         labels.reshape(b * s),
                                          chunk_rows=ce_chunk_rows)
         logits = jnp.matmul(x, w.T)
         lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)

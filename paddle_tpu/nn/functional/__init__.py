@@ -613,8 +613,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     """TPU fast path: routes to the fused attention kernel (Pallas when
     available, XLA-fused otherwise).  Beyond-parity: the reference only has
     multihead_matmul fusion for inference (operators/fused/multihead_matmul_op.cu).
-    ``layout="bsnd"`` consumes [b, seq, heads, dim] seq-major in place (no
-    transposes around the kernel) — the layout paddle's own 2.3+ sdpa uses.
+    ``layout="bnsd"`` (default) takes [b, heads, seq, dim];
+    ``layout="bsnd"`` consumes [b, seq, heads, dim] in place (no transposes
+    around the kernel) — the layout paddle's own 2.3+ sdpa uses.  Any other
+    layout raises ValueError.
     K/V with fewer heads than Q select grouped-query attention (query heads
     gathered per group inside the kernel); ``window`` restricts the causal
     mask to the trailing ``window`` positions (sliding-window attention)."""
@@ -626,21 +628,18 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     )
 
 
-def ring_attention(query, key, value, axis="mp", is_causal=False, name=None,
-                   layout="bnsd"):
+def ring_attention(query, key, value, axis="mp", is_causal=False, name=None):
     """Sequence-parallel attention over a mesh axis (kernels/ring.py):
     Q/K/V sequence-sharded, K/V streamed around the ICI ring via ppermute.
     Beyond-parity long-context path (SURVEY §5); inputs/outputs are
-    (B, H, S, D) Tensors — or (S, B, NH, D) with ``layout="sbnd"``, the
-    model's seq-major activation layout (GPTConfig.seq_major) — output
-    sequence-sharded like the inputs.
+    (B, H, S, D) Tensors, output sequence-sharded like the inputs.
     Differentiable (vjp through the shard_map ring)."""
     from ...kernels.ring import ring_attention as _ring
 
     from ...dygraph import tracer
 
     def fn(q, k, v):
-        return _ring(q, k, v, axis=axis, causal=is_causal, layout=layout)
+        return _ring(q, k, v, axis=axis, causal=is_causal)
 
     return tracer.trace_fn(fn, [query, key, value], name="ring_attention")
 

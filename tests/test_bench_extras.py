@@ -1,6 +1,6 @@
-"""bench.py extras must be runnable on CPU: the seq-major flagship config
-(tiny-sized here), the eager-vs-jit dispatch-latency microbench, and the
-DataLoader spawn+shm-ring throughput microbench (ISSUE r06 acceptance)."""
+"""bench.py extras must be runnable on CPU: the eager-vs-jit
+dispatch-latency microbench and the DataLoader spawn+shm-ring throughput
+microbench (ISSUE r06 acceptance), and a tiny-sized smoke of each leg."""
 
 import os
 import sys
@@ -13,19 +13,6 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import bench  # noqa: E402
-
-
-def test_seq_major_bench_config_runs():
-    from paddle_tpu.models import GPTConfig
-
-    res = bench._run(
-        GPTConfig(vocab_size=256, hidden_size=64, num_layers=2, num_heads=2,
-                  max_seq_len=64, dropout=0.0, seq_major=True),
-        batch=2, seq=32, steps=2, peak_flops=1e12,
-        dtype="float32", remat=False, ce_rows=0)
-    assert res["tokens_per_sec"] > 0
-    assert np.isfinite(res["loss"])
-    assert res["config"]["seq"] == 32
 
 
 def test_dispatch_latency_bench_emits_numbers():
@@ -142,20 +129,32 @@ def test_prefix_serving_bench_smoke():
 
 
 def test_metrics_overhead_bench_smoke():
-    """r11 acceptance point: the metrics-on engine completes the same
-    load as the metrics-off engine and reports a sane goodput ratio.
-    The < 2% bar is asserted loosely here (CPU CI timing noise on a
-    sub-second run dwarfs the real registry cost); bench.py records the
-    honest number on quiet hardware."""
+    """Smoke of the observability-cost leg: the bare, metrics+trace and
+    full-stack engines each finish the SAME load, report a finite positive
+    rate, and the observed legs embed a registry that counted that load.
+    The cost itself (the ratio of two wall-clock rates) is no assertion
+    here: on a shared CPU a sub-second run's ratio is scheduling noise."""
     res = bench._metrics_overhead_bench(hidden=48, layers=1, heads=2,
                                         vocab=128, n_requests=8,
                                         max_slots=2, page_size=8,
                                         prompt_len=8, new_tokens=12,
                                         dtype="float32")
-    assert res["off_tokens_per_sec"] > 0
-    assert res["on_tokens_per_sec"] > 0
-    assert res["on_off_ratio"] > 0.5       # noise guard, not the 2% bar
     assert res["config"]["n_requests"] == 8
+    for name in ("off", "on", "full"):
+        rate = res[f"{name}_tokens_per_sec"]
+        assert np.isfinite(rate) and rate > 0, (name, rate)
+        leg = res["legs"][name]
+        assert (leg["requests"], leg["tokens"]) == (8, 8 * 12), (name, leg)
+    for ratio in ("on_off_ratio", "full_off_ratio"):
+        assert np.isfinite(res[ratio]) and res[ratio] > 0
+    assert res["legs"]["off"]["metrics"] is None
+    for name in ("on", "full"):
+        m = res["legs"][name]["metrics"]
+        # the registry was attached before the two-token warm-up request
+        assert m["serving_requests_terminal_length"] == 8 + 1
+        assert m["serving_tokens_generated"] == 8 * 12 + 2
+    assert res["legs"]["full"]["metrics"][
+        "serving_tenant_tokens_generated.tenant=bench"] == 8 * 12
 
 
 @pytest.mark.slow

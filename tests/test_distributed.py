@@ -130,6 +130,23 @@ def test_vocab_parallel_embedding_and_parallel_ce():
     assert logits.grad is not None
 
 
+def test_parallel_cross_entropy_ignores_the_order_of_leading_dims():
+    """ParallelCrossEntropy is indifferent to its leading dims: [S, B, V]
+    logits + [S, B, 1] labels give the transposed [B, S] losses."""
+    from paddle_tpu.distributed.fleet.meta_parallel import ParallelCrossEntropy
+
+    rng = np.random.RandomState(0)
+    s, b, v = 8, 4, 32
+    logits = rng.randn(b, s, v).astype("float32")
+    labels = rng.randint(0, v, (b, s, 1)).astype("int64")
+    ce = ParallelCrossEntropy()
+    ref = ce(paddle.to_tensor(logits), paddle.to_tensor(labels)).numpy()
+    out = ce(paddle.to_tensor(np.transpose(logits, (1, 0, 2))),
+             paddle.to_tensor(np.transpose(labels, (1, 0, 2)))).numpy()
+    np.testing.assert_allclose(np.transpose(out, (1, 0, 2)), ref,
+                               rtol=1e-6, atol=1e-6)
+
+
 def _strategy(dp=1, mp=1, pp=1, sharding=1):
     s = fleet.DistributedStrategy()
     s.hybrid_configs = {

@@ -200,3 +200,39 @@ def test_flash_bsnd_seq_major_matches_bnsd():
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-5)
+
+
+def _call_flash(q, layout):
+    return flash.flash_attention(q, q, q, causal=True, layout=layout,
+                                 interpret=True)
+
+
+def _call_sdpa(q, layout):
+    from paddle_tpu.kernels.attention import sdpa
+
+    return sdpa(q, q, q, is_causal=True, layout=layout)
+
+
+def _call_functional(q, layout):
+    import paddle_tpu as paddle
+    import paddle_tpu.nn.functional as F
+
+    t = paddle.to_tensor(np.asarray(q))
+    return F.scaled_dot_product_attention(t, t, t, is_causal=True,
+                                          training=False, layout=layout)
+
+
+@pytest.mark.parametrize("layout", ["sbnd", "no-such-layout"])
+@pytest.mark.parametrize("entry", [_call_flash, _call_sdpa, _call_functional],
+                         ids=["flash_attention", "sdpa",
+                              "F.scaled_dot_product_attention"])
+def test_unknown_layout_is_refused(entry, layout):
+    """A layout no kernel implements is an error that names the accepted
+    ones at every entry, never a quiet computation in another layout (on a
+    4-D array any permutation of the axes has a valid shape)."""
+    q = jnp.zeros((1, 16, 16, 16), jnp.float32)
+    assert not flash.supported(q, q, layout=layout)
+    with pytest.raises(ValueError, match="accepted layouts.*bnsd.*bsnd"):
+        entry(q, layout)
+    for known in flash.LAYOUTS:     # the same calls with a known layout run
+        assert np.asarray(entry(q, known)).shape == q.shape

@@ -10,7 +10,7 @@ dense decoder token-for-token, not approximately.  All CPU-runnable:
     prefill, each across group factor {1, 2, 4} x window {off, on} x page
     bits {float, 8, 4}, kernel (interpret — the exact TPU code path) vs
     jnp reference;
-  * layout: the flash sbnd GQA path reaches the Pallas kernel with ZERO
+  * layout: the flash bsnd GQA path reaches the Pallas kernel with ZERO
     transpose primitives, and GQA adds zero transposes to the ring
     engine's jaxpr;
   * int4 plumbing: pack/unpack round-trip, the quantization error band,
@@ -333,25 +333,25 @@ def test_gather_pages_int4_matches_manual_dequant():
 
 
 # ---------------------------------------------------------------------------
-# layout: GQA adds zero transposes around the seq-major kernels
+# layout: GQA adds zero transposes around the bsnd kernels
 # ---------------------------------------------------------------------------
 
 
-def test_flash_sbnd_gqa_window_no_transposes():
-    """The sbnd flash entry consumes GQA K/V in place — query-head groups
+@pytest.mark.parametrize("window", [None, 48])
+def test_flash_bsnd_gqa_window_no_transposes(window):
+    """The bsnd flash entry consumes GQA K/V in place — query-head groups
     gather onto the shared K/V head inside the BlockSpec index maps, so
     the jaxpr reaches pallas_call without one transpose primitive, window
     on or off."""
-    s, b, h, hkv, d = 128, 2, 4, 2, 32
-    q = jnp.zeros((s, b, h, d), jnp.float32)
-    k = jnp.zeros((s, b, hkv, d), jnp.float32)
-    v = jnp.zeros((s, b, hkv, d), jnp.float32)
-    for window in (None, 48):
-        jx = jax.make_jaxpr(lambda q, k, v: flash.flash_attention(
-            q, k, v, causal=True, layout="sbnd", window=window,
-            interpret=True))(q, k, v)
-        assert count_primitive(jx, "pallas_call") >= 1
-        assert count_primitive(jx, "transpose") == 0
+    b, s, h, hkv, d = 2, 128, 4, 2, 32
+    q = jnp.zeros((b, s, h, d), jnp.float32)
+    k = jnp.zeros((b, s, hkv, d), jnp.float32)
+    v = jnp.zeros((b, s, hkv, d), jnp.float32)
+    jx = jax.make_jaxpr(lambda q, k, v: flash.flash_attention(
+        q, k, v, causal=True, layout="bsnd", window=window,
+        interpret=True))(q, k, v)
+    assert count_primitive(jx, "pallas_call") >= 1
+    assert count_primitive(jx, "transpose") == 0
 
 
 def test_ring_gqa_adds_zero_transposes():
@@ -373,12 +373,14 @@ def test_ring_gqa_adds_zero_transposes():
     assert probe(kg) <= probe(kf)
 
 
-def _sbnd_reference(q, k, v, window):
-    s_len, _, h, d = q.shape
-    g = h // k.shape[2]
-    kk = jnp.repeat(k, g, axis=2)
-    vv = jnp.repeat(v, g, axis=2)
-    logits = jnp.einsum("ibhd,jbhd->bhij", q, kk) / np.sqrt(d)
+def _repeat_heads_reference(q, k, v, window):
+    """Causal (windowed) attention over [b, h, s, d] q and [b, hkv, s, d]
+    k/v with K/V heads repeated to h: the plain oracle for GQA."""
+    _, h, s_len, d = q.shape
+    g = h // k.shape[1]
+    kk = jnp.repeat(k, g, axis=1)
+    vv = jnp.repeat(v, g, axis=1)
+    logits = jnp.einsum("bhid,bhjd->bhij", q, kk) / np.sqrt(d)
     i = jnp.arange(s_len)[:, None]
     j = jnp.arange(s_len)[None, :]
     mask = j <= i
@@ -386,29 +388,34 @@ def _sbnd_reference(q, k, v, window):
         mask = mask & (j > i - window)
     logits = jnp.where(mask[None, None], logits, -1e30)
     att = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhij,jbhd->ibhd", att, vv)
+    return jnp.einsum("bhij,bhjd->bhid", att, vv)
 
 
-def test_flash_sbnd_gqa_window_matches_reference():
-    """Forward AND gradients of the sbnd GQA + window kernel == the
-    repeat-heads einsum oracle."""
+@pytest.mark.parametrize("hkv,w", [(2, 100), (2, None), (4, 100)],
+                         ids=["gqa2_window", "gqa2", "window"])
+def test_flash_bsnd_gqa_window_matches_reference(hkv, w):
+    """Forward AND gradients of the bsnd kernel under GQA, under a window
+    and under both == the repeat-heads einsum oracle."""
     rng = np.random.RandomState(0)
-    s, b, h, hkv, d, w = 256, 2, 4, 2, 32, 100
-    q = jnp.asarray(rng.randn(s, b, h, d).astype("float32"))
-    k = jnp.asarray(rng.randn(s, b, hkv, d).astype("float32"))
-    v = jnp.asarray(rng.randn(s, b, hkv, d).astype("float32"))
+    b, s, h, d = 2, 256, 4, 32
+    q = jnp.asarray(rng.randn(b, s, h, d).astype("float32"))
+    k = jnp.asarray(rng.randn(b, s, hkv, d).astype("float32"))
+    v = jnp.asarray(rng.randn(b, s, hkv, d).astype("float32"))
 
     def f(q, k, v):
-        return flash.flash_attention(q, k, v, causal=True, layout="sbnd",
+        return flash.flash_attention(q, k, v, causal=True, layout="bsnd",
                                      window=w, interpret=True)
 
+    def oracle(q, k, v):  # bsnd -> the oracle's bnsd and back
+        return jnp.swapaxes(_repeat_heads_reference(
+            *(jnp.swapaxes(a, 1, 2) for a in (q, k, v)), w), 1, 2)
+
     out = f(q, k, v)
-    ref = _sbnd_reference(q, k, v, w)
+    ref = oracle(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     g_k = jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=(0, 1, 2))
-    g_r = jax.grad(lambda *a: jnp.sum(_sbnd_reference(*a, w) ** 2),
-                   argnums=(0, 1, 2))
+    g_r = jax.grad(lambda *a: jnp.sum(oracle(*a) ** 2), argnums=(0, 1, 2))
     for a, b_ in zip(g_k(q, k, v), g_r(q, k, v)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4)
@@ -443,11 +450,8 @@ def test_ring_gqa_window_matches_reference():
     out = np.asarray(ring_attention(jnp.asarray(q), jnp.asarray(k),
                                     jnp.asarray(v), axis="mp", causal=True,
                                     window=w))
-    # same oracle, bnsd layout
-    ref = np.asarray(jnp.transpose(_sbnd_reference(
-        jnp.transpose(jnp.asarray(q), (2, 0, 1, 3)),
-        jnp.transpose(jnp.asarray(k), (2, 0, 1, 3)),
-        jnp.transpose(jnp.asarray(v), (2, 0, 1, 3)), w), (1, 2, 0, 3)))
+    ref = np.asarray(_repeat_heads_reference(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), w))
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 

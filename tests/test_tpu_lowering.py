@@ -2,8 +2,8 @@
 
 ``interpret=True`` (what every other kernel test runs) never meets the
 Mosaic lowering, so a kernel can be exact on the CPU and still not exist
-on the chip: the single-query decode kernel and the ``sbnd`` flash entry
-were both in that state (ISSUE 21).  Lowering with ``interpret=False`` for
+on the chip: the single-query decode kernel was in that state (ISSUE 21).
+Lowering with ``interpret=False`` for
 ``lowering_platforms=("tpu",)`` needs no chip and takes seconds.  It is
 LOWERING only — Mosaic's layout and VMEM checks run inside libtpu at
 compile time, which ``python chip_smoke.py`` exercises on the chip with
@@ -26,22 +26,7 @@ import pytest
 import chip_smoke
 from paddle_tpu.utils import compile_cache
 
-SBND_REFUSAL = (
-    "the TPU lowering refuses a squeezed second-to-last block dimension: "
-    "flash._smajor_specs builds (block, None, d) blocks over the (S, B, "
-    "H*D) array — ValueError: ... last two dimensions of your block shape "
-    "... (ROADMAP D4: seq_major cannot run on the chip as written)")
-
-
-def _params():
-    for name in chip_smoke.KERNEL_CASES:
-        marks = [pytest.mark.xfail(strict=True, raises=ValueError,
-                                   reason=SBND_REFUSAL)] \
-            if name.startswith("flash_sbnd") else []
-        yield pytest.param(name, marks=marks)
-
-
-@pytest.mark.parametrize("name", _params())
+@pytest.mark.parametrize("name", list(chip_smoke.KERNEL_CASES))
 def test_kernel_lowers_for_tpu(name):
     case = chip_smoke.kernel_case(name)
     lowered = jax.jit(case.kernel).trace(*case.args).lower(
