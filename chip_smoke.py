@@ -317,6 +317,38 @@ def _w8a8_case(*, required, m, k, n):
 
     return KernelCase(required, TOL_FWD, kernel, oracle, (x, wq, ws))
 
+def _cell_prefill_case(*, required, heads, n_kv, max_pages, start,
+                       window=None):
+    """The chunk kernel at a benchmark cell's own shape: one 128-row chunk
+    that follows ``start`` written positions of a ``max_pages``-entry
+    table.  The entries up to the chunk's end name pages of a small pool,
+    the rest the null page, as the engine leaves them."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import paged_prefill as pp
+
+    rng = np.random.RandomState(0)
+    kp, vp = (jnp.asarray(
+        rng.randn(1 + 256, n_kv, PAGE, HEAD_DIM).astype("float32"),
+        jnp.bfloat16) for _ in range(2))
+    written = -(-(start + CHUNK) // PAGE)
+    table = np.zeros((max_pages,), np.int32)
+    table[:written] = 1 + rng.permutation(256)[:written]
+    q = jnp.asarray(rng.randn(CHUNK, heads, HEAD_DIM).astype("float32"),
+                    jnp.bfloat16)
+
+    def kernel(q, kp, vp, table, start):
+        return (pp.paged_prefill(q, kp, vp, table, start, window=window,
+                                 interpret=False),)
+
+    def oracle(q, kp, vp, table, start):
+        return (pp.paged_prefill_ref(q, kp, vp, table, start,
+                                     window=window),)
+
+    return KernelCase(required, TOL_FWD, kernel, oracle,
+                      (q, kp, vp, jnp.asarray(table), jnp.int32(start)))
+
+
 
 _H = WIDTH["hidden_size"]
 
@@ -344,6 +376,16 @@ KERNEL_CASES = {
         required=True, slots=32, heads=128, n_kv=8, max_pages=256)),
     "paged_attention_cell_gqa16_window": (_cell_decode_case, dict(
         required=True, slots=32, heads=128, n_kv=8, max_pages=256,
+        window=4096)),
+    # ... and their chunk shapes: a chunk part-way through a 1.3b prompt,
+    # one deep in a long Command A+ prompt on a full layer, and the same on
+    # a sliding layer (``start`` past the window)
+    "paged_prefill_cell_1.3b": (_cell_prefill_case, dict(
+        required=True, heads=16, n_kv=16, max_pages=32, start=9 * PAGE + 7)),
+    "paged_prefill_cell_gqa16": (_cell_prefill_case, dict(
+        required=True, heads=128, n_kv=8, max_pages=256, start=9000)),
+    "paged_prefill_cell_gqa16_window": (_cell_prefill_case, dict(
+        required=True, heads=128, n_kv=8, max_pages=256, start=9000,
         window=4096)),
     "w8a8_gemm_chunk": (_w8a8_case, dict(
         required=True, m=CHUNK, k=_H, n=3 * _H)),

@@ -10,19 +10,28 @@ gather as the history and the kernel needs no separate in-chunk path.
 
 Design (pallas_guide.md, same skeleton as the decode kernel):
 
-  * grid = (pages,); the slot's block table and the chunk's global start
-    position ride in as SCALAR-PREFETCH args, so the K/V page of grid
-    step p is ``block_table[p]`` — the gather IS the BlockSpec index_map,
-    i.e. the DMA schedule;
+  * the grid is the chunk's LIVE PAGES and nothing else: the logical pages
+    ``[lo, hi)`` some row of the chunk sees, ``paged_attention.live_pages(
+    start + 1, rows=chunk)`` under the layer's window — the range the
+    decode kernels, the masks and the engine's counters share.  ``hi - lo``
+    is the grid's traced bound on the page axis; the slot's block table and
+    ``(start, lo, hi)`` ride in as SCALAR-PREFETCH args, so the K/V page of
+    grid step p is ``block_table[lo + p]`` — the gather IS the BlockSpec
+    index_map, i.e. the DMA schedule.  A chunk therefore costs the context
+    it attends: no grid step, DMA or score for a table entry past the
+    chunk's end or under its window, whatever the table's width (such an
+    entry may name any page);
   * the whole (chunk, H, D) query block sits in VMEM across the page
     grid where it fits; where it does not (128 query heads of 128: 17 MB),
     the grid gains a leading axis over KV heads and a block is one KV
     head's group, (chunk, H / Hkv, D), against that head's (page_size, D)
     rows of the page: :func:`block_heads` decides from the shapes.  Each
-    page folds into a flash online-softmax recurrence with
-    per-query m/l/acc scratch.  CAUSALITY is the only mask: page position
-    j is visible to chunk row i iff ``j <= start + i`` — global position
-    0 is visible to every row, so no row is ever fully masked;
+    live page folds into a flash online-softmax recurrence with per-query
+    m/l/acc scratch, initialised on page ``lo`` and divided out on page
+    ``hi - 1``.  Inside a live page CAUSALITY (and the window's lower
+    bound) is the mask: page position j is visible to chunk row i iff
+    ``j <= start + i`` — a row of the prompt sees its own position, which
+    lies in a live page, so none is ever fully masked;
   * int8 pages carry fp32 per-(position, head) scales dequantized in
     VMEM right after the page DMA — the identical layout/decision as the
     decode kernel and the dense int8 KV cache;
@@ -45,7 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash import _backend_is_tpu, _x64_off
-from .paged_attention import _unpack4_vmem, gather_pages
+from .paged_attention import _unpack4_vmem, gather_pages, live_pages
 
 _NEG_INF = -1e30
 
@@ -95,19 +104,20 @@ def supported(n_heads: int, page_size: int, head_dim: int, chunk: int,
     return block_heads(n_heads, page_size, head_dim, chunk, nkv) is not None
 
 
-def _group_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
+def _group_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                       page_size, scale, window=None):
-    """The page step of the grid over (KV heads, pages): the block is one
-    KV head's group of query heads, ``q_ref`` (C, g, D) against that
+    """The page step of the grid over (KV heads, live pages): the block is
+    one KV head's group of query heads, ``q_ref`` (C, g, D) against that
     head's ``k``/``v`` (1, ps, D).  Rows and heads merge into one matmul
     dimension of C * g (row-major, so row r is chunk row ``r // g``): two
-    plain 2-D products a page, no regrouping of the scores.  Same mask,
-    same recurrence and the same division at the end as
+    plain 2-D products a page, no regrouping of the scores.  Same walk,
+    same mask, same recurrence and the same division at the end as
     :func:`_chunk_recurrence`; m and l are kept lane-broadcast like the
     decode kernel's."""
-    p = pl.program_id(1)
+    start, lo, hi = walk_ref[0], walk_ref[1], walk_ref[2]
+    p = lo + pl.program_id(1)              # this step's logical page
 
-    @pl.when(p == 0)
+    @pl.when(p == lo)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -119,7 +129,7 @@ def _group_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                             preferred_element_type=jnp.float32) * scale
     pos = p * jnp.int32(page_size) + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)
-    qpos = start_ref[0] + jax.lax.broadcasted_iota(
+    qpos = start + jax.lax.broadcasted_iota(
         jnp.int32, (c * g, 1), 0) // jnp.int32(g)
     keep = pos <= qpos
     if window is not None:
@@ -136,30 +146,33 @@ def _group_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(p == pl.num_programs(1) - 1)
+    @pl.when(p == hi - 1)
     def _finish():
         out = acc_ref[...] / l_ref[:, :1]
         o_ref[...] = out.reshape(c, g, v.shape[-1]).astype(o_ref.dtype)
 
 
-def _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
+def _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                       page_size, scale, chunk, window=None, n_kv=None,
                       page_axis=0):
     """The ONE online-softmax page step shared by the float/int8/int4
-    entries (only how k/v materialize in VMEM differs): init scratch on
-    the first page, score + causal-mask this page against every chunk
-    row (GQA query heads regrouped over the shared KV head, never
-    repeating K/V; sliding window drops keys more than ``window`` behind
-    each row), fold into the m/l/acc flash recurrence, divide out on the
-    last page.  ``page_axis`` is the grid axis that runs over pages: 0,
-    or 1 under a leading axis over KV heads (:func:`_group_recurrence`
-    then does the step)."""
+    entries (only how k/v materialize in VMEM differs).  ``walk_ref``
+    holds ``(start, lo, hi)``: grid step ``p`` of the page axis is logical
+    page ``lo + p`` of the chunk's live range.  Init scratch on the first
+    live page, score + causal-mask this page against every chunk row (GQA
+    query heads regrouped over the shared KV head, never repeating K/V;
+    sliding window drops keys more than ``window`` behind each row), fold
+    into the m/l/acc flash recurrence, divide out on the last live page.
+    ``page_axis`` is the grid axis that runs over pages: 0, or 1 under a
+    leading axis over KV heads (:func:`_group_recurrence` then does the
+    step)."""
     if page_axis:
-        return _group_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref,
+        return _group_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref,
                                  acc_ref, page_size, scale, window=window)
-    p = pl.program_id(0)
+    start, lo, hi = walk_ref[0], walk_ref[1], walk_ref[2]
+    p = lo + pl.program_id(0)              # this step's logical page
 
-    @pl.when(p == 0)
+    @pl.when(p == lo)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -180,7 +193,7 @@ def _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     s = s * scale
     pos = p * jnp.int32(page_size) + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, page_size), 2)
-    qpos = start_ref[0] + jax.lax.broadcasted_iota(
+    qpos = start + jax.lax.broadcasted_iota(
         jnp.int32, (1, chunk, 1), 1)
     keep = pos <= qpos
     if window is not None:
@@ -203,43 +216,43 @@ def _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     acc_ref[...] = acc_ref[...] * alpha[:, :, None] + upd
     m_ref[...] = m_new
 
-    @pl.when(p == pl.num_programs(0) - 1)
+    @pl.when(p == hi - 1)
     def _finish():
         out = acc_ref[...] / l_ref[...][:, :, None]        # (H, C, D)
         o_ref[...] = jnp.einsum("hcd->chd", out).astype(o_ref.dtype)
 
 
-def _prefill_kernel(bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
+def _prefill_kernel(bt_ref, walk_ref, q_ref, k_ref, v_ref, o_ref,
                     m_ref, l_ref, acc_ref, *, page_size, scale, chunk,
                     window=None, n_kv=None, page_axis=0):
     k = k_ref[0].astype(jnp.float32)                       # (Hkv, ps, D)
     v = v_ref[0].astype(jnp.float32)
-    _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
+    _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                       page_size, scale, chunk, window=window, n_kv=n_kv,
                       page_axis=page_axis)
 
 
 # the int8 entry has its own arity (scale refs) but the same recurrence
-def _prefill_kernel_int8(bt_ref, start_ref, q_ref, k_ref, ks_ref, v_ref,
+def _prefill_kernel_int8(bt_ref, walk_ref, q_ref, k_ref, ks_ref, v_ref,
                          vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
                          page_size, scale, chunk, window=None, n_kv=None,
                          page_axis=0):
     k = k_ref[0].astype(jnp.float32) * ks_ref[0]           # (Hkv, ps, D)
     v = v_ref[0].astype(jnp.float32) * vs_ref[0]
-    _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
+    _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                       page_size, scale, chunk, window=window, n_kv=n_kv,
                       page_axis=page_axis)
 
 
 # int4 pages arrive nibble-packed (D//2 bytes per position); the unpack
 # happens in VMEM right after the page DMA — same decision as decode
-def _prefill_kernel_int4(bt_ref, start_ref, q_ref, k_ref, ks_ref, v_ref,
+def _prefill_kernel_int4(bt_ref, walk_ref, q_ref, k_ref, ks_ref, v_ref,
                          vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
                          page_size, scale, chunk, window=None, n_kv=None,
                          page_axis=0):
     k = _unpack4_vmem(k_ref[0]) * ks_ref[0]                # (Hkv, ps, D)
     v = _unpack4_vmem(v_ref[0]) * vs_ref[0]
-    _chunk_recurrence(start_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
+    _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
                       page_size, scale, chunk, window=window, n_kv=n_kv,
                       page_axis=page_axis)
 
@@ -254,8 +267,9 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
     Hkv may divide H (GQA) — or int8 with ``k_scales``/``v_scales``
     (P, Hkv, page_size, 1) fp32, or nibble-packed int4 (last dim D//2)
     with the same scale layout; ``block_table`` (max_pages,) int32 page
-    ids for THIS slot (padding entries must reference a valid page — the
-    pool's null page 0); ``start`` scalar int32 positions already valid
+    ids for THIS slot — every entry must name a valid page (padding is the
+    pool's null page 0), but only those of ``live_pages(start + 1, rows=C)``
+    are read; ``start`` scalar int32 positions already valid
     before the chunk; ``window`` optional sliding-window width — row i
     sees positions ``(start + i - window, start + i]``.  The chunk's own
     K/V must ALREADY be written into the pages.  Returns (C, H, D) in
@@ -274,23 +288,33 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
     int4 = quant and d_store != d
     win = None if window is None else int(window)
 
+    # the walk: the logical pages some row of the chunk sees, [lo, hi) of
+    # the table's entries, is the page axis of the grid (a traced bound);
+    # step p reads entry lo + p.  Clamped: the pipeline reads the NEXT
+    # step's ids at the last step too
+    start = jnp.asarray(start, jnp.int32).reshape(())
+    lo, hi = live_pages(start + 1, ps, win, rows=c, max_pages=max_pages)
+
+    def entry(p, bt, walk):
+        return bt[jnp.minimum(walk[1] + p, max_pages - 1)]
+
     hb = block_heads(h, ps, d, c, hkv) or h       # query heads in a block
     if hb == h:
-        # the whole chunk's heads at once; the grid runs over pages
-        nkv, grid, page_axis = (None if hkv == h else hkv), (max_pages,), 0
+        # the whole chunk's heads at once; the grid runs over live pages
+        nkv, grid, page_axis = (None if hkv == h else hkv), (hi - lo,), 0
         kvb = hkv
 
         def at(page):
-            return lambda p, bt, st: ((bt[p], 0, 0, 0) if page
-                                      else (0, 0, 0))
+            return lambda p, bt, walk: ((entry(p, bt, walk), 0, 0, 0)
+                                        if page else (0, 0, 0))
     else:
-        # one KV head's group at a time: grid (KV heads, pages)
-        nkv, grid, page_axis = 1, (hkv, max_pages), 1
+        # one KV head's group at a time: grid (KV heads, live pages)
+        nkv, grid, page_axis = 1, (hkv, hi - lo), 1
         kvb = 1
 
         def at(page):
-            return lambda n, p, bt, st: ((bt[p], n, 0, 0) if page
-                                         else (0, n, 0))
+            return lambda n, p, bt, walk: ((entry(p, bt, walk), n, 0, 0)
+                                           if page else (0, n, 0))
 
     q_spec = pl.BlockSpec((c, hb, d), at(False))
     pg_spec = pl.BlockSpec((1, kvb, ps, d_store), at(True))
@@ -325,8 +349,7 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
             out_shape=jax.ShapeDtypeStruct((c, h, d), q.dtype),
             interpret=interpret,
             name="paged_prefill",
-        )(block_table.astype(jnp.int32),
-          jnp.asarray(start, jnp.int32).reshape(1), *args)
+        )(block_table.astype(jnp.int32), jnp.stack([start, lo, hi]), *args)
 
 
 def paged_prefill_ref(q, k_pages, v_pages, block_table, start, *,

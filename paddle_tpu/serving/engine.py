@@ -572,6 +572,8 @@ class ServingEngine:
                       # and layers (the kernels' own live range), beside
                       # what every table entry of every lane would be
                       "decode_pages_walked": 0, "decode_pages_in_table": 0,
+                      # ... and the chunk kernel, summed over layers
+                      "prefill_pages_walked": 0, "prefill_pages_in_table": 0,
                       # disaggregation traffic (r15)
                       "handoffs_out": 0, "handoffs_in": 0,
                       "handoff_bytes": 0, "handoff_faults": 0,
@@ -1564,7 +1566,7 @@ class ServingEngine:
                     self._m["chunk_s"].observe(sp.dur)
                 if self.tracer is not None:
                     self.tracer.end(self._pid_req, req.rid)
-                self.stats["prefill_calls"] += 1
+                self._note_prefill_dispatch(st.prefilled, c_pad)
                 self._chunks_this_step += 1
                 st.prefilled += n
                 budget -= n
@@ -2061,6 +2063,18 @@ class ServingEngine:
             st["moe_experts_active"] += int((per_layer > 0).sum())
             st["moe_layer_passes"] += len(per_layer)
         self._moe_pending.clear()
+
+    def _note_prefill_dispatch(self, start: int, width: int) -> None:
+        """Counters of one chunk dispatch: ``width`` rows (the bucket's, as
+        the kernel sees them) after ``start`` written positions, each layer
+        walking its own window's live pages of the slot's one table row."""
+        self.stats["prefill_calls"] += 1
+        for window, n_layers in self._window_layers.items():
+            lo, hi = pa.live_pages(start + 1, self.page_size, window, width,
+                                   self.max_pages)
+            self.stats["prefill_pages_walked"] += n_layers * int(hi - lo)
+        self.stats["prefill_pages_in_table"] += (
+            self.max_pages * len(self.layers))
 
     def _note_decode_dispatch(self, run: List[int],
                               remaining: Optional[np.ndarray] = None) -> None:

@@ -285,3 +285,36 @@ def test_pages_walked_counter_is_the_kernels_own_range(model):
         n + 1, 8, None, 1, eng.max_pages)[::-1]).sum()) for n in handed)
     assert full_only < want < 4 * full_only
     assert want < st["decode_pages_in_table"] // 2
+
+
+def test_prefill_pages_walked_counter_is_the_chunk_kernels_own_range(model):
+    """``stats["prefill_pages_walked"]`` with two page groups: per chunk
+    dispatch, the size of ``live_pages(start + 1, rows=bucket width)`` for
+    every layer under its own window (three ring layers and a full one over
+    one table width); ``prefill_pages_in_table`` is the whole row of each."""
+    from paddle_tpu.kernels import paged_attention as pa
+
+    eng = ServingEngine(model, max_slots=3, page_size=8, max_seq_len=160,
+                        chunk_tokens=16)
+    handed, run = [], eng._prefill_fn    # (p, bufs, toks, start, n, ...)
+    eng._prefill_fn = lambda *a: (handed.append(
+        (int(a[3]), a[2].shape[0])), run(*a))[1]
+    for p in _prompts(6, (70, 9, 33, 100)):
+        eng.add_request(p, 3)
+    eng.run()
+    windows = [s.window for s in model.layer_specs()]
+    assert windows == [16, 16, 16, None] and len(handed) > 12
+
+    def walked(w):
+        return sum(int(np.subtract(*pa.live_pages(
+            start + 1, 8, w, width, eng.max_pages)[::-1]))
+            for start, width in handed)
+
+    st = eng.stats
+    assert st["prefill_calls"] == len(handed)
+    assert st["prefill_pages_walked"] == sum(map(walked, windows))
+    assert st["prefill_pages_in_table"] == len(handed) * eng.max_pages * 4
+    # chunks deep in a prompt: a window layer walks fewer pages than the
+    # full one, and all of them fewer than the tables hold
+    assert walked(16) < walked(None)
+    assert st["prefill_pages_walked"] < st["prefill_pages_in_table"] // 2

@@ -274,6 +274,96 @@ def test_paged_prefill_kernel_matrix(group, window, bits):
                                rtol=1e-5, atol=1e-5)
 
 
+def _walked_ids(monkeypatch):
+    """Spy on ``paged_prefill``'s ``pallas_call``: per call, the pool page
+    the K index map names at every step of the grid it was built with (a
+    concrete bound: the calls here are eager)."""
+    calls, real = [], pp.pl.pallas_call
+
+    def spy(kernel, *, grid_spec, **kw):
+        run = real(kernel, grid_spec=grid_spec, **kw)
+
+        def call(bt, walk, *args):
+            at = grid_spec.in_specs[1].index_map
+            calls.append([
+                int(at(*step, np.asarray(bt), np.asarray(walk))[0])
+                for step in np.ndindex(*map(int, grid_spec.grid))])
+            return run(bt, walk, *args)
+
+        return call
+
+    monkeypatch.setattr(pp.pl, "pallas_call", spy)
+    return calls
+
+
+@pytest.mark.parametrize("bits", [None, 8, 4], ids=["fp", "int8", "int4"])
+@pytest.mark.parametrize("group", [1, 8], ids=["heads", "kvgroup"])
+@pytest.mark.parametrize("window", [None, 12, 64],
+                         ids=["full", "win12", "win64"])
+@pytest.mark.parametrize("start", [0, 13, 16, 40],
+                         ids=["first", "midpage", "boundary", "last"])
+def test_paged_prefill_kernel_walks_live_pages_only(monkeypatch, start,
+                                                    window, group, bits):
+    """The grid's page axis is the chunk's live range and nothing else,
+    for both block shapes (all heads; one KV head's group) and every pool
+    type.  Every dead table entry names a page of its own that holds large
+    finite values: a walked one shows in the COUNT of pages the index map
+    handed the body (it would round away in the output: its scores are
+    masked), and the output equals the dense reference's."""
+    rng = np.random.RandomState(59 * group + 7 * start + (bits or 1))
+    C, HKV, D, PS, MAXP = 8, 2, 16, 8, 6
+    H, P = HKV * group, 1 + 2 * MAXP
+    assert pp.block_heads(H, PS, D, C, HKV) == (H if group == 1 else group)
+    q = jnp.asarray(rng.randn(C, H, D).astype("float32"))
+    kq, vq, ks, vs = _mk_pages(rng, P, HKV, PS, D, bits)
+    lo, hi = (int(x) for x in pa.live_pages(start + 1, PS, window, C, MAXP))
+    live = 1 + np.arange(MAXP)
+    dead = 1 + MAXP + np.arange(MAXP)
+    col = np.arange(MAXP)
+    bt = np.where((col >= lo) & (col < hi), live, dead).astype("int32")
+    big = lambda a: a.at[dead].set(1e4)
+    if bits is None:
+        kq, vq = big(kq), big(vq)
+    else:
+        ks, vs = big(ks), big(vs)
+    calls = _walked_ids(monkeypatch)
+    out = pp.paged_prefill(q, kq, vq, jnp.asarray(bt), start, k_scales=ks,
+                           v_scales=vs, interpret=True, window=window)
+    ref = pp.paged_prefill_ref(q, kq, vq, jnp.asarray(bt), start,
+                               k_scales=ks, v_scales=vs, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    # each live page once per step of the leading axis, in order; no other
+    (ids,) = calls
+    assert ids == list(live[lo:hi]) * (1 if group == 1 else HKV)
+
+
+@pytest.mark.parametrize("attn_window", [None, 24], ids=["full", "win24"])
+def test_engine_counts_the_pages_its_chunk_kernel_walks(attn_window):
+    """``prefill_pages_walked`` is the chunk kernel's own live range summed
+    over the layers of every ``_prefill_fn`` dispatch, recomputed here from
+    the ``start`` and the bucket width the program was handed."""
+    m = _model(seed=1, attn_window=attn_window, num_layers=2)
+    eng = ServingEngine(m, max_slots=3, page_size=8, chunk_tokens=16,
+                        use_paged_kernel=False)
+    handed, run = [], eng._prefill_fn    # (p, bufs, toks, start, n, ...)
+    eng._prefill_fn = lambda *a: (handed.append(
+        (int(a[3]), a[2].shape[0])), run(*a))[1]
+    rng = np.random.RandomState(3)
+    for p in _prompts(rng, (30, 5, 17, 41)):
+        eng.add_request(p, 4)
+    eng.run()
+    want = sum(2 * int(np.subtract(*pa.live_pages(
+        start + 1, 8, attn_window, width, eng.max_pages)[::-1]))
+        for start, width in handed)
+    assert len(handed) == eng.stats["prefill_calls"] > 6
+    assert {w for _, w in handed} == {8, 16} and max(handed)[0] >= 32
+    assert eng.stats["prefill_pages_walked"] == want
+    assert eng.stats["prefill_pages_in_table"] == \
+        len(handed) * eng.max_pages * 2
+    assert 0 < want < eng.stats["prefill_pages_in_table"]
+
+
 def test_windowed_ref_ignores_out_of_window_positions():
     """The window bound is as hard as the length bound: rewriting page
     positions at or below ``lengths - window`` (what the engine's ring

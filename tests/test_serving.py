@@ -407,6 +407,31 @@ def test_paged_prefill_kernel_matches_ref_float():
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("heads, kv_heads", [(2, 2), (16, 2)],
+                         ids=["heads", "kvgroup"])
+def test_paged_prefill_costs_the_context_not_the_table(heads, kv_heads):
+    """A chunk's answer does not depend on how wide its table is: the walk
+    stops at the chunk's last page, so a 4-entry and a 40-entry table with
+    the same live entries give the same bits (the rest may name anything)."""
+    rng = np.random.RandomState(41)
+    C, D, PS, P = 8, 16, 8, 12
+    q = jnp.asarray(rng.randn(C, heads, D).astype("float32"))
+    kp = jnp.asarray(rng.randn(P, kv_heads, PS, D).astype("float32"))
+    vp = jnp.asarray(rng.randn(P, kv_heads, PS, D).astype("float32"))
+    live = rng.randint(1, P - 1, (4,)).astype("int32")
+    kp, vp = kp.at[P - 1].set(1e4), vp.at[P - 1].set(1e4)
+    wide = np.concatenate([live, np.full((36,), P - 1, "int32")])
+    for start in (0, 13, 24):
+        tight = pp.paged_prefill(q, kp, vp, jnp.asarray(live), start,
+                                 interpret=True)
+        out = pp.paged_prefill(q, kp, vp, jnp.asarray(wide), start,
+                               interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(tight))
+        ref = pp.paged_prefill_ref(q, kp, vp, jnp.asarray(wide), start)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("window", [None, 40])
 @pytest.mark.parametrize("kv_bits", [None, 8])
 def test_paged_prefill_kernel_tiled_by_kv_head_matches_ref(window, kv_bits):
