@@ -12,6 +12,11 @@ least 90% of the scored requests met both limits.  The job is the one the
 cell's file names, as in ``run.py``.  The lead-in is the cell's ``lead_s``
 unless ``--lead`` gives another: three request lifetimes or more
 (``lifetime_p95_s`` of each line), or every rate reads as a ramp.
+
+``--requests`` follows each rate's line with one line per request that was
+scored or whose first token fell inside the window (what ``served_rate``
+counts), and with the engine's own rate over the window: to run down a run
+that reads far from its neighbours.
 """
 
 from __future__ import annotations
@@ -30,26 +35,67 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, default=1)
-    ap.add_argument("--seconds", type=float, default=40.0)
-    ap.add_argument("--rates", required=True)
-    ap.add_argument("--lead", type=float, help="instead of the cell's lead_s")
-    args = ap.parse_args()
+def request_lines(server, obs: dict) -> list[dict]:
+    """One record per request that was due inside the window (``scored``) or
+    whose first token fell inside it: times on the window's clock, None for
+    what never came.  ``in_served_interval``: ``served_rate`` counts its
+    tokens if it ``completed`` (the window's earliest first token only opens
+    the interval)."""
+    seconds, origin = obs["seconds"], obs["origin"]
+    rows = []
+    for rid, (req, _) in obs["sent"].items():
+        times = server.token_times.get(rid)
+        first = times[0] - origin if times else None
+        inside = first is not None and 0 <= first < seconds
+        scored = 0 <= req.due < seconds
+        if not (inside or scored):
+            continue
+        fin = obs["finished"].get(rid)
+        rows.append({"request": rid, "due": req.due,
+                     "prompt": len(req.prompt), "new": req.max_new,
+                     "scored": scored, "first_token": first,
+                     "completed": fin[1] if fin is not None and fin[0].ok
+                     else None, "in_served_interval": inside})
+    opener = min((r for r in rows if r["in_served_interval"]),
+                 key=lambda r: r["first_token"], default=None)
+    if opener is not None:
+        opener["in_served_interval"] = False
+    return rows
 
+
+def engine_rate(server, obs: dict, stats: dict) -> dict:
+    """What the engine itself counted over the window, per second: tokens
+    sampled, chunk dispatches and, where the model counts its expert rows
+    (every row of every dispatch, prompt or decode, passes every expert
+    layer once), the rows it processed.  No request is cut at an edge.
+    ``longest_step_ms``: the longest time from the end of one step to the
+    end of the next inside the window, so that a stall shows as one."""
+    seconds = obs["seconds"]
+    ends = [t for t, _ in obs["depth"] if 0 <= t < seconds]
+    out = {"generated_tokens_per_s": stats["tokens_generated"] / seconds,
+           "prefill_calls_per_s": stats["prefill_calls"] / seconds,
+           "longest_step_ms": 1e3 * max(
+               (b - a for a, b in zip(ends, ends[1:])), default=0.0)}
+    sz = getattr(server, "sz", None)
+    if sz and stats.get("moe_assignments"):
+        out["rows_per_s"] = (stats["moe_assignments"]
+                             / (sz["top_k"] * sz["layers"]) / seconds)
+    return out
+
+
+def sweep(root: str, args, emit) -> None:
+    """``args`` as ``main`` parses them; the cell's files under ``root``;
+    every line goes to ``emit`` as a dict."""
     import numpy as np
 
     from benchmarks import run, sut, weights
 
-    cell = run.load_json(os.path.join(HERE, "workloads", f"{args.workload}.json"))
+    cell = run.load_json(os.path.join(root, "workloads", f"{args.workload}.json"))
     if args.lead is not None:
         cell["lead_s"] = args.lead
     job = importlib.import_module(f"benchmarks.jobs.{cell['job']}")
-    run.require_tpu(cell["chips"])
-    config = run.load_json(os.path.join(HERE, "configs", f"{cell['config']}.json"))
-    traffic = run.load_json(os.path.join(HERE, "traffic",
+    config = run.load_json(os.path.join(root, "configs", f"{cell['config']}.json"))
+    traffic = run.load_json(os.path.join(root, "traffic",
                                      f"{cell['traffic']}.json"))
     ctx = run.Context(cell=cell, config=config, traffic=traffic,
                       sizes=weights.sizes(config), seed=args.seed,
@@ -60,8 +106,7 @@ def main() -> int:
     server = job.Server(ctx)
     checks = job.reference_check(server, ctx)
     server.weights = None
-    print(json.dumps({"checks": checks,
-                      "paths": server.engine.attention_paths()}), flush=True)
+    emit({"checks": checks, "paths": server.engine.attention_paths()})
     for rate in (float(r) for r in args.rates.split(",")):
         while server.engine.has_work:       # what the last rate left behind
             server.engine.step()
@@ -78,7 +123,7 @@ def main() -> int:
         # ended counts as long as it was watched
         lives = [(obs["drained_at"] if fin is None else fin[1]) - req.due
                  for req, fin in scored if req.due < args.seconds / 2]
-        print(json.dumps({
+        emit({
             "rate_rps": rate, "lead_s": cell["lead_s"],
             "scored": out["attempted"],
             "failed": out["failed"], "values": out["values"],
@@ -98,7 +143,31 @@ def main() -> int:
                 "window_compiles")},
             "preemptions": r["stats"]["preemptions"],
             "decode_batch_mean": r["stats"]["tokens_generated"]
-            / max(r["stats"]["decode_calls"], 1)}), flush=True)
+            / max(r["stats"]["decode_calls"], 1)})
+        if args.requests:
+            for row in request_lines(server, obs):
+                emit(row)
+            emit({"rate_rps": rate,
+                  "engine": engine_rate(server, obs, r["stats"])})
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--seconds", type=float, default=40.0)
+    ap.add_argument("--rates", required=True)
+    ap.add_argument("--lead", type=float, help="instead of the cell's lead_s")
+    ap.add_argument("--requests", action="store_true",
+                    help="a line per request and the engine's own rate")
+    args = ap.parse_args()
+
+    from benchmarks import run
+
+    chips = run.load_json(os.path.join(
+        HERE, "workloads", f"{args.workload}.json"))["chips"]
+    run.require_tpu(chips)
+    sweep(HERE, args, lambda line: print(json.dumps(line), flush=True))
     return 0
 
 

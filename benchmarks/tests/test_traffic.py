@@ -90,3 +90,27 @@ def test_arrival_seed_fixes_the_instants_and_leaves_the_order_to_the_seed():
     assert not np.array_equal(a[0].prompt[:8], b[0].prompt[:8])
     assert sum(r.due < 0 for r in a) == 5 and sum(r.due >= 0 for r in a) == 30
     assert all(x.due <= y.due for x, y in zip(a, a[1:]))
+
+
+def test_length_seed_fixes_the_order_of_the_lengths_and_nothing_else():
+    params = dict(CHAT, length_seed=0)
+    a, b = _sched(3, params, rate=1.0), _sched(4, params, rate=1.0)
+    shape = lambda s: [(len(r.prompt), r.max_new) for r in s]   # noqa: E731
+    assert shape(a) == shape(b)
+    assert shape(a) != shape(_sched(3, dict(CHAT, length_seed=1), rate=1.0))
+    free = _sched(3, rate=1.0)       # the same lengths, paired by the seed
+    for k in (0, 1):
+        assert sorted(x[k] for x in shape(a)) == sorted(
+            x[k] for x in shape(free))
+    assert [r.due for r in a] != [r.due for r in b]
+    assert not np.array_equal(a[0].prompt[:8], b[0].prompt[:8])
+    # with the instants fixed too, every seed offers one schedule
+    both = dict(params, arrival_seed=0)
+    c, d = _sched(3, both, rate=1.0), _sched(4, both, rate=1.0)
+    assert [(r.due, len(r.prompt), r.max_new) for r in c] == [
+        (r.due, len(r.prompt), r.max_new) for r in d]
+    assert [r.due for r in c] == [
+        r.due for r in _sched(3, dict(CHAT, arrival_seed=0), rate=1.0)]
+    assert not np.array_equal(c[0].prompt[:8], d[0].prompt[:8])
+    # a mix without the key draws as it did before the key existed
+    assert shape(_sched(3)) == shape(_sched(3, dict(CHAT, length_seed=None)))
