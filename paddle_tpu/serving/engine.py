@@ -16,8 +16,11 @@ programs called from a host loop:
     prefill (kernels/paged_prefill.py).  Chunk widths pad to power-of-two
     buckets so the program retraces per bucket, not per length.  A long
     prompt no longer stalls every in-flight decode for a monolithic
-    prefill: each step spends at most the scheduler's chunk budget on
-    prefill, co-scheduled with decode.
+    prefill: each step spends at most the scheduler's token budget on
+    prefill (``token_budget`` less the decoding lanes), co-scheduled with
+    decode, as several dispatches of this one program: as many chunks as
+    requests wait on prefill per decoding lane, one when decodes
+    outnumber them (``FCFSScheduler.prefill_budget``).
   * ``decode``: ONE token for EVERY started slot — per-slot paged KV
     write at each slot's own position, paged attention through the block
     table (kernels/paged_attention.py), sampling.  Slot count is static;
@@ -228,8 +231,9 @@ class ServingEngine:
     ``page_size`` the pool granularity; ``num_pages`` the pool size
     (default: enough for every slot at ``max_seq_len``, +1 null page);
     ``token_budget`` the scheduler's per-step token budget (decode tokens
-    + prefill chunk); ``chunk_tokens`` the chunk-prefill program width —
-    prompts longer than a step's chunk budget prefill across steps,
+    + prefill chunks; ``token_budget=chunk_tokens`` holds a step to one
+    chunk); ``chunk_tokens`` the chunk-prefill program width —
+    prompts longer than a step's prefill budget prefill across steps,
     co-scheduled with decode; ``prefix_cache`` reuses KV pages across
     requests sharing a page-aligned token prefix.  Sampling knobs mirror
     ``build_generate_fn``; ``int8`` serves W8A8 projections + int8 KV
@@ -520,6 +524,7 @@ class ServingEngine:
         # host mirrors of the decode step's device operands
         self._tokens_this_step = 0
         self._chunks_this_step = 0
+        self._budget_chunks = 1
         self._phase_s: Dict[str, tuple] = {}
         self._slots: List[Optional[_Slot]] = [None] * max_slots
         self._tok = np.zeros((max_slots,), np.int32)
@@ -568,6 +573,10 @@ class ServingEngine:
                       # context lengths the decode dispatches attended
                       **dict.fromkeys(_DECODE_AFTER, 0),
                       "decode_attended_tokens": 0,
+                      # whole chunks the prefill budget held, summed over
+                      # the steps that went on to decode: over decode_calls
+                      # the allowance, as prefill_calls is what was spent
+                      "prefill_budget_chunks": 0,
                       # what the decode kernels walked, summed over lanes
                       # and layers (the kernels' own live range), beside
                       # what every table entry of every lane would be
@@ -1521,26 +1530,33 @@ class ServingEngine:
                                "preempted": req.n_preempted})
 
     def _prefill_chunks(self, finished: List[FinishedRequest]) -> None:
-        """Spend the step's chunk budget FCFS over partially-prefilled
-        slots: at most ``prefill_budget`` prompt tokens total, each call
-        one chunk of one slot's work prompt (prompt + any
-        preemption-survived tokens).  A slot whose prompt completes
-        samples its next token and joins this step's decode batch."""
+        """Spend the step's prefill budget FCFS over partially-prefilled
+        slots: at most ``prefill_budget`` prompt tokens total (several
+        chunks while more requests wait on prefill than decode), each call
+        at most ``chunk_tokens`` of one slot's work prompt (prompt + any
+        preemption-survived tokens), dispatched one after another without
+        a sync between them.  A slot whose prompt completes samples its
+        next token and joins this step's decode batch."""
         n_decoding = sum(1 for s in self._slots
                          if s is not None and s.started)
-        budget = self.scheduler.prefill_budget(
-            n_decoding, self.chunk_tokens, decode_cost=1 + self.spec_k)
         partial = sorted(
             (i for i, s in enumerate(self._slots)
              if s is not None and not s.started),
             key=lambda i: self._slots[i].seq)
+        budget = self.scheduler.prefill_budget(
+            n_decoding, self.chunk_tokens, decode_cost=1 + self.spec_k,
+            n_prefilling=len(partial) + self.scheduler.n_waiting)
+        self._budget_chunks = max(1, budget // self.chunk_tokens)
         for idx in partial:
             st = self._slots[idx]
             req = st.request
             work = req.work_prompt()
             while budget > 0 and not st.started:
-                n = min(st.base_len - st.prefilled, budget,
-                        self.chunk_tokens)
+                n = self.scheduler.chunk_rows(
+                    st.base_len - st.prefilled, budget,
+                    self._tokens_this_step, self.chunk_tokens)
+                if n == 0:
+                    return
                 c_pad = min(_next_pow2(max(n, 8)),
                             max(self.chunk_tokens, n))
                 toks = np.zeros((c_pad,), np.int32)
@@ -2082,6 +2098,7 @@ class ServingEngine:
         ``remaining`` is a decode program's argument (a verify has none)."""
         self.stats["decode_calls"] += 1
         self.stats[_DECODE_AFTER[min(self._chunks_this_step, 2)]] += 1
+        self.stats["prefill_budget_chunks"] += self._budget_chunks
         self.stats["decode_attended_tokens"] += int(self._len[run].sum())
         # the attention kernels see EVERY lane, each layer under its own
         # window: a verify once with spec_k + 1 rows, a decode once per

@@ -10,8 +10,10 @@ instead of run-to-completion batches.
 Budget semantics (Sarathi-Serve's chunked prefill): admission costs
 nothing up front — an admitted request's prompt is prefilled in CHUNKS
 across subsequent steps, co-scheduled with decode.  Each step the engine
-spends :meth:`prefill_budget` prompt tokens, i.e. ``token_budget`` minus
-one token per active decode, so a burst of long prompts can no longer
+spends :meth:`prefill_budget` prompt tokens: at most ``token_budget``
+minus one token per active decode, and under that cap as many chunks as
+there are requests waiting on prefill per decoding lane (one chunk when
+decodes outnumber them), so a burst of long prompts can no longer
 stall every in-flight decode behind a monolithic prefill (the pre-r09
 failure mode that needed whole prompts force-admitted over budget).
 Admission is gated only by free slots and pages.
@@ -231,9 +233,9 @@ class FCFSScheduler:
                  tenants=None):
         self.n_slots = n_slots
         self.pool = pool
-        # default budget: every slot decoding plus one flagship-sized
-        # prefill chunk per step keeps step latency bounded without
-        # starving admission
+        # default budget: every slot decoding plus 512 prompt tokens (four
+        # chunks of the default program width) bounds a step's latency
+        # without starving admission
         self.token_budget = token_budget or (n_slots + 512)
         self.policy: SchedulerPolicy = make_policy(policy, tenants)
         self._free_slots: List[int] = list(range(n_slots - 1, -1, -1))
@@ -309,19 +311,45 @@ class FCFSScheduler:
     # -- per-step decisions ----------------------------------------------
 
     def prefill_budget(self, n_decoding: int, chunk_tokens: int,
-                       decode_cost: int = 1) -> int:
-        """Sarathi chunk budget for one step: the token budget left after
-        paying ``decode_cost`` tokens per active decode, capped at the
-        engine's chunk program width and floored at 1 so prefill always
-        progresses even when decodes alone exceed the budget.
+                       decode_cost: int = 1, n_prefilling: int = 0) -> int:
+        """Prompt tokens one step may spend (Sarathi's budget):
+        ``min(k * chunk_tokens, token_budget - n_decoding * decode_cost)``,
+        floored at 1 so prefill always progresses even when decodes alone
+        exceed the budget.  ``chunk_tokens`` is only the width of one
+        dispatch; ``token_budget`` is the cap.  ``k``, the chunks worth
+        holding the step's decode back for, is read from two counts and no
+        clock, so a run's schedule is a function of its arrivals:
+        ``n_decoding`` lanes each wait one chunk longer for their next
+        token, ``n_prefilling`` requests (in a slot or queued, first token
+        not yet out) each get theirs a decode and a host turn sooner, so
+        ``k = ceil(n_prefilling / n_decoding)``, at least 1: an extra chunk
+        needs one more waiting request per decoding lane to gain from it.
+        With nothing decoding ``k`` is 1: such a step has no sync to
+        amortise, the next one dispatches the next chunk.
         ``decode_cost`` is 1 for plain decode; a speculative engine
         reserves ``spec_k + 1`` per decoding slot — the verify dispatch
         scores that many positions whether or not they are accepted, so
         the step's compute reservation must not be distorted by
         speculation (WFQ SERVICE charging, by contrast, bills accepted
         tokens only, through ``Request.uncharged_tokens``)."""
-        return max(1, min(chunk_tokens,
+        k = max(1, -(-n_prefilling // n_decoding)) if n_decoding else 1
+        return max(1, min(k * chunk_tokens,
                           self.token_budget - n_decoding * decode_cost))
+
+    def chunk_rows(self, remaining: int, budget: int, spent: int,
+                   chunk_tokens: int) -> int:
+        """Rows of the next chunk dispatch of a prompt with ``remaining``
+        tokens to go, ``budget`` (> 0) left of the step's allowance and
+        ``spent`` of it gone; 0 ends the step's prefill.  The budget is
+        spent in whole dispatches, a full chunk or a prompt's end: a tail
+        of budget padded to its bucket would stream every weight for a
+        few rows.  Only within the step's first ``chunk_tokens`` does the
+        budget cut a dispatch short, so a budget of one chunk or less is
+        spent to the token, as it always was."""
+        n = min(remaining, chunk_tokens)
+        if n <= budget:
+            return n
+        return budget if spent < chunk_tokens else 0
 
     def schedule_step(self) -> List[Admission]:
         """Admit from the policy's queue into free slots until slots or

@@ -204,6 +204,49 @@ def test_the_ring_turns_in_prefill_and_decode_and_both_groups_drain(model):
     assert not ring.table.any()
 
 
+def test_a_backlog_spends_several_chunks_a_step_and_turns_the_ring_each(
+        model):
+    """Two long prompts wait beside one decoding request: the step's
+    budget is two chunks of the 16-row program, the window ring turns once
+    per chunk (never by more than the chunk it was sized for), and the
+    tokens are those of an engine held to one chunk a step."""
+    first, *backlog = _prompts(36, (9, 112, 96))
+
+    def serve(**kw):
+        eng = ServingEngine(model, max_slots=3, page_size=8, max_seq_len=160,
+                            chunk_tokens=16, **kw)
+        turns, advance = [], eng.ring.advance
+
+        def counted(slot, start, end):
+            if not eng._slots[slot].started:
+                turns.append((slot, start, end))
+            advance(slot, start, end)
+
+        eng.ring.advance = counted
+        rids = [eng.add_request(first, 20)]
+        eng.step()
+        rids += [eng.add_request(p, 8) for p in backlog]
+        before = eng.stats["prefill_calls"]
+        eng.step()
+        in_a_step = eng.stats["prefill_calls"] - before
+        out = eng.run()
+        return eng, [out[r].tokens for r in rids], in_a_step, turns
+
+    one, want, n_one, _ = serve(token_budget=16)
+    eng, got, n, turns = serve()
+    assert (n_one, n) == (1, 2)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert len(turns) == eng.stats["prefill_calls"] == 1 + 7 + 6
+    assert all(0 < end - start <= 16 for _, start, end in turns)
+    for slot in {t[0] for t in turns[1:]}:       # each prompt in order
+        mine = [t for t in turns[1:] if t[0] == slot]
+        assert [t[1] for t in mine[1:]] == [t[2] for t in mine[:-1]]
+    assert eng.stats["prefill_budget_chunks"] > eng.stats["decode_calls"]
+    assert eng.stats["window_pages_recycled"] > 0
+    assert eng.pool.pages_in_use == 0 and eng.ring.pages_in_use == 0
+
+
 def test_preemption_under_a_small_full_group_is_exact(model):
     prompts = _prompts(5, (8, 16, 30))
     new = (24, 16, 12)

@@ -189,16 +189,59 @@ def test_scheduler_admission_ignores_max_new_tokens():
     assert len(adm) == 1 and len(adm[0].pages) == 1  # not pages_for(32)
 
 
-def test_scheduler_chunk_budget():
-    """Sarathi budget arithmetic: prefill allowance = token_budget minus
-    one token per active decode, capped at the chunk program width,
-    floored at 1 so a saturated decode batch can't starve prefill."""
+@pytest.mark.parametrize("call, token_budget, kw, want", [
+    # token_budget minus one token per active decode, under one chunk's
+    # width while nothing asks for more, floored at 1
+    ("prefill_budget", 16, dict(n_decoding=0, chunk_tokens=64), 16),
+    ("prefill_budget", 16, dict(n_decoding=4, chunk_tokens=64), 12),
+    ("prefill_budget", 16, dict(n_decoding=4, chunk_tokens=8), 8),
+    ("prefill_budget", 16, dict(n_decoding=99, chunk_tokens=8), 1),
+    # k chunks: one with nothing decoding, one while decodes outnumber the
+    # requests waiting on prefill, ceil(p / b) beyond
+    ("prefill_budget", 600,
+     dict(n_decoding=0, chunk_tokens=128, n_prefilling=40), 128),
+    ("prefill_budget", 600,
+     dict(n_decoding=7, chunk_tokens=128, n_prefilling=7), 128),
+    ("prefill_budget", 600,
+     dict(n_decoding=7, chunk_tokens=128, n_prefilling=8), 256),
+    ("prefill_budget", 600,
+     dict(n_decoding=7, chunk_tokens=128, n_prefilling=15), 384),
+    # ... capped by token_budget less the decodes' reservation
+    ("prefill_budget", 544,
+     dict(n_decoding=7, chunk_tokens=128, n_prefilling=40), 537),
+    ("prefill_budget", 544, dict(n_decoding=7, chunk_tokens=128,
+                                 decode_cost=5, n_prefilling=40), 509),
+    ("prefill_budget", 128,
+     dict(n_decoding=1, chunk_tokens=128, n_prefilling=40), 127),
+    ("prefill_budget", 8,
+     dict(n_decoding=9, chunk_tokens=128, n_prefilling=40), 1),
+    # the budget goes in whole dispatches: a full chunk, a prompt's end
+    ("chunk_rows", None,
+     dict(remaining=900, budget=537, spent=0, chunk_tokens=128), 128),
+    ("chunk_rows", None,
+     dict(remaining=30, budget=537, spent=256, chunk_tokens=128), 30),
+    # a tail under chunk_tokens is spent only to finish a prompt
+    ("chunk_rows", None,
+     dict(remaining=20, budget=25, spent=512, chunk_tokens=128), 20),
+    ("chunk_rows", None,
+     dict(remaining=900, budget=25, spent=512, chunk_tokens=128), 0),
+    ("chunk_rows", None,
+     dict(remaining=26, budget=25, spent=512, chunk_tokens=128), 0),
+    # ... but a step's first chunk_tokens are spent to the token, as a
+    # budget of one chunk always was
+    ("chunk_rows", None,
+     dict(remaining=900, budget=98, spent=30, chunk_tokens=128), 98),
+    ("chunk_rows", None,
+     dict(remaining=900, budget=4, spent=0, chunk_tokens=64), 4),
+])
+def test_scheduler_chunk_budget(call, token_budget, kw, want):
+    """Sarathi budget arithmetic: prefill allowance = k chunks under
+    token_budget minus one token per active decode, k from the counts of
+    decoding and prefilling requests, floored at 1 so a saturated decode
+    batch can't starve prefill; and what one dispatch takes of it."""
     pool = KVPool(1, 1, 8, num_pages=20, page_size=4)
-    sched = FCFSScheduler(n_slots=8, pool=pool, token_budget=16)
-    assert sched.prefill_budget(0, chunk_tokens=64) == 16
-    assert sched.prefill_budget(4, chunk_tokens=64) == 12
-    assert sched.prefill_budget(4, chunk_tokens=8) == 8   # chunk cap
-    assert sched.prefill_budget(99, chunk_tokens=8) == 1  # progress floor
+    sched = FCFSScheduler(n_slots=8, pool=pool, token_budget=token_budget)
+    assert getattr(sched, call)(**kw) == want
 
 
 def test_scheduler_rejects_oversized_request():
@@ -713,6 +756,52 @@ def test_engine_mid_prefill_admission_and_budget():
     np.testing.assert_array_equal(fins[r1].tokens, refs[0])
     np.testing.assert_array_equal(fins[r2].tokens, refs[1])
     assert steps >= 5          # 16 prompt tokens at <= 4 per step + decode
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["fp", "int8"])
+def test_engine_backlog_spends_several_chunks_a_step(int8):
+    """Three long prompts wait on prefill beside ONE decoding request: the
+    step's budget is ceil(3 / 1) chunks of the same 8-row program, spent
+    whole before the decode, the tokens are those of an engine held to one
+    chunk a step (``token_budget=chunk_tokens``), and the counter reads
+    the allowance."""
+    model = _model()
+    rng = np.random.RandomState(36)
+    first, *backlog = _prompts(rng, (5, 40, 32, 24))
+
+    def serve(**kw):
+        eng = ServingEngine(model, max_slots=4, page_size=8, chunk_tokens=8,
+                            prefix_cache=False, int8=int8, **kw)
+        rids = [eng.add_request(first, 24)]
+        eng.step()                              # first decodes from here on
+        rids += [eng.add_request(p, 6) for p in backlog]
+        fins, per_step = {}, []
+        while eng.has_work:
+            before = eng.stats["prefill_calls"]
+            for f in eng.step():
+                fins[f.rid] = f
+            per_step.append(eng.stats["prefill_calls"] - before)
+        return eng, [fins[r].tokens for r in rids], per_step
+
+    one, want, one_steps = serve(token_budget=8)
+    eng, got, steps = serve()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    # b = 1, p = 3: three chunks a step until the first long prompt is
+    # through; then b = 2, p = 2 and b = 3, p = 1: one.  The backlog is
+    # prefilled in fewer steps (the run's length is the first request's 24
+    # decodes either way)
+    assert steps[:9] == [3, 3, 1, 1, 1, 1, 1, 1, 0]
+    assert np.count_nonzero(one_steps) > 8
+    assert eng.stats["prefill_traces"] == one.stats["prefill_traces"]
+    s = eng.stats
+    assert one.stats["prefill_budget_chunks"] == one.stats["decode_calls"]
+    assert s["prefill_budget_chunks"] == s["decode_calls"] + 2 + 2
+    assert s["decode_calls_after_2plus_chunks"] == 2
+    # 40 + 32 + 24 tokens in whole chunks: no dispatch was cut short by
+    # the budget, where 7 tokens a step (8 less the decode) cut every one
+    assert s["prefill_calls"] - 1 == 5 + 4 + 3
+    assert one.stats["prefill_calls"] - 1 > 5 + 4 + 3
 
 
 def test_engine_rejects_prompt_larger_than_pool():
