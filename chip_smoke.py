@@ -7,6 +7,10 @@ the GPT-760M flagship (``bench.py``: vocab 50304, hidden 1536, 12 heads x
 
   * kernel leg — every Pallas entry COMPILED (never interpreted) at the
     shapes the two legs below use and compared with its own jnp oracle;
+  * state leg — the same for a model with recurrent state beside its pages
+    (Falcon-H1's cell): the two kernels of the Mamba-2 recurrence
+    (``kernels/ssd.py``) against their jnp paths, and the two paged kernels
+    at its GQA group of 5;
   * train leg — ``models.gpt.build_functional_train_step``, batch 12 x seq
     1024, a few steps on a fixed batch;
   * serve leg — ``serving.ServingEngine`` with default auto-dispatch, a
@@ -55,6 +59,7 @@ SPEC_K = 4
 # fp32 at "highest" matmul precision on the same bf16-rounded inputs.
 TOL_FWD = 2e-2
 TOL_GRAD = 3e-2
+TOL_STATE = 1e-3      # the recurrence's kernels are float32 throughout
 
 
 class SmokeFailure(Exception):
@@ -349,6 +354,68 @@ def _cell_prefill_case(*, required, heads, n_kv, max_pages, start,
                       (q, kp, vp, jnp.asarray(table), jnp.int32(start)))
 
 
+def _ssm_operands(rng, rows, heads=32, groups=2, head_dim=128, d_state=256,
+                  slab_rows=24):
+    """A state slab and ``rows`` rows of the recurrence's operands at
+    Falcon-H1's widths (32 heads of 128 in 2 groups, state 256), float32:
+    decays between 0.2 and 0.999 a row."""
+    import jax.numpy as jnp
+
+    def arr(*shape):
+        return jnp.asarray(rng.randn(*shape).astype("float32"))
+
+    slab = arr(slab_rows, heads, head_dim, d_state)
+    x, b, c = arr(rows, heads, head_dim), arr(rows, groups, d_state), \
+        arr(rows, groups, d_state)
+    dt = jnp.asarray(rng.uniform(1e-3, 1e-1, (rows, heads)).astype("float32"))
+    a = -jnp.asarray(rng.uniform(1.0, 16.0, (heads,)).astype("float32"))
+    return slab, x, dt, a, b, c, arr(heads)
+
+
+def _scan_case(*, required, rows, valid):
+    """The chunk scan at the cell's widths: a bucket of ``rows`` rows of
+    which ``valid`` are real, from a carried state, into row 7 of a slab
+    whose other rows must come back untouched."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import ssd
+
+    slab, x, dt, a, b, c, d = _ssm_operands(np.random.RandomState(0), rows)
+    dt = dt * (jnp.arange(rows) < valid)[:, None]
+
+    def kernel(slab, x, dt, a, b, c, d):
+        return ssd.ssd_chunk_scan(slab, 7, x, dt, a, b, c, d,
+                                  interpret=False)
+
+    def oracle(slab, x, dt, a, b, c, d):
+        return ssd.ssd_chunk_scan_ref(slab, 7, x, dt, a, b, c, d)
+
+    return KernelCase(required, TOL_STATE, kernel, oracle,
+                      (slab, x, dt, a, b, c, d))
+
+
+def _step_case(*, required, slots, live):
+    """The decode state step at the cell's widths: ``slots`` lanes of
+    which every ``live``-th is dead, on the second layer's rows of a slab
+    of two: dead lanes and the first layer must come back untouched."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import ssd
+
+    slab, x, dt, a, b, c, d = _ssm_operands(
+        np.random.RandomState(0), slots, slab_rows=2 * slots)
+    active = jnp.asarray(np.arange(slots) % live != live - 1)
+
+    def kernel(slab, x, dt, a, b, c, d, active):
+        return ssd.ssm_state_step(slab, slots, x, dt, a, b, c, d, active,
+                                  interpret=False)
+
+    def oracle(slab, x, dt, a, b, c, d, active):
+        return ssd.ssm_state_step_ref(slab, slots, x, dt, a, b, c, d, active)
+
+    return KernelCase(required, TOL_STATE, kernel, oracle,
+                      (slab, x, dt, a, b, c, d, active))
+
 
 _H = WIDTH["hidden_size"]
 
@@ -389,6 +456,18 @@ KERNEL_CASES = {
         window=4096)),
     "w8a8_gemm_chunk": (_w8a8_case, dict(
         required=True, m=CHUNK, k=_H, n=3 * _H)),
+    # Falcon-H1's cell: the paged kernels at its GQA group of 5 (20 query
+    # heads over 4 KV heads), and the two kernels of its recurrence
+    "paged_attention_cell_gqa5": (_cell_decode_case, dict(
+        required=True, slots=96, heads=20, n_kv=4, max_pages=32)),
+    "paged_prefill_cell_gqa5": (_cell_prefill_case, dict(
+        required=True, heads=20, n_kv=4, max_pages=32, start=9 * PAGE + 7)),
+    "ssd_chunk_scan_cell": (_scan_case, dict(
+        required=True, rows=CHUNK, valid=CHUNK)),
+    "ssd_chunk_scan_bucket8": (_scan_case, dict(
+        required=True, rows=8, valid=5)),
+    "ssm_state_step_cell": (_step_case, dict(
+        required=True, slots=96, live=4)),
     # attempted and reported
     "paged_attention_int4": (_decode_case, dict(required=False, kv_bits=4)),
     "paged_attention_window": (_decode_case, dict(
@@ -425,14 +504,21 @@ def _max_rel_err(got, want) -> float:
     return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
 
 
-def kernel_leg() -> None:
+#: the cases of the state leg: a model with recurrent state beside its pages
+STATE_CASES = ("paged_attention_cell_gqa5", "paged_prefill_cell_gqa5",
+               "ssd_chunk_scan_cell", "ssd_chunk_scan_bucket8",
+               "ssm_state_step_cell")
+
+
+def kernel_leg(names=None) -> None:
     import jax
 
     from paddle_tpu.analysis.jaxpr_audit import pallas_kernels
 
     refused: List[str] = []
     broken: List[str] = []
-    for name in KERNEL_CASES:
+    for name in (names if names is not None
+                 else [n for n in KERNEL_CASES if n not in STATE_CASES]):
         case = kernel_case(name)
         t0 = time.perf_counter()
         try:
@@ -721,6 +807,7 @@ def main() -> int:
 
     failed: List[str] = []
     _run_leg("kernel leg", kernel_leg, failed)
+    _run_leg("state leg", lambda: kernel_leg(STATE_CASES), failed)
     first_loss = _run_leg("train leg", train_leg, failed)
     _run_leg("serve leg", serve_leg, failed)
     if n_dev >= 4 and first_loss is not None:

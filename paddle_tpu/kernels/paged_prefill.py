@@ -21,11 +21,15 @@ Design (pallas_guide.md, same skeleton as the decode kernel):
     it attends: no grid step, DMA or score for a table entry past the
     chunk's end or under its window, whatever the table's width (such an
     entry may name any page);
-  * the whole (chunk, H, D) query block sits in VMEM across the page
-    grid where it fits; where it does not (128 query heads of 128: 17 MB),
-    the grid gains a leading axis over KV heads and a block is one KV
-    head's group, (chunk, H / Hkv, D), against that head's (page_size, D)
-    rows of the page: :func:`block_heads` decides from the shapes.  Each
+  * under MHA the whole (chunk, H, D) query block sits in VMEM across the
+    page grid.  Under GQA the grid gains a leading axis over KV heads and a
+    block is one KV head's group, (chunk, H / Hkv, D), against that head's
+    (page_size, D) rows of the page (128 query heads of 128 would not fit
+    whole: 17 MB), the group padded with zero query heads to a multiple of
+    8 where it is not one (20 heads over 4: 5 -> 8; the padding's output is
+    dropped): a regrouping of the whole block's scores over KV heads does
+    not lower for the chip at any group.  :func:`block_heads` decides from
+    the shapes.  Each
     live page folds into a flash online-softmax recurrence with per-query
     m/l/acc scratch, initialised on page ``lo`` and divided out on page
     ``hi - 1``.  Inside a live page CAUSALITY (and the window's lower
@@ -72,17 +76,13 @@ _VMEM_BUDGET = 8 * 1024 * 1024      # of the core's 16 MB
 def block_heads(n_heads: int, page_size: int, head_dim: int, chunk: int,
                 n_kv_heads: int | None = None) -> int | None:
     """Query heads in one block of the kernel, or None where no block
-    fits.  All ``n_heads`` under MHA and under GQA with a group that is not
-    sublane-aligned: q + acc (chunk, H, D) and the K/V pages (Hkv, ps, D)
-    have to fit the VMEM budget.  One KV head's group ``H / Hkv`` under GQA
-    with a group of a multiple of 8 (the grid then also runs over KV
-    heads): the whole block's regrouping of its scores over KV heads does
-    not lower for the chip at such groups, and at 128 query heads it would
-    not fit either."""
+    fits.  All ``n_heads`` under MHA: q + acc (chunk, H, D) and the K/V
+    pages (H, ps, D) have to fit the VMEM budget.  One KV head's group
+    under GQA, ``H / Hkv`` rounded up to a multiple of 8 (the grid then
+    also runs over KV heads, and :func:`paged_prefill` pads the group)."""
     nkv = n_kv_heads or n_heads
-    group = n_heads // nkv
-    heads, kv = (group, 1) if nkv != n_heads and group % 8 == 0 \
-        else (n_heads, nkv)
+    heads, kv = (n_heads, nkv) if nkv == n_heads \
+        else (-(-(n_heads // nkv) // 8) * 8, 1)
     vmem = 4 * (2 * chunk * heads * head_dim + 2 * kv * page_size * head_dim)
     return heads if vmem < _VMEM_BUDGET else None
 
@@ -153,19 +153,17 @@ def _group_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
 
 
 def _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=None, n_kv=None,
-                      page_axis=0):
+                      page_size, scale, chunk, window=None, page_axis=0):
     """The ONE online-softmax page step shared by the float/int8/int4
     entries (only how k/v materialize in VMEM differs).  ``walk_ref``
     holds ``(start, lo, hi)``: grid step ``p`` of the page axis is logical
     page ``lo + p`` of the chunk's live range.  Init scratch on the first
-    live page, score + causal-mask this page against every chunk row (GQA
-    query heads regrouped over the shared KV head, never repeating K/V;
-    sliding window drops keys more than ``window`` behind each row), fold
+    live page, score + causal-mask this page against every chunk row
+    (sliding window drops keys more than ``window`` behind each row), fold
     into the m/l/acc flash recurrence, divide out on the last live page.
-    ``page_axis`` is the grid axis that runs over pages: 0, or 1 under a
-    leading axis over KV heads (:func:`_group_recurrence` then does the
-    step)."""
+    ``page_axis`` is the grid axis that runs over pages: 0 (MHA, all heads
+    in the block), or 1 under a leading axis over KV heads (GQA:
+    :func:`_group_recurrence` then does the step)."""
     if page_axis:
         return _group_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref,
                                  acc_ref, page_size, scale, window=window)
@@ -179,18 +177,8 @@ def _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[...].astype(jnp.float32)                     # (C, H, D)
-    c, h, d = q.shape
-    nkv = h if n_kv is None else n_kv
-    g = h // nkv
-    if g == 1:
-        s = jnp.einsum("chd,hsd->hcs", q, k,
-                       preferred_element_type=jnp.float32)  # (H, C, ps)
-    else:
-        qg = q.reshape(c, nkv, g, d)
-        s = jnp.einsum("cngd,nsd->ngcs", qg, k,
-                       preferred_element_type=jnp.float32) \
-            .reshape(h, c, page_size)
-    s = s * scale
+    s = jnp.einsum("chd,hsd->hcs", q, k,
+                   preferred_element_type=jnp.float32) * scale  # (H, C, ps)
     pos = p * jnp.int32(page_size) + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, page_size), 2)
     qpos = start + jax.lax.broadcasted_iota(
@@ -205,14 +193,8 @@ def _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
     alpha = jnp.exp(m_prev - m_new)
     pexp = jnp.exp(s - m_new[:, :, None])
     l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=2)
-    if g == 1:
-        upd = jnp.einsum("hcs,hsd->hcd", pexp, v,
-                         preferred_element_type=jnp.float32)
-    else:
-        pg = pexp.reshape(nkv, g, c, page_size)
-        upd = jnp.einsum("ngcs,nsd->ngcd", pg, v,
-                         preferred_element_type=jnp.float32) \
-            .reshape(h, c, v.shape[-1])
+    upd = jnp.einsum("hcs,hsd->hcd", pexp, v,
+                     preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * alpha[:, :, None] + upd
     m_ref[...] = m_new
 
@@ -224,23 +206,22 @@ def _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
 
 def _prefill_kernel(bt_ref, walk_ref, q_ref, k_ref, v_ref, o_ref,
                     m_ref, l_ref, acc_ref, *, page_size, scale, chunk,
-                    window=None, n_kv=None, page_axis=0):
+                    window=None, page_axis=0):
     k = k_ref[0].astype(jnp.float32)                       # (Hkv, ps, D)
     v = v_ref[0].astype(jnp.float32)
     _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=window, n_kv=n_kv,
+                      page_size, scale, chunk, window=window,
                       page_axis=page_axis)
 
 
 # the int8 entry has its own arity (scale refs) but the same recurrence
 def _prefill_kernel_int8(bt_ref, walk_ref, q_ref, k_ref, ks_ref, v_ref,
                          vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                         page_size, scale, chunk, window=None, n_kv=None,
-                         page_axis=0):
+                         page_size, scale, chunk, window=None, page_axis=0):
     k = k_ref[0].astype(jnp.float32) * ks_ref[0]           # (Hkv, ps, D)
     v = v_ref[0].astype(jnp.float32) * vs_ref[0]
     _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=window, n_kv=n_kv,
+                      page_size, scale, chunk, window=window,
                       page_axis=page_axis)
 
 
@@ -248,12 +229,11 @@ def _prefill_kernel_int8(bt_ref, walk_ref, q_ref, k_ref, ks_ref, v_ref,
 # happens in VMEM right after the page DMA — same decision as decode
 def _prefill_kernel_int4(bt_ref, walk_ref, q_ref, k_ref, ks_ref, v_ref,
                          vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                         page_size, scale, chunk, window=None, n_kv=None,
-                         page_axis=0):
+                         page_size, scale, chunk, window=None, page_axis=0):
     k = _unpack4_vmem(k_ref[0]) * ks_ref[0]                # (Hkv, ps, D)
     v = _unpack4_vmem(v_ref[0]) * vs_ref[0]
     _chunk_recurrence(walk_ref, q_ref, k, v, o_ref, m_ref, l_ref, acc_ref,
-                      page_size, scale, chunk, window=window, n_kv=n_kv,
+                      page_size, scale, chunk, window=window,
                       page_axis=page_axis)
 
 
@@ -282,6 +262,18 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
     if scale is None:
         scale = 1.0 / np.sqrt(d)
     scale = np.float32(scale)
+    group = h // hkv
+    if hkv != h and group % 8:
+        # a group that is not sublane-aligned: zero query heads fill it up
+        # (their rows attend like any other and are dropped)
+        full = -(-group // 8) * 8
+        qp = jnp.pad(q.reshape(c, hkv, group, d),
+                     ((0, 0), (0, 0), (0, full - group), (0, 0)))
+        out = paged_prefill(
+            qp.reshape(c, hkv * full, d), k_pages, v_pages, block_table,
+            start, k_scales=k_scales, v_scales=v_scales, scale=scale,
+            window=window, interpret=interpret)
+        return out.reshape(c, hkv, full, d)[:, :, :group].reshape(c, h, d)
     if interpret is None:
         interpret = not _backend_is_tpu()
     quant = k_scales is not None
@@ -298,10 +290,9 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
     def entry(p, bt, walk):
         return bt[jnp.minimum(walk[1] + p, max_pages - 1)]
 
-    hb = block_heads(h, ps, d, c, hkv) or h       # query heads in a block
-    if hb == h:
+    if hkv == h:
         # the whole chunk's heads at once; the grid runs over live pages
-        nkv, grid, page_axis = (None if hkv == h else hkv), (hi - lo,), 0
+        hb, grid, page_axis = h, (hi - lo,), 0
         kvb = hkv
 
         def at(page):
@@ -309,7 +300,7 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
                                         if page else (0, 0, 0))
     else:
         # one KV head's group at a time: grid (KV heads, live pages)
-        nkv, grid, page_axis = 1, (hkv, hi - lo), 1
+        hb, grid, page_axis = group, (hkv, hi - lo), 1
         kvb = 1
 
         def at(page):
@@ -328,7 +319,7 @@ def paged_prefill(q, k_pages, v_pages, block_table, start, *,
         in_specs = [q_spec, pg_spec, pg_spec]
         args = (q, k_pages, v_pages)
     kernel = functools.partial(body, page_size=ps, scale=scale, chunk=c,
-                               window=win, n_kv=nkv, page_axis=page_axis)
+                               window=win, page_axis=page_axis)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -25,6 +25,9 @@ from .generation import (  # noqa: F401
 from .cohere2_moe import (  # noqa: F401
     Cohere2MoeConfig, Cohere2MoeForCausalLM,
 )
+from .falcon_h1 import (  # noqa: F401
+    FalconH1Config, FalconH1ForCausalLM,
+)
 from .rec import (  # noqa: F401
     RecConfig, DeepFM, WideDeep, FusedSparseEmbedding, synthetic_click_batch,
 )

@@ -182,14 +182,15 @@ class Cohere2MoeForCausalLM(nn.Layer):
         pos = jnp.broadcast_to(jnp.arange(ids.shape[1], dtype=jnp.int32),
                                ids.shape)
         x = p["wte"][ids]
-        for spec, bp in zip(self.layer_specs(), p["blocks"]):
+        specs = self.layer_specs()
+        for spec, bp in zip(specs, p["blocks"]):
             q, k, v = _block_qkv(bp, x, cfg.num_heads, cfg.layer_norm_eps,
                                  n_kv_heads=cfg.num_kv_heads, spec=spec,
                                  pos=pos)
             out = dense_attention(q, k, v, window=spec.window)
             x = _block_finish(bp, x, out.astype(x.dtype), cfg.layer_norm_eps,
                               spec=spec)
-        return _lm_head(p, x, cfg.layer_norm_eps)
+        return _lm_head(p, x, cfg.layer_norm_eps, specs[-1])
 
     def forward(self, ids):
         from ..dygraph.tensor import Tensor

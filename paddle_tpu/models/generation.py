@@ -40,8 +40,28 @@ import jax.numpy as jnp
 from jax import lax
 
 from .moe import MoESpec, moe_ffn
+from .ssm import SSMSpec
 
 _tree_map = jax.tree_util.tree_map
+
+
+@dataclasses.dataclass(frozen=True)
+class MuP:
+    """The scalar multipliers of a maximal-update parametrisation, each
+    applied where its name says (1 everywhere is no parametrisation):
+    ``embedding`` on the token embedding, ``attn_in`` on the normed input
+    of the q/k/v projections, ``key`` on k, ``attn_out`` on the attention
+    output projection, ``mlp_gate`` on the gated MLP's gate before its
+    activation, ``mlp_down`` on the MLP's output, ``head`` on the logits.
+    (The state-space mixer's are on its :class:`SSMSpec`.)"""
+
+    embedding: float = 1.0
+    attn_in: float = 1.0
+    key: float = 1.0
+    attn_out: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+    head: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,14 +70,22 @@ class LayerSpec:
     ``_block_finish``) and the serving engine's programs read this and the
     block's parameter names; the default is GPT-2's layer.
 
-    ``norm_bias``: LayerNorm with an offset, or gain only.  ``position``:
-    ``"learned"`` (a table added to the token embedding, nothing in the
-    layer), ``"rope"`` (q and k rotated in interleaved pairs over all of
-    the head's dims, GPT-J style, by ``rope_theta``) or ``"none"``.
+    ``norm``: ``"layer"`` (LayerNorm; ``norm_bias``: with an offset, or
+    gain only) or ``"rms"`` (RMSNorm, gain only, computed in float32).
+    ``position``: ``"learned"`` (a table added to the token embedding,
+    nothing in the layer), ``"rope"`` (q and k rotated in interleaved pairs
+    over all of the head's dims, GPT-J style, by ``rope_theta``),
+    ``"rope_half"`` (the same angles, dim ``i`` paired with ``i + d / 2``:
+    rotate-half, GPT-NeoX style) or ``"none"``.
     ``window``: causal sliding window of that many keys, or None for full
     causal attention.  ``parallel``: ``x + attn(n) + mlp(n)`` with one norm
     feeding both, instead of GPT-2's two norms in sequence.  ``moe``: the
-    expert layer's description, or None for GPT-2's GELU MLP.
+    expert layer's description, or None for a dense MLP of kind ``mlp``:
+    ``"gelu"`` (GPT-2's ``fc1``/``fc2``) or ``"gated_silu"``
+    (``(silu(m Wg) * m Wu) Wd``).  ``ssm``: a state-space mixer that reads
+    the attention's norm and whose output joins the attention's in the
+    residual (``x + attn(n) + ssm(n)``, the MLP after it by ``parallel``),
+    or None.  ``mup``: the block's scalar multipliers.
     ``head_dim``: None means ``hidden // n_heads``."""
 
     norm_bias: bool = True
@@ -67,10 +95,18 @@ class LayerSpec:
     parallel: bool = False
     moe: Optional[MoESpec] = None
     head_dim: Optional[int] = None
+    norm: str = "layer"
+    mlp: str = "gelu"
+    ssm: Optional[SSMSpec] = None
+    mup: MuP = MuP()
 
     def __post_init__(self):
-        if self.position not in ("learned", "rope", "none"):
+        if self.position not in ("learned", "rope", "rope_half", "none"):
             raise ValueError(f"position kind {self.position!r}")
+        if self.norm not in ("layer", "rms"):
+            raise ValueError(f"norm kind {self.norm!r}")
+        if self.mlp not in ("gelu", "gated_silu"):
+            raise ValueError(f"mlp kind {self.mlp!r}")
 
 
 GPT2_LAYER = LayerSpec()
@@ -103,6 +139,26 @@ def rope_interleaved(x, pos, theta):
     a, b = xf[..., 0::2], xf[..., 1::2]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
                      axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def rope_half(x, pos, theta):
+    """Rotate ``x`` (B, heads, T, D) in the pairs (i, i + D / 2)
+    (rotate-half), the row at ``pos`` (B, T) by ``pos * theta ** (-2i /
+    D)``; float32 inside."""
+    d = x.shape[-1]
+    inv = 1.0 / (jnp.float32(theta)
+                 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = pos.astype(jnp.float32)[:, None, :, None] * inv      # (B,1,T,D/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (2, d // 2))
+    a, b = xf[..., 0, :], xf[..., 1, :]
+    # (a concatenate of the two half-width results along the lanes aborts
+    # the TPU compiler, libtpu 0.0.34; the stack + reshape does not)
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-2).reshape(x.shape).astype(x.dtype)
+
+
+_ROPE = {"rope": rope_interleaved, "rope_half": rope_half}
 
 
 def _block_params(blk, int8=False):
@@ -175,6 +231,22 @@ def _ln(x, g, b, eps):
     return y if b is None else y + b
 
 
+def _times(y, m):
+    """``y`` scaled by the multiplier ``m`` in its own type (1: as it is)."""
+    return y if m == 1.0 else y * jnp.asarray(m, y.dtype)
+
+
+def _norm(p, name, x, eps, spec):
+    """The block's norm ``name`` (``ln1``, ``ln2``, ``lnf``) of the kind
+    ``spec`` states."""
+    if spec.norm == "rms":
+        xf = x.astype(jnp.float32)
+        y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+        return (y * p[name + "_g"].astype(jnp.float32)).astype(x.dtype)
+    return _ln(x, p[name + "_g"],
+               p[name + "_b"] if spec.norm_bias else None, eps)
+
+
 def _block_qkv(p, x, n_heads, eps, n_kv_heads=None, spec=GPT2_LAYER,
                pos=None):
     """The block's pre-attention half: LN1 + fused QKV projection + head
@@ -189,7 +261,7 @@ def _block_qkv(p, x, n_heads, eps, n_kv_heads=None, spec=GPT2_LAYER,
     b, t, h = x.shape
     hd = spec.head_dim or h // n_heads
     nkv = n_heads if n_kv_heads is None else n_kv_heads
-    hx = _ln(x, p["ln1_g"], p["ln1_b"] if spec.norm_bias else None, eps)
+    hx = _times(_norm(p, "ln1", x, eps, spec), spec.mup.attn_in)
     qkv = _mm(p, "qkv", hx)
     if "qkv_b" in p:
         qkv = qkv + p["qkv_b"]
@@ -198,46 +270,61 @@ def _block_qkv(p, x, n_heads, eps, n_kv_heads=None, spec=GPT2_LAYER,
     def heads(z, n):  # (B, T, n*hd) -> (B, n, T, hd)
         return z.reshape(b, t, n, hd).transpose(0, 2, 1, 3)
 
+    k = _times(k, spec.mup.key)
     q = heads(q, n_heads)
     k_blk, v_blk = heads(k, nkv), heads(v, nkv)
-    if spec.position == "rope":
-        q = rope_interleaved(q, pos, spec.rope_theta)
-        k_blk = rope_interleaved(k_blk, pos, spec.rope_theta)
+    rotate = _ROPE.get(spec.position)
+    if rotate is not None:
+        q = rotate(q, pos, spec.rope_theta)
+        k_blk = rotate(k_blk, pos, spec.rope_theta)
     return q, k_blk, v_blk
 
 
-def _lm_head(p, x, eps):
-    """Final LN + tied-embedding projection to fp32 logits over the last
-    axis of ``x``.  Shared by the dense decoder and the serving engine's
+def _lm_head(p, x, eps, spec=GPT2_LAYER):
+    """Final norm (of the kind the model's layers have) + projection to
+    fp32 logits over the last axis of ``x``: by the head ``lm_head``
+    (V, h) where the model has its own, else by the tied embedding.
+    Shared by the dense decoder and the serving engine's
     chunk-prefill/decode programs so the logits math cannot fork."""
-    h = _ln(x, p["lnf_g"], p.get("lnf_b"), eps)
-    return (h @ p["wte"].T).astype(jnp.float32)
+    h = _norm(p, "lnf", x, eps, spec)
+    logits = (h @ p.get("lm_head", p["wte"]).T).astype(jnp.float32)
+    return _times(logits, spec.mup.head)
 
 
-def _embed(p, toks, pos):
-    """Token embedding, plus the learned position table where the model
-    has one (rotary and position-free layers take positions themselves)."""
-    x = p["wte"][toks]
+def _embed(p, toks, pos, spec=GPT2_LAYER):
+    """Token embedding (times the model's embedding multiplier), plus the
+    learned position table where the model has one (rotary and
+    position-free layers take positions themselves)."""
+    x = _times(p["wte"][toks], spec.mup.embedding)
     return x + p["wpe"][pos] if "wpe" in p else x
 
 
-def _block_finish(p, x, out, eps, spec=GPT2_LAYER, valid=None, counts=None):
+def _block_finish(p, x, out, eps, spec=GPT2_LAYER, valid=None, counts=None,
+                  mix=None):
     """The block's post-attention half: output projection residual + MLP
     residual.  ``out`` is the attention output already merged back to the
-    activation layout of ``x``.  Shared with serving/engine.py.  One body
+    activation layout of ``x``; ``mix`` is the state-space mixer's term of
+    the same residual, where the block has one.  Shared with
+    serving/engine.py.  One body
     for every :class:`LayerSpec`: a bias is added where the block has one,
     the MLP's norm reads the block's input (parallel) or the attention
-    residual (sequential), and the MLP is GPT-2's or the expert layer.  An
+    residual (sequential), and the MLP is GPT-2's, the gated one or the
+    expert layer.  An
     expert layer appends its per-expert row counts to the list ``counts``
     (``valid`` masks padded rows and idle lanes out of them)."""
     def plus(y, name):
         return y + p[name] if name in p else y
 
     h_in = x
-    x = plus(x + _mm(p, "proj", out), "proj_b")
-    ln = "ln1" if spec.parallel else "ln2"
-    hx = _ln(h_in if spec.parallel else x, p[ln + "_g"],
-             p[ln + "_b"] if spec.norm_bias else None, eps)
+    x = plus(x + _times(_mm(p, "proj", out), spec.mup.attn_out), "proj_b")
+    if mix is not None:
+        x = x + mix
+    hx = _norm(p, "ln1" if spec.parallel else "ln2",
+               h_in if spec.parallel else x, eps, spec)
+    if spec.moe is None and spec.mlp == "gated_silu":
+        mid = jax.nn.silu(_times(_mm(p, "gate", hx), spec.mup.mlp_gate)) \
+            * _mm(p, "up", hx)
+        return x + _times(_mm(p, "down", mid), spec.mup.mlp_down)
     if spec.moe is None:
         mid = jax.nn.gelu(plus(_mm(p, "fc1", hx), "fc1_b"),
                           approximate=False)

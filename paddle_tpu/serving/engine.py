@@ -99,16 +99,19 @@ from ..models.generation import (
     _embed,
     _lm_head,
     _make_sampler,
+    _norm,
     _resolve_kv_bits,
     decoder_layers,
     spec_accept_greedy,
 )
+from ..models.ssm import ssm_conv, ssm_in, ssm_out, ssm_split
 from ..kernels import paged_attention as pa
 from ..kernels import paged_prefill as pp
+from ..kernels import ssd
 from .drafter import NGramDrafter
 from .faults import FaultPlan, InjectedFault
 from .flight_recorder import FlightRecorder
-from .kv_pool import KVPool, WindowRing
+from .kv_pool import KVPool, StateSlab, WindowRing
 from .metrics import MetricsRegistry, SLOTracker
 from .scheduler import FCFSScheduler, Request
 from .tenancy import normalize_tenants
@@ -145,9 +148,11 @@ class FinishedRequest:
 
 
 class MultiGroupUnsupported(NotImplementedError):
-    """A feature that a model with two page groups (sliding-window layers
-    beside full-attention layers, ``serving/kv_pool.WindowRing``) does not
-    have yet; PERF.md section 7 lists them."""
+    """A feature that a model with more than the one page group does not
+    have yet: two page groups (sliding-window layers beside full-attention
+    layers, ``serving/kv_pool.WindowRing``), or recurrent state beside its
+    pages (``serving/kv_pool.StateSlab``), which cannot be sliced by
+    position at all; PERF.md section 7 lists them."""
 
 
 #: the four phases of a step: their spans also fill ``_phase_s``, which
@@ -379,7 +384,17 @@ class ServingEngine:
         # pages-walked counter
         self._window_layers = collections.Counter(
             spec.window for spec in self.layers)
+        # the state-space mixer's description, if the model has one (one
+        # for all its layers that have one: the slab is one shape)
+        mixers = {sp.ssm for sp in self.layers if sp.ssm is not None}
+        if len(mixers) > 1:
+            raise MultiGroupUnsupported(
+                "layers with different state-space mixers")
+        self._ssm = next(iter(mixers), None)
         if hasattr(model, "decoder_params"):
+            if int8 and self._ssm is not None:
+                raise MultiGroupUnsupported(
+                    "not available to a model with recurrent state: int8")
             if int8:
                 raise ValueError("int8 projections are GPT's (_decoder_setup)")
             self.params, self.int8 = model.decoder_params(), False
@@ -411,15 +426,18 @@ class ServingEngine:
         self.window = None if two_groups else next(iter(windows))
         if self.window is not None:
             self.window = int(self.window)
-        if two_groups:
+        if two_groups or self._ssm is not None:
             refused = {"role": role != "both", "spec_k": self.spec_k > 0,
                        "decode_block": self.decode_block > 1,
                        "double_buffer": self.double_buffer,
                        "kv_bits": self.kv_bits is not None}
             if any(refused.values()):
                 raise MultiGroupUnsupported(
-                    "not available to a model with two page groups: "
-                    + ", ".join(k for k, v in refused.items() if v))
+                    "not available to a model with "
+                    + " and ".join(
+                        ["two page groups"] * two_groups
+                        + ["recurrent state"] * (self._ssm is not None))
+                    + ": " + ", ".join(k for k, v in refused.items() if v))
         self.max_slots = max_slots
         self.page_size = page_size
         self.max_seq_len = max_seq_len or cfg.max_seq_len
@@ -450,7 +468,8 @@ class ServingEngine:
                              for j, li in enumerate(ls)}
         self.pool = KVPool(len(full), cfg.num_heads, self.head_dim,
                            n_pages, page_size, dtype=dtype,
-                           prefix_cache=prefix_cache and not two_groups,
+                           prefix_cache=(prefix_cache and not two_groups
+                                         and self._ssm is None),
                            num_kv_heads=self.n_kv_heads,
                            kv_bits=self.kv_bits, window=self.window)
         self.pool.faults = faults
@@ -463,6 +482,14 @@ class ServingEngine:
                 self.chunk_tokens, dtype=dtype)
         self._group_pages = (n_pages,) + (
             (self.ring.num_pages,) if two_groups else ())
+        # the state group: each slot's recurrent state, a layer that has a
+        # mixer (model layer -> its index among them)
+        self.slab: Optional[StateSlab] = None
+        self._ssm_layer = {li: j for j, li in enumerate(
+            li for li, sp in enumerate(self.layers) if sp.ssm is not None)}
+        if self._ssm is not None:
+            self.slab = StateSlab(len(self._ssm_layer), max_slots, self._ssm,
+                                  conv_dtype=dtype)
         self.scheduler = FCFSScheduler(max_slots, self.pool,
                                        token_budget=token_budget,
                                        policy=policy, tenants=tenants)
@@ -485,10 +512,21 @@ class ServingEngine:
             self._use_spec_kernel = pa.available() and pa.supported_mq(
                 cfg.num_heads, page_size, self.head_dim, self.spec_k + 1,
                 n_kv_heads=self.n_kv_heads, kv_bits=self.kv_bits)
+            m = self._ssm
+            self._use_ssm_kernel = (
+                m is not None and ssd.available() and ssd.supported(
+                    m.n_heads, m.head_dim, m.n_groups, m.d_state,
+                    self.chunk_tokens))
         else:
             self._use_kernel = bool(use_paged_kernel)
             self._use_prefill_kernel = bool(use_paged_kernel)
             self._use_spec_kernel = bool(use_paged_kernel)
+            self._use_ssm_kernel = bool(use_paged_kernel)
+        if self._ssm is not None and self.chunk_tokens > self._ssm.chunk:
+            raise ValueError(
+                f"chunk_tokens {self.chunk_tokens} is more than the mixer's "
+                f"scan chunk {self._ssm.chunk}: an engine chunk is one chunk "
+                "of the scan")
 
         # ctor echo for snapshot/restore (serving/snapshot.py): enough to
         # rebuild an equivalent engine around the captured state.  faults
@@ -606,11 +644,22 @@ class ServingEngine:
                               moe_layer_passes=0)
         # (device counts, valid rows) of dispatches not yet synced on
         self._moe_pending: List[tuple] = []
+        if self.ring is not None or self.slab is not None:
+            # prefix_cache=True resolves to no index for two groups, and
+            # for state (a page of a prefix has no state to go with it)
+            self.stats["prefix_index_refused"] = int(bool(prefix_cache))
         if self.ring is not None:
             self.stats.update(
-                pages_in_use_window=0, window_pages_recycled=0,
-                # prefix_cache=True resolves to no index for two groups
-                prefix_index_refused=int(bool(prefix_cache)))
+                pages_in_use_window=0, window_pages_recycled=0)
+        if self.slab is not None:
+            # the state group: lane-layers the decode step was given and
+            # those of them that were live; valid rows through the chunk
+            # scan and rows as dispatched (padding too), times layers;
+            # zeroings of a slot's state; the slab's bytes
+            self.stats.update(
+                ssm_lane_steps=0, ssm_live_lane_steps=0, ssm_scan_rows=0,
+                ssm_scan_row_passes=0, state_resets=0,
+                state_slab_bytes=self.slab.hbm_bytes())
         # observability (r11/r16): all default OFF — the hot loop pays
         # nothing unless asked to measure itself
         self.metrics: Optional[MetricsRegistry] = None
@@ -651,6 +700,8 @@ class ServingEngine:
         self._prefill_fn = self._build_prefill()
         self._cow_fn = self._build_cow()
         self._verify_fn = self._build_verify() if self.spec_k else None
+        self._state_reset_fn = (self._build_state_reset()
+                                if self.slab is not None else None)
 
     # -- device programs --------------------------------------------------
 
@@ -687,17 +738,76 @@ class ServingEngine:
         out = tuple(self._unflat(b, g) for g, b in enumerate(groups))
         return out if self.ring is not None else out[0]
 
+    # A model with recurrent state hands the programs ``{"kv": <the page
+    # groups as above>, "state": StateSlab.buffers}``: the slab is donated
+    # and advanced in place with the pages (``_take_state`` splits it off
+    # on entry, ``_with_state`` puts it back on exit).
+
     def _device_pool(self):
         """The programs' buffer argument (donated)."""
-        if self.ring is None:
-            return self.pool.buffers
-        return (self.pool.buffers, self.ring.buffers)
+        kv = (self.pool.buffers if self.ring is None
+              else (self.pool.buffers, self.ring.buffers))
+        if self.slab is None:
+            return kv
+        return {"kv": kv, "state": self.slab.buffers}
 
     def _store_pool(self, bufs) -> None:
+        if self.slab is not None:
+            bufs, self.slab.buffers = bufs["kv"], bufs["state"]
         if self.ring is None:
             self.pool.buffers = bufs
         else:
             self.pool.buffers, self.ring.buffers = bufs
+
+    def _take_state(self, bufs):
+        """(page groups, state buffers or None) of a program's argument."""
+        if self.slab is None:
+            return bufs, None
+        return bufs["kv"], dict(bufs["state"])
+
+    def _with_state(self, kv, state):
+        return kv if state is None else {"kv": kv, "state": state}
+
+    def _mix(self, bp, x, spec, state, li, *, slot=None, n_valid=None,
+             active=None):
+        """The state-space mixer's term of layer ``li``'s residual for the
+        rows ``x`` of a program, advancing ``state`` (the slab's buffers,
+        updated in this dict) where the rows are real: a chunk's first
+        ``n_valid`` rows of ``slot`` (``x`` (1, C, h)), or one row of every
+        ``active`` lane (``x`` (S, 1, h)).  Kernel or jnp path."""
+        m, lj = spec.ssm, self._ssm_layer[li]
+        z, xbc, dt = ssm_in(bp, _norm(bp, "ln1", x, self.eps, spec), m)
+        conv, slab = state["conv"], state["ssm"]
+        rows = slab.reshape((-1,) + slab.shape[2:])        # (L * S, H, P, N)
+        a = -jnp.exp(bp["A_log"].astype(jnp.float32))
+        taps = m.d_conv - 1         # a slot's tail: (taps, channels), flat
+        if slot is not None:
+            tail = conv[lj, slot].reshape(taps, -1)
+            xbc, tail = ssm_conv(bp, xbc[0], tail, n_valid)
+            state["conv"] = conv.at[lj, slot].set(tail.reshape(-1))
+            xs, bm, cm = ssm_split(xbc, m)
+            # a padding row has dt = 0: it changes nothing
+            dt = jnp.where((jnp.arange(x.shape[1]) < n_valid)[:, None],
+                           dt[0], 0.0)
+            scan = (ssd.ssd_chunk_scan if self._use_ssm_kernel
+                    else ssd.ssd_chunk_scan_ref)
+            y, rows = scan(rows, lj * self.max_slots + slot, xs, dt, a, bm,
+                           cm, bp["D"])
+            y = y[None]
+        else:
+            old = conv[lj]                                 # (S, taps * ch)
+            xbc, tail = ssm_conv(bp, xbc, old.reshape(old.shape[0], taps, -1),
+                                 1)
+            state["conv"] = conv.at[lj].set(
+                jnp.where(active[:, None], tail.reshape(old.shape), old))
+            xs, bm, cm = ssm_split(xbc[:, 0], m)
+            step = (ssd.ssm_state_step if self._use_ssm_kernel
+                    else ssd.ssm_state_step_ref)
+            y, rows = step(rows, lj * self.max_slots, xs, dt[:, 0], a, bm,
+                           cm, bp["D"], active)
+            y = y[:, None]
+        state["ssm"] = rows.reshape(slab.shape)
+        return ssm_out(bp, y, z, m, self.eps, x.dtype)
 
     def _device_tables(self, idx: Optional[int] = None):
         """The block tables (of slot ``idx``, or all), one per group: host
@@ -828,9 +938,10 @@ class ServingEngine:
         n_heads, eps = self.n_heads, self.eps
         k_steps, n_kv = self.decode_block, self.n_kv_heads
 
-        def one_step(p, bufs, table, toks, lengths, active, key):
+        def one_step(p, bufs, table, toks, lengths, active, key, state=None):
             s = toks.shape[0]
-            x = _embed(p, toks, lengths)[:, None, :]              # (S, 1, h)
+            x = _embed(p, toks, lengths,
+                       self.layers[0])[:, None, :]                # (S, 1, h)
             # exhausted/inactive lanes write nothing
             writes = [self._page_writes(t, lengths, active[:, None])
                       for t in table]
@@ -842,21 +953,26 @@ class ServingEngine:
                 self._scatter_layer(bufs, li, writes, kb, vb)
                 out = self._attend(q[:, :, 0], bufs, li, table, lengths + 1)
                 out = out.reshape(s, -1)[:, None, :].astype(x.dtype)
+                mix = None if spec.ssm is None else self._mix(
+                    bp, x, spec, state, li, active=active)
                 x = _block_finish(bp, x, out, eps, spec=spec,
-                                  valid=active[:, None], counts=counts)
-            logits = _lm_head(p, x[:, 0], eps)                    # (S, V)
+                                  valid=active[:, None], counts=counts,
+                                  mix=mix)
+            logits = _lm_head(p, x[:, 0], eps, self.layers[-1])   # (S, V)
             key, sub = jax.random.split(key)
             nxt = self._sample(logits, sub).astype(jnp.int32)
             return bufs, nxt, ((jnp.stack(counts),) if counts else ())
 
         def decode(p, bufs, toks, lengths, table, remaining, key):
             self.stats["decode_traces"] += 1  # python side effect: per trace
+            bufs, state = self._take_state(bufs)
             bufs, table = self._enter(bufs, table)
             if k_steps == 1:
                 active = remaining > 0
                 bufs, nxt, extra = one_step(p, bufs, table, toks, lengths,
-                                            active, key)
-                return (self._leave(bufs), nxt[None]) + extra      # (1, S)
+                                            active, key, state)
+                return (self._with_state(self._leave(bufs), state),
+                        nxt[None]) + extra                         # (1, S)
 
             def body(carry, i):
                 bufs, toks, lengths, remaining, key = carry
@@ -902,7 +1018,8 @@ class ServingEngine:
             # pad rows of short drafts can index positions past the table;
             # clamp for the position embedding (their outputs are unused)
             x = _embed(p, block, jnp.minimum(
-                pos, self.cfg.max_seq_len - 1))                  # (S, T, h)
+                pos, self.cfg.max_seq_len - 1),
+                self.layers[0])                                  # (S, T, h)
             # rows beyond the slot's draft count — and every row of a
             # lane not decoding this step (n_draft == -1) — are written
             # nowhere, exactly like inactive decode lanes
@@ -918,7 +1035,7 @@ class ServingEngine:
                                         table, lengths)
                 out = out.reshape(s, t, -1).astype(x.dtype)
                 x = _block_finish(bp, x, out, eps, spec=spec)
-            logits = _lm_head(p, x, eps)                     # (S, T, V)
+            logits = _lm_head(p, x, eps, self.layers[-1])    # (S, T, V)
             key, sub = jax.random.split(key)
             pred = self._sample(logits.reshape(s * t, -1), sub)
             return self._leave(bufs), pred.reshape(s, t).astype(jnp.int32)
@@ -929,20 +1046,22 @@ class ServingEngine:
         n_heads, eps, n_kv = self.n_heads, self.eps, self.n_kv_heads
 
         def prefill(p, bufs, toks, start, n_valid, table_row, sample_idx,
-                    key):
+                    key, slot=None):
             """One chunk of one prompt: rows [start, start+n_valid) of the
             sequence.  Writes the chunk's K/V into the slot's pages, then
             attends the chunk against every already-written position (the
             cached/previous pages AND itself) through the block table.
             ``sample_idx`` is the chunk row holding the LAST prompt token;
             its sample is used only by the chunk that completes the
-            prompt."""
+            prompt.  ``slot``: whose recurrent state the chunk advances
+            (a model with state only)."""
             self.stats["prefill_traces"] += 1
             c = toks.shape[0]
             pos = start + jnp.arange(c, dtype=jnp.int32)
-            x = _embed(p, toks, pos)[None]                    # (1, C, h)
+            x = _embed(p, toks, pos, self.layers[0])[None]    # (1, C, h)
             # padded rows are written nowhere
             valid = (jnp.arange(c) < n_valid)[None]
+            bufs, state = self._take_state(bufs)
             bufs, table_row = self._enter(bufs, table_row)
             writes = [self._page_writes(tb[None], start[None], valid)
                       for tb in table_row]
@@ -955,17 +1074,20 @@ class ServingEngine:
                 out = self._attend_prefill(jnp.swapaxes(q[0], 0, 1), bufs,
                                            li, table_row, start)
                 out = out.reshape(c, -1)[None].astype(x.dtype)
+                mix = None if spec.ssm is None else self._mix(
+                    bp, x, spec, state, li, slot=slot, n_valid=n_valid)
                 x = _block_finish(bp, x, out, eps, spec=spec, valid=valid,
-                                  counts=counts)
+                                  counts=counts, mix=mix)
             # only the sample row's logits are ever consumed (and only by
             # the chunk completing the prompt): project ONE row, not the
             # whole (C, V) chunk — LN + matmul are row-wise, so the
             # sampled logits are bit-identical to the full projection
             h_row = jnp.take(x[0], sample_idx, axis=0)        # (h,)
-            last = _lm_head(p, h_row[None, :], eps)           # (1, V)
+            last = _lm_head(p, h_row[None, :], eps,
+                            self.layers[-1])                  # (1, V)
             key, sub = jax.random.split(key)
             tok = self._sample(last, sub)[0].astype(jnp.int32)
-            return (self._leave(bufs), tok) + (
+            return (self._with_state(self._leave(bufs), state), tok) + (
                 (jnp.stack(counts),) if counts else ())
 
         return jax.jit(prefill, donate_argnums=(1,))
@@ -978,6 +1100,14 @@ class ServingEngine:
             return {k: b.at[:, dst].set(b[:, src]) for k, b in bufs.items()}
 
         return jax.jit(cow, donate_argnums=(0,))
+
+    def _build_state_reset(self):
+        def reset(state, slot):
+            """Zero one slot's recurrent state in every layer: what a
+            request finds when it takes the slot."""
+            return {k: b.at[:, slot].set(0) for k, b in state.items()}
+
+        return jax.jit(reset, donate_argnums=(0,))
 
     # -- public API -------------------------------------------------------
 
@@ -1068,12 +1198,16 @@ class ServingEngine:
         (the jnp oracles) for ``decode``, ``prefill`` and — when
         speculating — ``verify``.  Auto-dispatch (``use_paged_kernel=None``)
         decides from the backend and the shape gates at construction; this
-        is where that decision can be read."""
+        is where that decision can be read.  A model with recurrent state
+        adds ``ssm_step`` and ``ssm_scan`` (``kernels/ssd.py``)."""
         name = {True: "kernel", False: "reference"}
         paths = {"decode": name[self._use_kernel],
                  "prefill": name[self._use_prefill_kernel]}
         if self.spec_k:
             paths["verify"] = name[self._use_spec_kernel]
+        if self.slab is not None:
+            # the recurrence of a model with state, in decode and in a chunk
+            paths["ssm_step"] = paths["ssm_scan"] = name[self._use_ssm_kernel]
         return paths
 
     def prefix_hit_rate(self) -> float:
@@ -1217,6 +1351,24 @@ class ServingEngine:
                              "host time blocked on the decode device "
                              "sync (double buffering shrinks this)"),
         }
+        if self.slab is not None:
+            # the state group's series, beside the KV pool's; a model
+            # without state has none of them
+            self._m.update(
+                ssm_lane_steps=c("serving_ssm_lane_steps",
+                                 "lane-layers the decode state step walked"),
+                ssm_live_lane_steps=c("serving_ssm_live_lane_steps",
+                                      "lane-layers of live slots among them"),
+                ssm_scan_rows=c("serving_ssm_scan_rows",
+                                "valid row-layers through the chunk scan"),
+                ssm_scan_row_passes=c("serving_ssm_scan_row_passes",
+                                      "row-layers through the chunk scan, "
+                                      "padding included"),
+                state_resets=c("serving_state_resets",
+                               "slots whose recurrent state was zeroed for "
+                               "a (re-)admitted request"),
+                state_slab_bytes=g("serving_state_slab_bytes",
+                                   "HBM bytes of the recurrent-state slab"))
         # SLO layer (r16): only tenants that DECLARE budgets cost series
         if any(c.ttft_slo_s is not None or c.e2e_slo_s is not None
                for c in self._tenant_cfg.values()):
@@ -1365,6 +1517,9 @@ class ServingEngine:
         if self.ring is not None:
             raise MultiGroupUnsupported(
                 "snapshot / restore of a model with two page groups")
+        if self.slab is not None:
+            raise MultiGroupUnsupported(
+                "snapshot / restore of a model with recurrent state")
         return snapshot_engine(self)
 
     @classmethod
@@ -1406,6 +1561,8 @@ class ServingEngine:
         self._len[idx] = 0
         if self.ring is not None:
             self.ring.release(idx)
+        if self.slab is not None:
+            self.slab.release(idx)
         self.scheduler.release(idx, st.pages, st.request)
         self._observe_terminal(st.request, reason)
         return FinishedRequest(
@@ -1426,6 +1583,8 @@ class ServingEngine:
         self._len[idx] = 0
         if self.ring is not None:
             self.ring.release(idx)
+        if self.slab is not None:
+            self.slab.release(idx)
         self.scheduler.release(idx, st.pages, st.request)
         st.request.n_preempted += 1
         self.scheduler.requeue(st.request)
@@ -1501,6 +1660,14 @@ class ServingEngine:
         row = np.zeros((self.max_pages,), np.int32)
         row[:len(pages)] = pages
         self._table[idx] = row
+        if self.slab is not None:
+            # whatever the slot's last tenant left is zeroed before this
+            # one's first chunk: a first admission and a recompute after
+            # preemption alike (nothing of the state was saved)
+            self.slab.buffers = self._state_reset_fn(self.slab.buffers,
+                                                     jnp.int32(idx))
+            self.slab.release(idx)
+            self.stats["state_resets"] += 1
         self.stats["prefix_hit_tokens"] += adm.matched
         self.stats["prompt_tokens"] += req.work_len
         if req.n_preempted > 0:
@@ -1528,6 +1695,9 @@ class ServingEngine:
             self.tracer.begin("resident", self._pid_req, req.rid,
                               {"slot": idx, "matched": adm.matched,
                                "preempted": req.n_preempted})
+            if self.slab is not None:
+                self.tracer.instant("state_reset", self._pid_req, req.rid,
+                                    {"slot": idx})
 
     def _prefill_chunks(self, finished: List[FinishedRequest]) -> None:
         """Spend the step's prefill budget FCFS over partially-prefilled
@@ -1575,7 +1745,8 @@ class ServingEngine:
                         self.params, self._device_pool(), jnp.asarray(toks),
                         jnp.int32(st.prefilled), jnp.int32(n),
                         self._device_tables(idx), jnp.int32(n - 1),
-                        self._next_key())
+                        self._next_key(),
+                        *(() if self.slab is None else (jnp.int32(idx),)))
                     self._store_pool(bufs)
                     self._moe_pending += [(c, n) for c in counts]
                 if self.metrics is not None:
@@ -1583,6 +1754,11 @@ class ServingEngine:
                 if self.tracer is not None:
                     self.tracer.end(self._pid_req, req.rid)
                 self._note_prefill_dispatch(st.prefilled, c_pad)
+                if self.slab is not None:
+                    self.slab.advanced[idx] += n
+                    self.stats["ssm_scan_rows"] += n * self.slab.num_layers
+                    self.stats["ssm_scan_row_passes"] += (
+                        c_pad * self.slab.num_layers)
                 self._chunks_this_step += 1
                 st.prefilled += n
                 budget -= n
@@ -1725,6 +1901,8 @@ class ServingEngine:
         self._len[idx] = 0
         if self.ring is not None:
             self.ring.release(idx)
+        if self.slab is not None:
+            self.slab.release(idx)
         self.scheduler.release(idx, st.pages, st.request)
         return st
 
@@ -1800,6 +1978,10 @@ class ServingEngine:
             raise ValueError(
                 "a prefill-role engine cannot ingest handoffs — route "
                 "them to a decode/both replica")
+        if self.slab is not None:
+            raise MultiGroupUnsupported(
+                "handoff to a model with recurrent state: pages carry no "
+                "state")
         payload = h["payload"]
         if payload is not None:
             self.pool.check_layout(payload["layout"], what="handoff")
@@ -2008,6 +2190,12 @@ class ServingEngine:
                                ("handoff_bytes", "handoff_bytes"),
                                ("handoff_faults", "handoff_faults")):
             m[name].set_total(s[stat_key])
+        if self.slab is not None:
+            for key in ("ssm_lane_steps", "ssm_live_lane_steps",
+                        "ssm_scan_rows", "ssm_scan_row_passes",
+                        "state_resets"):
+                m[key].set_total(s[key])
+            m["state_slab_bytes"].set(s["state_slab_bytes"])
         m["handoff_inbox"].set(len(self._handoff_in))
         m["alloc_calls"].set_total(self.pool.alloc_calls)
         m["alloc_failures"].set_total(self.pool.alloc_failures)
@@ -2114,6 +2302,12 @@ class ServingEngine:
             self.stats["decode_pages_walked"] += n_layers * int((hi - lo).sum())
         self.stats["decode_pages_in_table"] += (
             len(seen) * self.max_slots * self.max_pages * len(self.layers))
+        if self.slab is not None:
+            # the kernel walks the live lanes alone; the jnp path all
+            walked = len(run) if self._use_ssm_kernel else self.max_slots
+            self.stats["ssm_lane_steps"] += walked * self.slab.num_layers
+            self.stats["ssm_live_lane_steps"] += (
+                len(run) * self.slab.num_layers)
 
     def _decode_step(self, finished: List[FinishedRequest]) -> None:
         if self.spec_k:
@@ -2218,6 +2412,8 @@ class ServingEngine:
                 # and its carry token is the last sampled one
                 self._tok[idx] = int(toks_all[consumed - 1, idx])
                 self._len[idx] += consumed
+                if self.slab is not None:
+                    self.slab.advanced[idx] += consumed
                 self._recycle_window_pages(idx)
 
     def _spec_decode_step(self, finished: List[FinishedRequest]) -> None:
@@ -2318,6 +2514,11 @@ class ServingEngine:
                 self._len[idx] += n_new
                 self._recycle_window_pages(idx)
 
+    def _next_positions(self) -> Dict[int, int]:
+        """Occupied slot -> the next position it writes."""
+        return {i: (int(self._len[i]) if s.started else s.prefilled)
+                for i, s in enumerate(self._slots) if s is not None}
+
     def check_invariants(self) -> None:
         """Page-leak / refcount / scheduler-consistency audit.  The pool's
         internal bookkeeping must balance, the refcount total must equal
@@ -2330,9 +2531,11 @@ class ServingEngine:
         if self.ring is not None:
             # the window group: no slot over its ring, no live page outside
             # it, none given away while a later query still sees it
-            self.ring.check({
-                i: (int(self._len[i]) if s.started else s.prefilled)
-                for i, s in enumerate(self._slots) if s is not None})
+            self.ring.check(self._next_positions())
+        if self.slab is not None:
+            # the state group: each occupied slot's state has folded in
+            # exactly the positions before its next one, since its reset
+            self.slab.check(self._next_positions())
         refs = sum(len(s.pages) for s in self._slots if s is not None)
         held = sum(self.pool.refcount)
         if held != refs:

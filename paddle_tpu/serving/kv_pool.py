@@ -428,3 +428,62 @@ class WindowRing:
                 raise AssertionError(
                     f"slot {slot}: a query at {pos} still sees pages "
                     f"outside the live range [{lo}, {hi})")
+
+
+class StateSlab:
+    """The state group of a model with recurrent layers: a fixed-size state
+    a slot a layer, no pages and no allocator.
+
+    Where the page groups keep ``page_size`` positions a page, a
+    state-space mixer (``models/ssm.py``) keeps, whatever the context's
+    length, the state ``(n_heads, head_dim, d_state)`` in ``state_dtype``
+    and the convolution's tail, the last ``d_conv - 1`` rows of its input.
+    Buffers: ``ssm`` ``(L, slots, H, P, N)`` and ``conv`` ``(L, slots,
+    (d_conv - 1) * conv_dim)``, a slot's tail rows one after another (a
+    trailing dim of 3 would be tiled out to 128 lanes, and a fourth dim
+    lets the compiler pick a layout a program and re-lay the slab out).
+    Like :class:`WindowRing` the group
+    is sized from ``max_slots`` alone and can never be why an admission
+    fails.
+
+    State cannot be sliced by position, so what the engine does to it is
+    coarser than what it does to pages: it is ZEROED when a request takes
+    the slot (a first admission or a recompute after preemption alike:
+    nothing is saved), advanced in place by the chunk and decode programs
+    over valid rows and live lanes only, and left as it lies when the slot
+    is released: the next tenant's reset is what keeps it from leaking.
+    ``advanced`` mirrors, on the host, how many positions each slot's
+    state has folded in since its reset; :meth:`check` holds it to the
+    slots' own positions."""
+
+    def __init__(self, num_layers: int, max_slots: int, spec,
+                 conv_dtype=jnp.float32):
+        self.num_layers, self.max_slots, self.spec = (num_layers, max_slots,
+                                                      spec)
+        self.buffers: Dict[str, jnp.ndarray] = {
+            "ssm": jnp.zeros((num_layers, max_slots, spec.n_heads,
+                              spec.head_dim, spec.d_state),
+                             jnp.dtype(spec.state_dtype)),
+            "conv": jnp.zeros((num_layers, max_slots,
+                               (spec.d_conv - 1) * spec.conv_dim),
+                              conv_dtype)}
+        self.advanced = np.zeros((max_slots,), np.int64)
+
+    def release(self, slot: int) -> None:
+        """The slot's state counts for nothing from here on: its tenant
+        left, or the engine has just zeroed the device rows for the next."""
+        self.advanced[slot] = 0
+
+    def hbm_bytes(self) -> int:
+        return sum(b.size * b.dtype.itemsize for b in self.buffers.values())
+
+    def check(self, next_pos: Dict[int, int]) -> None:
+        """``next_pos``: occupied slot -> the next position it writes.  An
+        occupied slot's state has folded in exactly the positions before
+        it; an empty slot's mirror is clear."""
+        for slot in range(self.max_slots):
+            have, want = int(self.advanced[slot]), next_pos.get(slot, 0)
+            if have != want:
+                raise AssertionError(
+                    f"slot {slot}: recurrent state advanced over {have} "
+                    f"positions since its reset, the slot stands at {want}")

@@ -510,10 +510,15 @@ def test_paged_prefill_block_follows_the_shapes():
     # 128 query heads over 8 KV heads: one KV head's 16 at a time
     assert pp.block_heads(128, 64, 128, 128, 8) == 16
     assert pp.supported(128, 64, 128, 128, n_kv_heads=8)
-    # a group that is not sublane-aligned stays whole, if it fits
+    # a group that is not sublane-aligned is padded up to one that is, a
+    # KV head at a time as well (Falcon-H1: 20 heads over 4, 5 -> 8)
+    assert pp.block_heads(20, 64, 128, 128, 4) == 8
     assert pp.block_heads(8, 64, 128, 128, 2) == 8
-    assert pp.block_heads(128, 64, 128, 128, 32) is None
-    assert not pp.supported(128, 64, 128, 128, n_kv_heads=32)
+    assert pp.block_heads(128, 64, 128, 128, 32) == 8
+    assert pp.supported(128, 64, 128, 128, n_kv_heads=32)
+    # MHA too wide for the budget has no block: the jnp path
+    assert pp.block_heads(128, 64, 128, 128, 128) is None
+    assert not pp.supported(128, 64, 128, 128, n_kv_heads=128)
 
 
 def test_paged_prefill_kernel_matches_ref_int8():

@@ -313,3 +313,110 @@ def test_expert_operations_carry_their_scope(monkeypatch, one_chip, program):
                 and rows in dims[1:]:
             products[dims[0]] += 1
     assert all(n >= _LAYERS for n in products.values()), products
+
+
+# ---------------------------------------------------------------------------
+# recurrent state beside the pages (ISSUE 37)
+# ---------------------------------------------------------------------------
+
+def _state_engine(monkeypatch):
+    """An engine over Falcon-H1 as the benchmark's cell serves it (its
+    configuration file: published widths, 5 layers, 96 slots), described by
+    shapes alone: 20 query heads over 4 KV heads (a GQA group of 5), a
+    state slab of 2 GB."""
+    import json
+
+    from benchmarks import weights_falcon_h1
+    from benchmarks.jobs import serve_falcon_h1
+    from paddle_tpu.kernels import paged_attention as pa
+    from paddle_tpu.kernels import paged_prefill as pp
+    from paddle_tpu.kernels import ssd
+    from paddle_tpu.models import FalconH1ForCausalLM
+    from paddle_tpu.serving import ServingEngine
+
+    for mod in (pa, pp, ssd):
+        monkeypatch.setattr(mod, "_backend_is_tpu", lambda: True)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs",
+                           "falcon-h1-34b-5l.json")) as f:
+        config = json.load(f)
+    sz = weights_falcon_h1.sizes(config)
+    cfg = serve_falcon_h1.model_config(sz, config)
+
+    class ShapesOnly:
+        layer_specs = FalconH1ForCausalLM.layer_specs
+
+        def __init__(self):
+            self.cfg = cfg
+
+        def decoder_params(self):
+            def leaf(name, shape):
+                return jax.ShapeDtypeStruct(
+                    shape, jnp.float32 if name in
+                    weights_falcon_h1.F32_LEAVES else jnp.bfloat16)
+
+            table = (sz["vocab"], sz["hidden"])
+            return {"wte": leaf("wte", table), "lm_head": leaf("lm_head", table),
+                    "lnf_g": leaf("lnf_g", (sz["hidden"],)),
+                    "blocks": [{n: leaf(n, s) for n, (s, _) in
+                                weights_falcon_h1.leaf_shapes(sz).items()}
+                               for _ in range(sz["layers"])]}
+
+    eng = ServingEngine(ShapesOnly(), **config["engine"])
+    assert eng.slab is not None and eng.ring is None
+    assert eng.attention_paths() == dict.fromkeys(
+        ("decode", "prefill", "ssm_step", "ssm_scan"), "kernel")
+    return eng
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "prefill_8"])
+def test_state_program_keeps_pages_and_slab_in_place(monkeypatch, one_chip,
+                                                     program):
+    """The cell's own programs compile for the chip with all four paths as
+    kernels (the paged ones at a GQA group of 5), fit its memory, and
+    advance the slab where it lies: no copy, slice or re-layout the size of
+    one layer's state (the kernels index the donated slab through scalar
+    prefetch and return it aliased), temporaries far under it."""
+    eng = _state_engine(monkeypatch)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params, bufs, key = jax.tree_util.tree_map(
+        on_chip, (eng.params, eng._device_pool(), eng._key))
+    s, mp = eng.max_slots, eng.max_pages
+    rows = {"decode": s, "prefill": eng.chunk_tokens, "prefill_8": 8}[program]
+    if program == "decode":
+        fn, args = eng._decode_fn, (ints(s), ints(s), ints(s, mp), ints(s),
+                                    key)
+    else:
+        fn, args = eng._prefill_fn, (ints(rows), ints(), ints(), ints(mp),
+                                     ints(), key, ints())
+    compiled = fn.lower(params, bufs, *args).compile()
+    text = compiled.as_text()
+    layers = len(eng.layers)
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          text)) == 2 * layers
+    ssm = eng.slab.buffers["ssm"]
+    layer = int(np.prod(ssm.shape[1:])) * ssm.dtype.itemsize
+    assert layer > 128 << 20
+    moved = []
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(3) in _MOVES + ("transpose",):
+            dtype, dims, op = m.groups()
+            n = _BYTES.get(dtype, 4) * int(np.prod(
+                [int(d) for d in dims.split(",") if d] or [1]))
+            # float32 is the slab's type alone (weights are prefetched
+            # whole, in bfloat16, and are no move of the slab)
+            if dtype == "f32" and n >= layer // 8:
+                moved.append(f"{op} {dtype}[{dims}]")
+    assert not moved, f"slab-sized moves: {sorted(set(moved))}"
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer // 4, mem.temp_size_in_bytes
+    # weights, pages and slab, once: what the chip has to hold (16 GB)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    assert mem.alias_size_in_bytes >= eng.slab.hbm_bytes()
