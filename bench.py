@@ -197,9 +197,8 @@ def main():
             hidden=1536, layers=24, heads=12, vocab=50304, n_requests=32,
             max_slots=16, page_size=64, prompt_len=96, new_tokens=96,
             dtype="bfloat16", kv_group=4, window=64, decode_block=8)
-        # disaggregated 2-replica cluster vs the monolith, plus the
-        # double-buffered dispatch overlap (ISSUE r15 acceptance: >= 1.7x
-        # aggregate goodput with p99 TTFT no worse)
+        # disaggregated 2-replica cluster vs the monolith (ISSUE r15
+        # acceptance: >= 1.7x aggregate goodput with p99 TTFT no worse)
         serving_disagg = _disagg_serving_bench(
             hidden=1536, layers=24, heads=12, vocab=50304, n_requests=48,
             max_slots=8, page_size=64, prompt_len=96, shared_len=64,
@@ -420,7 +419,7 @@ def _reset_mirrored_stats(eng):
     mirrors via set_total, so a registry attached post-warmup — or per
     bench leg on a reused engine — reports THAT window's counts only."""
     for k in ("tokens_generated", "prefill_calls", "decode_calls",
-              "preemptions", "recompute_tokens", "step_faults",
+              "decode_ahead", "decode_sync_first", "preemptions", "recompute_tokens", "step_faults",
               "prefix_hit_tokens", "prompt_tokens",
               "spec_drafted", "spec_accepted", "spec_rejected"):
         eng.stats[k] = 0
@@ -1166,16 +1165,11 @@ def _disagg_serving_bench(hidden=1536, layers=24, heads=12, vocab=50304,
     [new_tokens/2, new_tokens], arrivals at ``overload_factor`` x the
     single engine's measured burst capacity, first ``shared_len`` tokens
     shared so the router's prefix probe has something to hit) runs
-    through three serving topologies with the same weights and greedy
+    through two serving topologies with the same weights and greedy
     sampling:
 
       * **single**: one ``ServingEngine(role="both")`` — the r08-r14
         monolith, the baseline every prior bench measured;
-      * **single_db**: the same engine with ``double_buffer=True`` —
-        step N+1 is scheduled on host while step N's decode dispatch
-        runs on device, so the reported ``decode_sync_s`` (host time
-        blocked in ``jax.block_until_ready``) is the direct measure of
-        the recovered overlap;
       * **cluster2**: ``make_cluster(n=2, disaggregate=True)`` — a
         prefill replica and a decode replica behind the cache- and
         load-aware Router, every request crossing the boundary through
@@ -1183,13 +1177,16 @@ def _disagg_serving_bench(hidden=1536, layers=24, heads=12, vocab=50304,
 
     Reported per leg: aggregate goodput tokens/s of COMPLETED requests,
     p99 TTFT (arrival -> first streamed token, through the on_token
-    hook), makespan; for the cluster additionally the router's routing
+    hook), makespan; for the single engine its ``decode_sync_s`` (host
+    time blocked in ``jax.block_until_ready``: the engine dispatches step
+    N+1 before it reads step N, so this is what the overlap left) and the
+    share of decode dispatches made ahead of the read; for the cluster
+    additionally the router's routing
     counters (per-replica spread, prefix hit-rate over admissions) and
     the handoff ledger (records, bytes, degraded).  BENCH acceptance
     (tests/test_bench_extras.py): CPU smoke asserts shape + routing
     counters; the slow TPU leg asserts cluster goodput >= 1.7x single
-    with p99 TTFT no worse, and double buffering shrinking the sync
-    stall.
+    with p99 TTFT no worse.
     """
     import jax.numpy as jnp
     import paddle_tpu as paddle
@@ -1214,10 +1211,9 @@ def _disagg_serving_bench(hidden=1536, layers=24, heads=12, vocab=50304,
         0, vocab, (int(n) - shared_len,)).astype("int32")]) for n in plens]
     news = rng.randint(max(new_tokens // 2, 1), new_tokens + 1, n_requests)
 
-    def build_single(db=False):
+    def build_single():
         eng = ServingEngine(model, max_slots=max_slots, page_size=page_size,
-                            greedy=True, decode_block=decode_block,
-                            double_buffer=db)
+                            greedy=True, decode_block=decode_block)
         eng.add_request(prompts[0], 2)      # compile prefill + decode
         eng.run()
         _reset_mirrored_stats(eng)
@@ -1283,10 +1279,9 @@ def _disagg_serving_bench(hidden=1536, layers=24, heads=12, vocab=50304,
     # -- phase 2: the SAME Poisson trace through all three topologies ----
     single = drive(eng_single, arrivals)          # drained: reusable
     single["decode_sync_s"] = round(eng_single.stats["decode_sync_s"], 4)
-
-    eng_db = build_single(db=True)
-    single_db = drive(eng_db, arrivals)
-    single_db["decode_sync_s"] = round(eng_db.stats["decode_sync_s"], 4)
+    single["decode_ahead_share"] = round(
+        eng_single.stats["decode_ahead"]
+        / max(eng_single.stats["decode_calls"], 1), 4)
 
     router = build_cluster()
     cluster = drive(router, arrivals)
@@ -1312,14 +1307,10 @@ def _disagg_serving_bench(hidden=1536, layers=24, heads=12, vocab=50304,
 
     return {
         "single": single,
-        "single_db": single_db,
         "cluster2": cluster,
         "speedup_cluster_vs_single": round(
             cluster["goodput_tokens_per_sec"]
             / max(single["goodput_tokens_per_sec"], 1e-9), 3),
-        "decode_sync_ratio_db_vs_off": round(
-            single_db["decode_sync_s"]
-            / max(single["decode_sync_s"], 1e-9), 3),
         "config": {"hidden": hidden, "layers": layers, "heads": heads,
                    "vocab": vocab, "n_requests": n_requests,
                    "max_slots": max_slots, "page_size": page_size,

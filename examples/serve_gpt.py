@@ -46,10 +46,6 @@ CPU-runnable out of the box (tiny config); flags scale it up::
         # through the v5 handoff and the summary prints the routing +
         # handoff ledger.  Composes with --http / --tenants (tenant
         # fairness is enforced CLUSTER-wide via the shared WFQ ledger)
-    python examples/serve_gpt.py --double-buffer
-        # r15: dispatch decode step N on device, schedule step N+1 on
-        # host, sync one step late — the summary prints the host time
-        # still blocked on the device
     python examples/serve_gpt.py --replicas 2 --disaggregate \\
             --metrics-dir /tmp/cluster_obs
         # r16: cluster-wide observability — per-replica metrics_r{i}.prom
@@ -147,10 +143,6 @@ def main():
                          "prefill and decode replicas; prefilled KV "
                          "pages cross the boundary via the page-payload "
                          "handoff (r15)")
-    ap.add_argument("--double-buffer", action="store_true",
-                    help="overlap host scheduling of step N+1 with the "
-                         "device running step N (sync one step late; "
-                         "excludes --speculate) (r15)")
     ap.add_argument("--debug", action="store_true",
                     help="with --http: expose the read-only /debug "
                          "surface (state + invariant verdicts, flight-"
@@ -200,8 +192,7 @@ def main():
                            prefix_cache=not args.no_prefix_cache,
                            greedy=args.top_p >= 1.0, top_p=args.top_p,
                            eos_token_id=args.eos, int8=args.int8,
-                           kv_bits=args.kv_bits,
-                           double_buffer=args.double_buffer)
+                           kv_bits=args.kv_bits)
     else:
         eng = ServingEngine(model, max_slots=args.slots,
                             page_size=args.page_size,
@@ -213,7 +204,6 @@ def main():
                             max_queue=args.max_queue, faults=faults,
                             tenants=tenants, spec_k=args.speculate,
                             kv_bits=args.kv_bits,
-                            double_buffer=args.double_buffer,
                             metrics=args.metrics_dir is not None,
                             trace=args.metrics_dir is not None)
     replicas = eng.replicas if cluster else [eng]
@@ -286,8 +276,7 @@ def main():
               f"{'cluster-wide WFQ' if tenants else 'FCFS'}")
     print(f"engine: slots={args.slots}/replica page_size={args.page_size} "
           f"pool={e0.pool.num_pages} pages "
-          f"({e0.pool.hbm_bytes() / 1e6:.1f} MB) int8={args.int8} "
-          f"double_buffer={args.double_buffer}")
+          f"({e0.pool.hbm_bytes() / 1e6:.1f} MB) int8={args.int8}")
     print(f"kv layout: {e0.pool.num_kv_heads}/{args.heads} kv heads, "
           f"kv_bits={e0.kv_bits or '-'} window={e0.window or '-'} -> "
           f"{e0.pool.bytes_per_token()} KV bytes/token")
@@ -348,10 +337,11 @@ def main():
               f"({rs['handoff_bytes'] / 1e6:.2f} MB page payloads, "
               f"{rs['degraded_handoffs']} degraded), "
               f"{rs['rejected']} rejected at the router")
-    if args.double_buffer:
-        print(f"double buffering: {s['decode_sync_s'] * 1e3:.1f}ms host "
-              f"time blocked on device syncs across "
-              f"{s['decode_calls']} decode dispatches")
+    print(f"dispatch ahead: {s['decode_ahead']} of {s['decode_calls']} "
+          f"decode dispatches were made before the previous one was read "
+          f"({s['decode_sync_first']} had to retire it first); "
+          f"{s['decode_sync_s'] * 1e3:.1f}ms host time blocked on device "
+          f"syncs")
     if args.speculate:
         acc = s["spec_accepted"] / max(s["spec_drafted"], 1)
         print(f"speculation (k={args.speculate}): {s['spec_drafted']} "

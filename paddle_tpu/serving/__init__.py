@@ -53,9 +53,13 @@ Composes four pieces:
     v5 page-payload handoffs, layout-guarded, adopted bit-exactly into
     the destination pool + prefix index), lifts WFQ virtual-token
     counters router-global
-    (:class:`~paddle_tpu.serving.tenancy.ClusterWFQState`), and
-    ``double_buffer=True`` overlaps host scheduling of step N+1 with
-    the device's step N (``make_cluster`` builds the whole fleet);
+    (:class:`~paddle_tpu.serving.tenancy.ClusterWFQState`;
+    ``make_cluster`` builds the whole fleet);
+  * a step that dispatches decode N+1 before it reads decode N: the carry
+    tokens stay on the device and every host read comes after the step's
+    last dispatch, so the host's turn overlaps the device's step (every
+    engine's one step path; ``stats["decode_ahead"]`` /
+    ``["decode_sync_first"]``; ``spec_k`` alone reads first, to draft);
   * cluster-wide observability (r16): replica-namespaced tracing with
     Chrome flow events stitching prefill export → router pump → decode
     ingest into ONE merged Perfetto timeline

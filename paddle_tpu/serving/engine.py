@@ -77,6 +77,22 @@ iteration-level scheduling (Orca) with block-table paging (vLLM),
 composed with the int8 W8A8 + int8-KV serving path from the dense
 decoder: the per-(layer, batch, head, position) scale layout carries
 over to per-page scales unchanged.
+
+The order of a step: every dispatch comes first, every host read of a
+device value after the last dispatch, and the decode that is read is the
+PREVIOUS step's.  Admit; the chunk dispatches; grow pages and dispatch
+decode N+1; then read decode N's tokens and retire them, then read the
+first tokens of the prompts this step completed.  The device holds decode
+N+1 while the host retires, returns from ``step()``, takes new requests
+and plans N+2.  What makes it possible: the carry tokens stay on the
+device (the decode program returns them, a completing chunk's sample is
+put into its lane there; the host's ``_tok`` is a mirror filled at
+retirement), and lengths, ring turns and state positions advance on the
+host at DISPATCH by a count that does not depend on the tokens.  Only an
+``eos`` is a surprise: its lane rides one more decode, whose token is
+dropped at retirement and whose row landed in pages the lane still owned
+(``_retire_decode``).  A preemption whose victim has an unread decode
+retires first; ``spec_k > 0`` reads before it drafts, every step.
 """
 
 from __future__ import annotations
@@ -222,6 +238,9 @@ class _Slot:
         # hw_pages keeps marking where the next growth appends
         self.hw_pages = len(pages)
         self.started = False          # first token sampled; decoding
+        # tokens sampled for this slot on the device and not read yet: a
+        # completed prompt's first token, the rows of a decode in flight
+        self.unread = 0
         # speculative draft buffer (r13): host-only, overwritten by every
         # spec step's fresh proposal — reconstructible from the request
         # history, so snapshots never capture it and a step fault between
@@ -302,10 +321,15 @@ class ServingEngine:
     prompt pages re-indexed for prefix reuse, zero recompute).
     :class:`~paddle_tpu.serving.router.Router` wires replicas together
     with cache-affinity routing and router-global WFQ.
-    ``double_buffer=True`` defers the decode sync one step so the host
-    schedules step N+1 while step N runs on device —
-    ``stats["decode_sync_s"]`` shows the overlap win; incompatible with
-    ``spec_k`` (drafting needs the retired history).
+
+    A step dispatches decode N+1 before it reads decode N (the module
+    docstring has the order): a request's tokens reach ``on_token`` and
+    ``step()``'s return one step after their dispatch, in order.
+    ``stats["decode_ahead"]`` counts the decode dispatches made while the
+    previous one was unread, ``stats["decode_sync_first"]`` those that
+    had to retire first (a preemption of a lane in flight, a snapshot);
+    with ``spec_k`` every step reads first, because drafting needs the
+    retired history.
     """
 
     def __init__(self, model, *, max_slots: int = 8, page_size: int = 32,
@@ -328,7 +352,7 @@ class ServingEngine:
                  spec_k: int = 0, spec_ngram: int = 3, drafter=None,
                  kv_bits: Optional[int] = None,
                  attn_window: Optional[int] = None,
-                 role: str = "both", double_buffer: bool = False):
+                 role: str = "both"):
         cfg = model.cfg
         self.cfg = cfg
         # r15 disaggregation: "prefill" engines run chunked prefill to
@@ -339,16 +363,6 @@ class ServingEngine:
             raise ValueError(
                 f"role must be 'prefill', 'decode' or 'both', got {role!r}")
         self.role = role
-        # r15 double-buffered dispatch: defer the decode sync one step —
-        # step N's dispatched program runs on device while the host
-        # admits/prefills step N+1; finishes deliver one step late,
-        # greedy outputs are schedule-invariant so parity holds.
-        self.double_buffer = bool(double_buffer)
-        if self.double_buffer and spec_k:
-            raise ValueError(
-                "double_buffer is incompatible with speculative decoding "
-                "(spec_k > 0): drafting reads the retired token history "
-                "the deferred sync has not produced yet")
         # decode_block > 1 fuses that many decode steps into ONE dispatched
         # lax.scan (multi-step scheduling): admission/finish granularity
         # coarsens to the block, but the host->device dispatch cost (not
@@ -429,7 +443,6 @@ class ServingEngine:
         if two_groups or self._ssm is not None:
             refused = {"role": role != "both", "spec_k": self.spec_k > 0,
                        "decode_block": self.decode_block > 1,
-                       "double_buffer": self.double_buffer,
                        "kv_bits": self.kv_bits is not None}
             if any(refused.values()):
                 raise MultiGroupUnsupported(
@@ -557,7 +570,7 @@ class ServingEngine:
             tenants=({t: dataclasses.asdict(c)
                       for t, c in normalize_tenants(tenants).items()}
                      if tenants else None),
-            role=role, double_buffer=self.double_buffer)
+            role=role)
 
         # host mirrors of the decode step's device operands
         self._tokens_this_step = 0
@@ -565,7 +578,12 @@ class ServingEngine:
         self._budget_chunks = 1
         self._phase_s: Dict[str, tuple] = {}
         self._slots: List[Optional[_Slot]] = [None] * max_slots
+        # the carry tokens live on the device (``_carry``: what the next
+        # decode embeds, lane by lane); ``_tok`` mirrors them as of the
+        # last retirement, for a handoff, a snapshot and the verify program
+        self._carry = jnp.zeros((max_slots,), jnp.int32)
         self._tok = np.zeros((max_slots,), np.int32)
+        # the next position each started slot writes: advanced at dispatch
         self._len = np.zeros((max_slots,), np.int32)
         self._table = np.zeros((max_slots, self.max_pages), np.int32)
         self._key = jax.random.PRNGKey(seed)
@@ -581,11 +599,21 @@ class ServingEngine:
         # hold no pool pages, so the leak audits are unaffected.
         self._handoff_out: List[dict] = []
         self._handoff_in: List[dict] = []
-        # r15 double-buffered dispatch: the un-retired decode future —
-        # ((slot, _Slot) pairs, remaining mirror, device tokens, t_dispatch)
+        # the decode dispatched and not read yet: ((slot, _Slot) pairs,
+        # the program's ``remaining``, device tokens, t_dispatch, its
+        # mark among the expert counts)
         self._inflight: Optional[tuple] = None
+        # completed prompts whose first token is not read yet:
+        # (slot, _Slot, device token, mark among the expert counts)
+        self._first_unread: List[tuple] = []
+        # a decode was retired ahead of its turn; the next dispatch counts
+        self._retired_early = False
         self.stats = {"prefill_calls": 0, "decode_calls": 0,
                       "prefill_traces": 0, "decode_traces": 0,
+                      # decode dispatches made while the previous decode
+                      # was unread, and those that had to retire it first
+                      # (one after idleness is neither)
+                      "decode_ahead": 0, "decode_sync_first": 0,
                       "tokens_generated": 0,
                       "prefix_hit_tokens": 0, "prompt_tokens": 0,
                       "pages_in_use": 0, "queue_depth": 0,
@@ -597,9 +625,7 @@ class ServingEngine:
                       "handoff_s": 0.0,
                       "last_admit_s": 0.0, "last_prefill_s": 0.0,
                       "last_decode_s": 0.0, "last_handoff_s": 0.0,
-                      # host time actually BLOCKED on the decode sync —
-                      # under double_buffer the overlap win shows up as
-                      # this staying far below the dispatch wall time
+                      # host time actually BLOCKED on the decode sync
                       "decode_sync_s": 0.0, "last_decode_sync_s": 0.0,
                       # ... and on a completed prompt's first token
                       "prefill_sync_s": 0.0,
@@ -642,8 +668,10 @@ class ServingEngine:
             self.stats.update(moe_assignments=0, moe_local_assignments=0,
                               moe_expert_tokens_max=0, moe_experts_active=0,
                               moe_layer_passes=0)
-        # (device counts, valid rows) of dispatches not yet synced on
-        self._moe_pending: List[tuple] = []
+        # (device counts, valid rows) of dispatches not yet synced on, in
+        # dispatch order, and how many were folded before them
+        self._moe_pending: collections.deque = collections.deque()
+        self._moe_folded = 0
         if self.ring is not None or self.slab is not None:
             # prefix_cache=True resolves to no index for two groups, and
             # for state (a page of a prefix has no state to go with it)
@@ -699,6 +727,7 @@ class ServingEngine:
         self._decode_fn = self._build_decode()
         self._prefill_fn = self._build_prefill()
         self._cow_fn = self._build_cow()
+        self._carry_put_fn = self._build_carry_put()
         self._verify_fn = self._build_verify() if self.spec_k else None
         self._state_reset_fn = (self._build_state_reset()
                                 if self.slab is not None else None)
@@ -964,6 +993,10 @@ class ServingEngine:
             return bufs, nxt, ((jnp.stack(counts),) if counts else ())
 
         def decode(p, bufs, toks, lengths, table, remaining, key):
+            """Returns the buffers, the sampled rows (k, S) and the carry
+            it leaves: each live lane's last sample, a dead lane's ``toks``
+            as it came.  The next decode takes that carry as it is, on the
+            device."""
             self.stats["decode_traces"] += 1  # python side effect: per trace
             bufs, state = self._take_state(bufs)
             bufs, table = self._enter(bufs, table)
@@ -972,7 +1005,8 @@ class ServingEngine:
                 bufs, nxt, extra = one_step(p, bufs, table, toks, lengths,
                                             active, key, state)
                 return (self._with_state(self._leave(bufs), state),
-                        nxt[None]) + extra                         # (1, S)
+                        nxt[None],                                 # (1, S)
+                        jnp.where(active, nxt, toks)) + extra
 
             def body(carry, i):
                 bufs, toks, lengths, remaining, key = carry
@@ -985,10 +1019,10 @@ class ServingEngine:
                 remaining = jnp.maximum(remaining - 1, 0)
                 return (bufs, toks, lengths, remaining, key), nxt
 
-            (bufs, _, _, _, _), toks_all = jax.lax.scan(
+            (bufs, toks, _, _, _), toks_all = jax.lax.scan(
                 body, (bufs, toks, lengths, remaining, key),
                 jnp.arange(k_steps))
-            return self._leave(bufs), toks_all                     # (k, S)
+            return self._leave(bufs), toks_all, toks               # (k, S)
 
         return jax.jit(decode, donate_argnums=(1,))
 
@@ -1101,6 +1135,15 @@ class ServingEngine:
 
         return jax.jit(cow, donate_argnums=(0,))
 
+    def _build_carry_put(self):
+        def carry_put(carry, slot, tok):
+            """One lane of the device's carry tokens takes ``tok``: the
+            first token a completing chunk sampled (a device value), or a
+            host value when a slot is adopted with its token."""
+            return carry.at[slot].set(tok)
+
+        return jax.jit(carry_put)
+
     def _build_state_reset(self):
         def reset(state, slot):
             """Zero one slot's recurrent state in every layer: what a
@@ -1186,11 +1229,13 @@ class ServingEngine:
     @property
     def has_work(self) -> bool:
         """Work THIS engine can advance by stepping: queue/slots,
-        undelivered terminals, queued handoff ingests, or an un-retired
-        double-buffered dispatch.  The handoff OUTBOX is deliberately
-        excluded — draining it is the router's job, not a step's."""
+        undelivered terminals, queued handoff ingests, or device tokens
+        not read yet (the decode in flight; first tokens a fault left
+        behind).  The handoff OUTBOX is deliberately excluded — draining
+        it is the router's job, not a step's."""
         return (self.scheduler.has_work or bool(self._pending)
-                or bool(self._handoff_in) or self._inflight is not None)
+                or bool(self._handoff_in) or self._inflight is not None
+                or bool(self._first_unread))
 
     def attention_paths(self) -> Dict[str, str]:
         """Which attention implementation each device program was built
@@ -1271,6 +1316,12 @@ class ServingEngine:
             "prefill_calls": c("serving_prefill_calls",
                                "chunk-prefill dispatches"),
             "decode_calls": c("serving_decode_calls", "decode dispatches"),
+            "decode_ahead": c("serving_decode_ahead",
+                              "decode dispatches made while the previous "
+                              "decode was unread"),
+            "decode_sync_first": c("serving_decode_sync_first",
+                                   "decode dispatches that had to retire "
+                                   "the previous decode first"),
             "preemptions": c("serving_preemptions",
                              "slots evicted for recompute"),
             "recompute": c("serving_recompute_tokens",
@@ -1349,7 +1400,7 @@ class ServingEngine:
                            "handoff export phase wall time"),
             "decode_sync": h("serving_decode_sync_s",
                              "host time blocked on the decode device "
-                             "sync (double buffering shrinks this)"),
+                             "sync"),
         }
         if self.slab is not None:
             # the state group's series, beside the KV pool's; a model
@@ -1706,9 +1757,14 @@ class ServingEngine:
         at most ``chunk_tokens`` of one slot's work prompt (prompt + any
         preemption-survived tokens), dispatched one after another without
         a sync between them.  A slot whose prompt completes samples its
-        next token and joins this step's decode batch."""
+        next token and joins this step's decode batch: the token goes into
+        the slot's lane of the device's carry, and is read and delivered
+        after the decode's dispatch (``_deliver_first_tokens``)."""
+        # the lanes this step's decode will hold: a lane that reaches its
+        # length with its unread tokens takes none of the budget
         n_decoding = sum(1 for s in self._slots
-                         if s is not None and s.started)
+                         if s is not None and s.started
+                         and s.request.remaining_new > s.unread)
         partial = sorted(
             (i for i, s in enumerate(self._slots)
              if s is not None and not s.started),
@@ -1748,7 +1804,7 @@ class ServingEngine:
                         self._next_key(),
                         *(() if self.slab is None else (jnp.int32(idx),)))
                     self._store_pool(bufs)
-                    self._moe_pending += [(c, n) for c in counts]
+                    self._moe_pending.extend((c, n) for c in counts)
                 if self.metrics is not None:
                     self._m["chunk_s"].observe(sp.dur)
                 if self.tracer is not None:
@@ -1784,49 +1840,70 @@ class ServingEngine:
                     else:
                         nfull = st.base_len // self.page_size
                         self.pool.prefix.insert(work, st.pages[:nfull])
-                with self._span("engine.first_token_sync",
-                                rid=req.rid) as sp:
-                    tok = int(tok)
-                self.stats["prefill_sync_s"] += sp.dur
-                self._fold_moe_counts()
-                st.tokens.append(tok)
-                self._emit_token(req, tok)
-                self._charge_service(req)
-                self.stats["tokens_generated"] += 1
-                now = self._now()
-                if req.t_first_token is None:
-                    self.stats["first_tokens"] += 1
-                    self.stats["prefill_wait_s"] += now - req.t_admitted
-                    if self.metrics is not None:
-                        self._m["ttft"].observe(now - req.t_enqueue)
-                    if self.tracer is not None:
-                        self.tracer.instant("first_token", self._pid_req,
-                                            req.rid)
-                    req.t_first_token = now
-                elif self.metrics is not None and req.t_last_token is not None:
-                    # a recomputed request's first post-readmission token:
-                    # the gap since its last delivered token is real
-                    # user-visible inter-token stall
-                    self._m["tbt"].observe(now - req.t_last_token)
-                req.t_last_token = now
-                self._tok[idx] = tok
+                self._carry = self._carry_put_fn(self._carry,
+                                                 jnp.int32(idx), tok)
                 self._len[idx] = st.base_len
-                if (self.eos_token_id is not None
-                        and tok == self.eos_token_id):
-                    finished.append(self._finish(idx, "eos"))
-                elif len(st.tokens) >= st.request.max_new_tokens:
-                    finished.append(self._finish(idx, "length"))
+                st.unread = 1
+                self._first_unread.append((idx, st, tok, self._moe_mark()))
             if budget <= 0:
                 break
 
-    def _grow_pages(self, idx: int, consumed: int) -> bool:
+    def _deliver_first_tokens(self, finished: List[FinishedRequest]) -> None:
+        """Read the first tokens of the prompts completed since the last
+        call, and deliver them: after the step's last dispatch (in the
+        prefill phase where the engine never decodes; before drafting,
+        which reads them).  A slot that went away meanwhile (cancelled,
+        expired, preempted: its recompute samples the token again) left
+        a dead token: not read."""
+        unread, self._first_unread = self._first_unread, []
+        for idx, st, tok, mark in unread:
+            if self._slots[idx] is not st:
+                continue
+            req = st.request
+            with self._span("engine.first_token_sync", rid=req.rid) as sp:
+                tok = int(tok)
+            self.stats["prefill_sync_s"] += sp.dur
+            self._fold_moe_counts(mark)
+            st.unread -= 1
+            st.tokens.append(tok)
+            self._emit_token(req, tok)
+            self._charge_service(req)
+            self.stats["tokens_generated"] += 1
+            now = self._now()
+            if req.t_first_token is None:
+                self.stats["first_tokens"] += 1
+                self.stats["prefill_wait_s"] += now - req.t_admitted
+                if self.metrics is not None:
+                    self._m["ttft"].observe(now - req.t_enqueue)
+                if self.tracer is not None:
+                    self.tracer.instant("first_token", self._pid_req,
+                                        req.rid)
+                req.t_first_token = now
+            elif self.metrics is not None and req.t_last_token is not None:
+                # a recomputed request's first post-readmission token:
+                # the gap since its last delivered token is real
+                # user-visible inter-token stall
+                self._m["tbt"].observe(now - req.t_last_token)
+            req.t_last_token = now
+            self._tok[idx] = tok
+            if self.eos_token_id is not None and tok == self.eos_token_id:
+                finished.append(self._finish(idx, "eos"))
+            elif len(st.tokens) >= req.max_new_tokens:
+                finished.append(self._finish(idx, "length"))
+
+    def _grow_pages(self, idx: int, consumed: int,
+                    finished: List[FinishedRequest]) -> bool:
         """Ensure slot ``idx`` owns every page its next ``consumed``
         decode writes need (positions ``len .. len+consumed-1``) —
         on-demand growth, one admission no longer pays max_new_tokens
         upfront.  On allocation failure, preempt the youngest occupied
-        slot and retry; never the oldest.  Returns True when the slot can
-        decode this step (False: it was preempted itself, or stalled
-        because no victim remains — retried next step)."""
+        slot and retry; never the oldest.  A victim with a decode in
+        flight has tokens the host has not read: the decode is retired
+        first (into ``finished``), so that the victim's recompute prompt
+        lacks none, and the next dispatch counts as ``decode_sync_first``.
+        Returns True when the slot can decode this step (False: it was
+        preempted itself, finished in that retirement, or stalled because
+        no victim remains — retried next step)."""
         st = self._slots[idx]
         # grow from the HIGH-WATER page count, not len(pages): windowed
         # recycling shrinks the live page list but table positions keep
@@ -1855,6 +1932,12 @@ class ServingEngine:
             victim = self._pick_victim()
             if victim is None:
                 return False          # stalled; pool can't shrink further
+            if self._inflight is not None and any(
+                    s is self._slots[victim] for _, s in self._inflight[0]):
+                self._retire_decode(finished, early=True)
+                if self._slots[idx] is not st:
+                    return False      # the grower's last token was in it
+                continue              # what finished may have freed enough
             self._preempt(victim)
             if victim == idx:
                 return False          # the grower was the youngest itself
@@ -2069,6 +2152,8 @@ class ServingEngine:
         # carry token is the last sampled one, the device length is the
         # work-prompt length whose K/V the pages hold
         self._tok[slot] = req.generated[-1]
+        self._carry = self._carry_put_fn(self._carry, jnp.int32(slot),
+                                         jnp.int32(req.generated[-1]))
         self._len[slot] = base_len
         # adopt the full prompt pages into THIS pool's prefix index —
         # same insert (and same windowed refusal) as local prefill; the
@@ -2096,10 +2181,12 @@ class ServingEngine:
     def step(self) -> List[FinishedRequest]:
         """One engine iteration: expire deadlines, admit into freed
         slots, advance partial prefills by the chunk budget, grow decode
-        pages (preempting under pressure), then one decode step over
-        every started slot.  Returns every request that reached a
+        pages (preempting under pressure), dispatch one decode step over
+        every started slot, THEN read the previous step's decode and this
+        step's first tokens.  Returns every request that reached a
         terminal state this step (including rejects/cancels recorded
-        since the last step).  Injected faults abort the remainder of the
+        since the last step): a decode's finishes one step after its
+        dispatch.  Injected faults abort the remainder of the
         iteration at a phase boundary; the next step resumes."""
         t0 = time.perf_counter()
         self._step_idx += 1
@@ -2177,6 +2264,8 @@ class ServingEngine:
         for stat_key, name in (("tokens_generated", "tokens"),
                                ("prefill_calls", "prefill_calls"),
                                ("decode_calls", "decode_calls"),
+                               ("decode_ahead", "decode_ahead"),
+                               ("decode_sync_first", "decode_sync_first"),
                                ("preemptions", "preemptions"),
                                ("recompute_tokens", "recompute"),
                                ("prefix_hit_tokens", "prefix_hit"),
@@ -2237,6 +2326,8 @@ class ServingEngine:
             self._fault_point("admit")
         with self._span("engine.prefill"):
             self._prefill_chunks(finished)
+            if self.role == "prefill":
+                self._deliver_first_tokens(finished)
             self._fault_point("prefill")
 
         if self.role == "prefill":
@@ -2252,13 +2343,19 @@ class ServingEngine:
             self._decode_step(finished)
             self._fault_point("decode")
 
-    def _fold_moe_counts(self) -> None:
-        """Add the expert-routing counts of the dispatches synced on so far
-        to the stats.  Called right after a sync the step makes anyway (a
-        first token, a decode's tokens): the programs that produced the
-        counts ran before the one just waited for, so reading them waits
-        for nothing."""
-        for counts, rows in self._moe_pending:
+    def _moe_mark(self) -> int:
+        """How many expert-count entries were dispatched so far."""
+        return self._moe_folded + len(self._moe_pending)
+
+    def _fold_moe_counts(self, mark: int) -> None:
+        """Add to the stats the expert-routing counts of the dispatches up
+        to ``mark`` (``_moe_mark`` as it stood when a program was
+        dispatched).  Called right after the sync on that program's token:
+        the programs before it ran before it, so reading their counts waits
+        for nothing, and the decode dispatched since is left alone."""
+        while self._moe_folded < mark:
+            counts, rows = self._moe_pending.popleft()
+            self._moe_folded += 1
             per_layer = np.asarray(counts)                # (layers, held)
             st = self.stats
             st["moe_assignments"] += rows * self._moe.top_k * len(per_layer)
@@ -2266,7 +2363,6 @@ class ServingEngine:
             st["moe_expert_tokens_max"] += int(per_layer.max(axis=1).sum())
             st["moe_experts_active"] += int((per_layer > 0).sum())
             st["moe_layer_passes"] += len(per_layer)
-        self._moe_pending.clear()
 
     def _note_prefill_dispatch(self, start: int, width: int) -> None:
         """Counters of one chunk dispatch: ``width`` rows (the bucket's, as
@@ -2310,79 +2406,122 @@ class ServingEngine:
                 len(run) * self.slab.num_layers)
 
     def _decode_step(self, finished: List[FinishedRequest]) -> None:
+        """Dispatch this step's decode, THEN read the previous step's and
+        the first tokens of this step's completed prompts (the module
+        docstring has the order and why it is safe)."""
         if self.spec_k:
+            # drafting reads the retired history: a synchronous step
+            self._deliver_first_tokens(finished)
             return self._spec_decode_step(finished)
-        # retire LAST step's dispatched decode FIRST (double-buffer mode
-        # leaves it un-synced so admit/prefill overlap the device): its
-        # finishes free pages before this step's growth asks for them,
-        # and growth can therefore never preempt an un-retired slot
-        if self._inflight is not None:
-            self._retire_decode(finished)
         # decode-page growth, oldest first so preemption victims are
-        # always younger than the grower
+        # always younger than the grower.  A lane's budget counts the
+        # tokens sampled for it and not read yet: one that reaches its
+        # length with them is left out and finishes when they are retired
         order = sorted((i for i, s in enumerate(self._slots)
                         if s is not None and s.started),
                        key=lambda i: self._slots[i].seq)
         run: List[int] = []
         for idx in order:
-            if self._slots[idx] is None:      # preempted by an earlier grow
-                continue
             st = self._slots[idx]
-            consumed = min(self.decode_block, st.request.remaining_new)
-            if self._grow_pages(idx, consumed):
+            if st is None:                    # preempted by an earlier grow
+                continue
+            left = st.request.remaining_new - st.unread
+            if left > 0 and self._grow_pages(
+                    idx, min(self.decode_block, left), finished):
                 run.append(idx)
+        # a growth that retired the decode in flight may have finished a
+        # lane picked before it
+        run = [idx for idx in run if self._slots[idx] is not None]
+        flight = None
         if run:
             remaining = np.zeros((self.max_slots,), np.int32)
             for idx in run:
-                remaining[idx] = self._slots[idx].request.remaining_new
+                st = self._slots[idx]
+                remaining[idx] = st.request.remaining_new - st.unread
                 if self.ring is not None:
                     self.ring.advance(idx, int(self._len[idx]),
                                       int(self._len[idx]) + 1)
             with self._span("engine.decode_dispatch", slots=len(run)) as sp:
-                bufs, toks_all, *counts = self._decode_fn(
-                    self.params, self._device_pool(), jnp.asarray(self._tok),
-                    jnp.asarray(self._len), self._device_tables(),
+                # ``_len`` goes as a copy, like the tables: it advances
+                # below while the program may not have read it yet
+                bufs, toks_all, self._carry, *counts = self._decode_fn(
+                    self.params, self._device_pool(), self._carry,
+                    jnp.asarray(self._len.copy()), self._device_tables(),
                     jnp.asarray(remaining), self._next_key())
                 self._store_pool(bufs)
-                self._moe_pending += [(c, len(run)) for c in counts]
+                self._moe_pending.extend((c, len(run)) for c in counts)
             self._note_decode_dispatch(run, remaining)
-            # stash the DISPATCHED call without syncing; slot objects ride
-            # along so retirement can detect cancel/expire/slot-reuse
-            self._inflight = ([(idx, self._slots[idx]) for idx in run],
-                              remaining, toks_all, sp.t0)
-            if not self.double_buffer:
-                self._retire_decode(finished)
+            if self._inflight is not None:
+                self.stats["decode_ahead"] += 1
+            elif self._retired_early:
+                self.stats["decode_sync_first"] += 1
+            self._retired_early = False
+            # the host's positions follow the dispatch, not the tokens: the
+            # program consumes a count known here, whatever it samples
+            for idx in run:
+                n = int(min(self.decode_block, remaining[idx]))
+                self._slots[idx].unread += n
+                self._len[idx] += n
+                if self.slab is not None:
+                    self.slab.advanced[idx] += n
+                self._recycle_window_pages(idx)
+            # slot objects ride along so retirement can detect
+            # cancel/expire/slot-reuse
+            flight = ([(idx, self._slots[idx]) for idx in run], remaining,
+                      toks_all, sp.t0, self._moe_mark())
+        if self._inflight is not None:
+            self._retire_decode(finished)
+        self._inflight = flight
+        self._deliver_first_tokens(finished)
 
-    def _retire_decode(self, finished: List[FinishedRequest]) -> None:
-        """Sync the stashed decode dispatch and apply its results: append
-        tokens, bill tenants, finish eos/length, mirror carry state.  In
-        double-buffer mode this runs one step LATE — the host scheduled
-        step N+1's admissions and prefill while step N's program ran on
-        device — so finishes surface a step later, which greedy outputs
-        (schedule-invariant per request) don't observe."""
-        entries, remaining, toks_all, t_c = self._inflight
+    def _retire_all(self, finished: List[FinishedRequest]) -> None:
+        """Read and apply every device token not read yet, ahead of its
+        turn: what a snapshot needs, which cannot carry a device value."""
+        if self._inflight is not None:
+            self._retire_decode(finished, early=True)
+        self._deliver_first_tokens(finished)
+
+    def _retire_decode(self, finished: List[FinishedRequest],
+                       early: bool = False) -> None:
+        """Sync the decode in flight and apply its results: append tokens,
+        bill tenants, finish eos/length, mirror the carry.  It runs one
+        step after the dispatch, behind the NEXT decode's dispatch, so
+        finishes surface a step later, which greedy outputs
+        (schedule-invariant per request) don't observe.  ``early``: ahead
+        of that turn (a preemption, a snapshot).
+
+        A lane may have left meanwhile (cancelled, expired, finished on an
+        ``eos`` that an earlier retirement or a first token brought while
+        this decode was already dispatched): its tokens are dropped.  The
+        row such a lane wrote went into pages it still owned at the
+        dispatch, at a position past its prompt's full pages (the prefix
+        index holds none of it); the pages were freed on the host after
+        that dispatch, and whatever a later owner runs on them is
+        dispatched later still.  The device runs programs in dispatch
+        order, so the stray row is written before the new owner's and
+        never after."""
+        entries, remaining, toks_all, t_c, mark = self._inflight
         self._inflight = None
+        self._retired_early = self._retired_early or early
         with self._span("engine.decode_sync") as sp:
             toks_all = np.asarray(jax.block_until_ready(toks_all))
         sync_s = sp.dur
-        self._fold_moe_counts()
+        self._fold_moe_counts(mark)
         self.stats["decode_sync_s"] += sync_s
         self.stats["last_decode_sync_s"] = sync_s
         if self.metrics is not None:
             # block_until_ready closed the dispatch, so this is the real
             # device step time, not the async hand-off; sync_s is the
-            # part the host actually WAITED — overlap makes it shrink
+            # part the host actually WAITED
             self._m["decode_call_s"].observe(time.perf_counter() - t_c)
             self._m["decode_sync"].observe(sync_s)
         now = self._now()
         for idx, st_dispatched in entries:
             st = self._slots[idx]
             if st is not st_dispatched:
-                # slot was cancelled/expired (or re-used by a fresh
-                # admission) between dispatch and retirement — its
-                # sampled tokens are dead, drop them on the floor
                 continue
             consumed = int(min(self.decode_block, remaining[idx]))
+            st.unread -= consumed
             reason = None
             n_new = 0
             req = st.request
@@ -2408,13 +2547,7 @@ class ServingEngine:
             if reason is not None:
                 finished.append(self._finish(idx, reason))
             else:
-                # mirror the DEVICE state: it advanced `consumed` steps
-                # and its carry token is the last sampled one
                 self._tok[idx] = int(toks_all[consumed - 1, idx])
-                self._len[idx] += consumed
-                if self.slab is not None:
-                    self.slab.advanced[idx] += consumed
-                self._recycle_window_pages(idx)
 
     def _spec_decode_step(self, finished: List[FinishedRequest]) -> None:
         """One speculative iteration over the started slots: draft from
@@ -2446,7 +2579,7 @@ class ServingEngine:
                 st.draft = [int(v) for v in prop[:cap]]
             else:
                 st.draft = []
-            if self._grow_pages(idx, len(st.draft) + 1):
+            if self._grow_pages(idx, len(st.draft) + 1, finished):
                 run.append(idx)
                 n_draft[idx] = len(st.draft)
                 if st.draft:

@@ -66,12 +66,13 @@ from .scheduler import Request
 #: snapshots default it to the live page count (exact: they predate
 #: recycling, so the two never diverged).
 #: r15 (disaggregation) rides on v5 with OPTIONAL keys: the config echo
-#: carries ``role``/``double_buffer`` (older snapshots restore as a
-#: monolithic synchronous engine), the engine section carries the
+#: carries ``role`` (older snapshots restore as a monolithic engine; a
+#: ``double_buffer`` key, from when dispatching ahead of the read was a
+#: mode, is ignored), the engine section carries the
 #: handoff inbox/outbox (absent = empty), and :func:`handoff_state`
 #: reuses the v5 pool-serialization shapes as the prefill→decode WIRE
-#: format — an in-flight double-buffered dispatch is retired before
-#: capture, so a snapshot never holds a live device future.
+#: format — the decode in flight and any unread first token are retired
+#: before capture, so a snapshot never holds a live device future.
 SNAPSHOT_VERSION = 5
 _READABLE_VERSIONS = (2, 3, 4, 5)
 
@@ -153,11 +154,10 @@ def handoff_state(eng, idx: int, with_payload: bool = True) -> dict:
 def snapshot_engine(eng) -> dict:
     """Capture ``eng`` (a :class:`~paddle_tpu.serving.engine.ServingEngine`)
     as a plain-python dict; see the module docstring for the contract."""
-    # double-buffered dispatch (r15): an un-retired decode future is
-    # device state a snapshot cannot carry — sync and process it first
-    # (its finishes land in _pending, delivered by the restored engine)
-    if getattr(eng, "_inflight", None) is not None:
-        eng._retire_decode(eng._pending)
+    # tokens the host has not read yet are device state a snapshot cannot
+    # carry — sync and process them first (their finishes land in
+    # _pending, delivered by the restored engine)
+    eng._retire_all(eng._pending)
     slots = []
     for st in eng._slots:
         if st is None:
@@ -232,6 +232,7 @@ def restore_engine(model, snap: dict, **overrides):
     if snap.get("version") not in _READABLE_VERSIONS:
         raise ValueError(f"unknown snapshot version {snap.get('version')!r}")
     cfg = dict(snap["config"])
+    cfg.pop("double_buffer", None)    # a mode once; the one step path now
     cfg.update(overrides)
     eng = ServingEngine(model, **cfg)
 
@@ -313,6 +314,7 @@ def restore_engine(model, snap: dict, **overrides):
     eng._admit_seq = es["admit_seq"]
     eng._key = jnp.asarray(es["key"])
     eng._tok = np.asarray(es["tok"], np.int32).copy()
+    eng._carry = jnp.asarray(eng._tok.copy())      # the device's copy of the carry
     eng._len = np.asarray(es["len"], np.int32).copy()
     eng._table = np.asarray(es["table"], np.int32).copy()
     eng.stats.update(es["stats"])

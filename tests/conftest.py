@@ -65,7 +65,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "disagg: disaggregated multi-replica serving (router, "
-        "prefill/decode handoff, cluster WFQ, double-buffered dispatch; "
+        "prefill/decode handoff, cluster WFQ, dispatch ahead of the read; "
         "tests/test_disagg.py) — CPU-runnable, included in tier-1")
     config.addinivalue_line(
         "markers",

@@ -374,11 +374,12 @@ def test_spec_serving_bench_tpu_scale():
 
 def test_disagg_serving_bench_smoke():
     """Fast CPU smoke of the disaggregated-serving bench (ISSUE r15):
-    all three topology legs complete the identical Poisson trace, the
+    both topology legs complete the identical Poisson trace, the
     router counters account for every request exactly once (all routed
     to the one prefill target, every one handed off with payload bytes,
     none degraded), the prefix probe hits the shared system prefix, and
-    the double-buffer leg reports its sync-stall ledger.  No perf
+    the single engine reports its sync-stall ledger and how often it
+    dispatched ahead of the read.  No perf
     assertion — CPU step timing is host-loop noise; the 1.7x bar lives
     in the slow TPU test below."""
     res = bench._disagg_serving_bench(hidden=64, layers=2, heads=2,
@@ -386,7 +387,7 @@ def test_disagg_serving_bench_smoke():
                                       page_size=8, prompt_len=16,
                                       shared_len=8, new_tokens=12,
                                       dtype="float32", decode_block=2)
-    for leg in ("single", "single_db", "cluster2"):
+    for leg in ("single", "cluster2"):
         assert res[leg]["goodput_tokens_per_sec"] > 0
         assert res[leg]["completed"] == 6
         assert res[leg]["p99_ttft_s"] is not None
@@ -404,10 +405,10 @@ def test_disagg_serving_bench_smoke():
     pre, dec = res["cluster2"]["per_replica"]
     assert pre["handoffs_out"] == 6 and pre["decode_calls"] == 0
     assert dec["handoffs_in"] == 6 and dec["prefill_calls"] == 0
-    # the sync-stall ledger exists and double buffering recorded one too
+    # the sync-stall ledger exists, and most decodes were dispatched
+    # while the one before them was still unread
     assert res["single"]["decode_sync_s"] > 0
-    assert res["single_db"]["decode_sync_s"] >= 0
-    assert res["decode_sync_ratio_db_vs_off"] >= 0
+    assert res["single"]["decode_ahead_share"] > 0.5
     assert res["config"]["arrival_rate_req_per_s"] > 0
 
 
@@ -416,8 +417,7 @@ def test_disagg_serving_bench_tpu_scale():
     """The flagship-sized disaggregation point bench.py records on TPU
     (marked slow).  The r15 acceptance bar lives here: the 2-replica
     disaggregated cluster serves >= 1.7x the monolith's aggregate
-    goodput with p99 TTFT no worse, and double-buffered dispatch
-    shrinks the host sync stall."""
+    goodput with p99 TTFT no worse."""
     res = bench._disagg_serving_bench(hidden=1536, layers=24, heads=12,
                                       vocab=50304, n_requests=48,
                                       max_slots=8, page_size=64,
@@ -427,4 +427,3 @@ def test_disagg_serving_bench_tpu_scale():
     assert res["speedup_cluster_vs_single"] >= 1.7, res
     assert res["cluster2"]["p99_ttft_s"] <= res["single"]["p99_ttft_s"], res
     assert res["cluster2"]["router"]["handoffs"] == 48, res
-    assert res["decode_sync_ratio_db_vs_off"] < 1.0, res

@@ -268,11 +268,24 @@ def test_preemption_under_a_small_full_group_is_exact(model):
 
 
 @pytest.mark.parametrize("kw", [
-    dict(spec_k=2), dict(decode_block=2), dict(double_buffer=True),
+    dict(spec_k=2), dict(decode_block=2),
     dict(role="prefill"), dict(kv_bits=8)], ids=lambda kw: next(iter(kw)))
 def test_what_two_page_groups_refuse_is_a_named_error(model, kw):
     with pytest.raises(MultiGroupUnsupported):
         ServingEngine(model, max_slots=2, page_size=8, max_seq_len=160, **kw)
+
+
+def test_two_page_groups_dispatch_ahead_of_the_read(model):
+    """What was a refused mode is the step: the ring turns from lengths the
+    host advances at dispatch, so decode N+1 goes out before N is read."""
+    eng = ServingEngine(model, max_slots=2, page_size=8, max_seq_len=160,
+                        chunk_tokens=16)
+    for p in _prompts(11, (30, 9)):
+        eng.add_request(p, 12)
+    eng.run()
+    s = eng.stats
+    assert s["decode_ahead"] == s["decode_calls"] - 1 > 0
+    assert s["decode_sync_first"] == 0 and s["window_pages_recycled"] > 0
 
 
 def test_snapshot_of_two_page_groups_is_refused(model):

@@ -16,8 +16,9 @@ Acceptance contracts, all CPU-runnable (``disagg`` marker):
   * seeded FaultPlans against every replica keep the r10 invariants
     across the replica boundary: every request exactly one terminal,
     leak-free drain on every replica (conftest audits every step);
-  * double-buffered dispatch is parity-exact (with and without
-    preemption/cancel) and snapshot/restore quiesces it.
+  * dispatching decode N+1 before decode N is read (every engine's step)
+    is parity-exact (with and without preemption/cancel) and
+    snapshot/restore quiesces it.
 """
 
 import numpy as np
@@ -32,7 +33,7 @@ from paddle_tpu.serving import (FaultPlan, Router, ServingEngine,
 pytestmark = pytest.mark.disagg
 
 # 1-layer models (r13 tier-1 budget precedent): routing, handoff,
-# fairness and double-buffer properties are layer-count-independent —
+# fairness and dispatch-ahead properties are layer-count-independent —
 # multi-layer paged-KV exactness lives in test_serving.py
 CFG = dict(vocab_size=512, hidden_size=64, num_layers=1, num_heads=2,
            max_seq_len=96, dropout=0.0)
@@ -154,8 +155,9 @@ def test_prefill_role_refuses_ingest_and_router_validates():
         Router([pre])
     with pytest.raises(ValueError, match="replica"):
         Router([])
-    with pytest.raises(ValueError, match="spec_k|speculative"):
-        ServingEngine(model, double_buffer=True, spec_k=2)
+    # dispatching ahead of the read is the step, not a mode: no keyword
+    with pytest.raises(TypeError, match="double_buffer"):
+        ServingEngine(model, double_buffer=True)
 
 
 # ---------------------------------------------------------------------------
@@ -218,33 +220,33 @@ def test_disagg_parity_under_pool_pressure_preemption():
     assert dec.stats["recompute_tokens"] > 0
 
 
-def test_double_buffer_parity_and_overlap_accounting():
-    """double_buffer=True defers the decode sync one step: outputs stay
-    token-for-token identical (schedule-invariant greedy), under pool
-    pressure too, and the sync-time ledger actually records."""
+def test_dispatch_ahead_parity_and_overlap_accounting():
+    """The decode sync comes one step after the dispatch: outputs stay
+    token-for-token the dense decoder's (schedule-invariant greedy),
+    under pool pressure too, and the sync-time ledger and the two
+    counters of the order actually record."""
     model = _model()
     rng = np.random.RandomState(3)
     prompts = _prompts(rng, [9, 14, 22])
     news = [14, 10, 8]
-    ref = ServingEngine(model, max_slots=2, page_size=8,
-                        num_pages=10).run(list(zip(prompts, news)))
-    eng = ServingEngine(model, max_slots=2, page_size=8, num_pages=10,
-                        double_buffer=True)
+    refs = _dense_refs(model, prompts, news)
+    eng = ServingEngine(model, max_slots=2, page_size=8, num_pages=10)
     out = eng.run(list(zip(prompts, news)))
-    for rid_ref, rid in zip(sorted(ref), sorted(out)):
-        np.testing.assert_array_equal(ref[rid_ref].tokens,
-                                      out[rid].tokens)
-    assert eng.stats["decode_sync_s"] > 0.0
+    for ref, rid in zip(refs, sorted(out)):
+        np.testing.assert_array_equal(ref, out[rid].tokens)
+    s = eng.stats
+    assert s["decode_sync_s"] > 0.0
+    assert s["decode_ahead"] > s["decode_calls"] // 2
+    assert s["decode_ahead"] + s["decode_sync_first"] < s["decode_calls"]
     assert eng._inflight is None and eng.pool.pages_in_use == 0
 
 
-def test_double_buffer_cancel_mid_flight_drops_dead_tokens():
+def test_cancel_mid_flight_drops_dead_tokens():
     """Cancelling a request whose decode dispatch is still in flight:
     retirement must skip the dead slot (identity check), deliver exactly
     one terminal, and leak nothing."""
     model = _model()
-    eng = ServingEngine(model, max_slots=2, page_size=8, num_pages=32,
-                        double_buffer=True)
+    eng = ServingEngine(model, max_slots=2, page_size=8, num_pages=32)
     ra = eng.add_request(np.arange(6, dtype=np.int32), 20)
     rb = eng.add_request(np.arange(3, 12, dtype=np.int32), 20)
     eng.step()                   # admit+prefill+dispatch, sync deferred

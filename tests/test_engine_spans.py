@@ -20,13 +20,15 @@ from paddle_tpu.serving import FaultPlan, ServingEngine, TraceRecorder
 CFG = dict(vocab_size=512, hidden_size=64, num_layers=1, num_heads=2,
            max_seq_len=96, dropout=0.0)
 
-#: span -> the span it must open inside (the table of ISSUE 24)
+#: span -> the span it must open inside (the table of ISSUE 24; PR 38: a
+#: first token is read after the decode's dispatch, inside ``engine.decode``,
+#: and inside ``engine.prefill`` only where the engine never decodes)
 PARENT = {
     "engine.step": None,
     "engine.admit": "engine.step",
     "engine.prefill": "engine.step",
     "engine.prefill_dispatch": "engine.prefill",
-    "engine.first_token_sync": "engine.prefill",
+    "engine.first_token_sync": "engine.decode",
     "engine.handoff": "engine.step",
     "engine.decode": "engine.step",
     "engine.decode_dispatch": "engine.decode",
@@ -83,20 +85,23 @@ def spans(monkeypatch):
     return rec
 
 
-def _assert_well_formed(rec):
+def _assert_well_formed(rec, role="both"):
+    parent = dict(PARENT)
+    if role == "prefill":
+        parent["engine.first_token_sync"] = "engine.prefill"
     assert not rec.stack
     for e in rec.events:
         assert e["closed"], e
-        assert e["parent"] == PARENT[e["name"]], e
+        assert e["parent"] == parent[e["name"]], e
 
 
 @pytest.mark.parametrize("kw,absent", [
     (dict(), {"engine.handoff"}),
-    (dict(double_buffer=True), {"engine.handoff"}),
+    (dict(decode_block=2), {"engine.handoff"}),
     (dict(spec_k=2), {"engine.handoff"}),
     (dict(role="prefill"), {"engine.decode", "engine.decode_dispatch",
                             "engine.decode_sync"}),
-], ids=["both", "double_buffer", "spec", "prefill_role"])
+], ids=["both", "decode_block", "spec", "prefill_role"])
 def test_mixed_load_emits_the_spans_nested_as_documented(spans, kw, absent):
     """Between them the engine's modes emit all nine names; each opens
     inside its documented parent, carries its documented arguments, and a
@@ -113,7 +118,7 @@ def test_mixed_load_emits_the_spans_nested_as_documented(spans, kw, absent):
         eng.drain_handoffs()
         steps += 1
         assert steps < 200
-    _assert_well_formed(spans)
+    _assert_well_formed(spans, kw.get("role", "both"))
     assert {e["name"] for e in spans.events} == set(PARENT) - absent
     assert [e["args"] for e in spans.named("engine.step")] == \
         [{"step": i + 1} for i in range(steps)]
@@ -163,7 +168,7 @@ def test_an_injected_fault_closes_every_span(spans, phase, kw):
     eng.step()                                   # the faulted step
     faulted = spans.events[before:]
     assert plan.injected["raise"] == 1
-    _assert_well_formed(spans)
+    _assert_well_formed(spans, kw.get("role", "both"))
     span = {"verify": "engine.decode"}.get(phase, f"engine.{phase}")
     (ev,) = [e for e in faulted if e["name"] == span]
     if phase == "handoff":
@@ -180,7 +185,7 @@ def test_an_injected_fault_closes_every_span(spans, phase, kw):
     while eng.has_work:
         eng.step()
         eng.drain_handoffs()
-    _assert_well_formed(spans)
+    _assert_well_formed(spans, kw.get("role", "both"))
     assert eng.pool.pages_in_use == 0
 
 
@@ -209,15 +214,17 @@ def test_phase_times_and_trace_recorder_events_keep_their_source(spans):
     assert sum(eng.stats[f"{ph}_s"] for ph in ("admit", "prefill", "decode")
                ) <= eng.stats["step_wall_s"] + 1e-6
     # the syncs are inside their phases, and the recorder got no new names
-    assert 0 < eng.stats["decode_sync_s"] <= eng.stats["decode_s"]
-    assert 0 < eng.stats["prefill_sync_s"] <= eng.stats["prefill_s"]
+    assert 0 < (eng.stats["decode_sync_s"] + eng.stats["prefill_sync_s"]
+                ) <= eng.stats["decode_s"]
+    assert eng.stats["decode_sync_s"] > 0 and eng.stats["prefill_sync_s"] > 0
     assert not any(e["name"].startswith("engine.") for e in tracer.events)
 
 
 def test_waits_are_exact_under_an_injected_clock():
     """One slot, two requests of two chunks each, the clock moved by hand:
-    A waits 3 s for admission and 2 s more for its second chunk, B 6 s
-    (A holds the slot) and 2 s."""
+    A waits 3 s for admission and 2 s more for its second chunk, B 8 s
+    (A holds the slot until its one decode is read, the step after the
+    dispatch) and 2 s."""
     now = {"t": 0.0}
     eng = ServingEngine(_model(), max_slots=1, page_size=8, chunk_tokens=8,
                         clock=lambda: now["t"])
@@ -226,15 +233,18 @@ def test_waits_are_exact_under_an_injected_clock():
     now["t"] = 1.0
     eng.add_request(_prompt(rng, 12), 2)          # B, enqueued at 1
     seen = []
-    for t in (3.0, 5.0, 7.0, 9.0):
+    for t in (3.0, 5.0, 7.0, 9.0, 11.0):
         now["t"] = t
         eng.step()
         s = eng.stats
         seen.append((s["admissions"], s["queue_wait_s"], s["first_tokens"],
                      s["prefill_wait_s"]))
-    assert seen == [(1, 3.0, 0, 0.0), (1, 3.0, 1, 2.0),
-                    (2, 9.0, 1, 2.0), (2, 9.0, 2, 4.0)]
-    assert not eng.has_work
+    assert seen == [(1, 3.0, 0, 0.0), (1, 3.0, 1, 2.0), (1, 3.0, 1, 2.0),
+                    (2, 11.0, 1, 2.0), (2, 11.0, 2, 4.0)]
+    assert eng.has_work            # B's decode is in flight
+    now["t"] = 13.0
+    (fin,) = eng.step()
+    assert len(fin.tokens) == 2 and not eng.has_work
 
 
 def test_a_preempted_and_readmitted_request_is_counted_once():
@@ -284,6 +294,81 @@ def test_step_mix_and_attended_tokens_of_a_hand_counted_run():
            s["decode_calls_after_2plus_chunks"]]
     assert sum(mix) == s["decode_calls"] > 0 and mix[0] > 0 and mix[1] > 0
     assert s["decode_attended_tokens"] > s["decode_calls"]
+
+
+def _reads_and_dispatches(rec):
+    """Per step, in opening order, the names of the dispatch and read spans
+    (``engine.*_dispatch``, ``engine.*_sync``)."""
+    steps = []
+    for e in rec.events:
+        if e["name"] == "engine.step":
+            steps.append([])
+        elif e["name"].endswith(("_dispatch", "_sync")):
+            steps[-1].append(e["name"].split(".")[1])
+    return steps
+
+
+def test_a_step_dispatches_before_it_reads_and_reads_the_previous_decode(
+        spans):
+    """No pool pressure, several requests: within a step every dispatch
+    opens before the first read; decode k+1 is dispatched before decode k
+    is read, and before any first token of its own step is; every decode
+    but the busy period's first was dispatched ahead of a read."""
+    eng = ServingEngine(_model(), max_slots=4, page_size=8, chunk_tokens=8)
+    rng = np.random.RandomState(6)
+    for i, n in enumerate((3, 19, 8, 11)):
+        eng.add_request(_prompt(rng, n), 5 + i)
+    out = eng.run()
+    assert sorted(len(f.tokens) for f in out.values()) == [5, 6, 7, 8]
+    steps = _reads_and_dispatches(spans)
+    for names in steps:
+        reads = [i for i, n in enumerate(names) if n.endswith("_sync")]
+        if reads:
+            assert all(n.endswith("_sync") for n in names[reads[0]:]), names
+        assert names.count("decode_dispatch") <= 1
+        assert names.count("decode_sync") <= 1
+        if "first_token_sync" in names:        # it rides this step's decode
+            assert names.index("decode_dispatch") < \
+                names.index("first_token_sync")
+            if "decode_sync" in names:
+                assert names.index("decode_sync") < \
+                    names.index("first_token_sync")
+    # D1 D2 S1 D3 S2 ... Dn S(n-1) Sn: sync j follows dispatch j + 1
+    flat = [n for names in steps for n in names if n.startswith("decode_")]
+    n = eng.stats["decode_calls"]
+    assert flat == ["decode_dispatch"] + \
+        ["decode_dispatch", "decode_sync"] * (n - 1) + ["decode_sync"]
+    assert eng.stats["decode_ahead"] == n - 1 > 0
+    assert eng.stats["decode_sync_first"] == 0
+    assert eng._inflight is None and not eng._first_unread
+
+
+def test_a_preemption_of_a_lane_in_flight_retires_first_and_loses_no_token(
+        spans):
+    """The pool of ``test_engine_preempt_recompute_exact``: A's growth
+    preempts B while B's last decode is unread.  That decode is read first
+    (a dispatch that follows counts as ``decode_sync_first``), so B's
+    recompute prompt holds every token sampled for it: both requests emit
+    what they emit with room."""
+    model, rng = _model(), np.random.RandomState(51)
+    prompts = [_prompt(rng, 8), _prompt(rng, 16)]
+    roomy = ServingEngine(model, max_slots=2, page_size=8, chunk_tokens=16)
+    want = roomy.run(list(zip(prompts, (24, 16))))
+    assert roomy.stats["preemptions"] == roomy.stats["decode_sync_first"] == 0
+    eng = ServingEngine(model, max_slots=2, page_size=8, num_pages=7,
+                        chunk_tokens=16)
+    got = eng.run(list(zip(prompts, (24, 16))))
+    assert eng.stats["preemptions"] >= 1
+    assert eng.stats["decode_sync_first"] >= 1
+    for a, b in zip(sorted(want), sorted(got)):
+        np.testing.assert_array_equal(want[a].tokens, got[b].tokens)
+    # in the step that preempted, the read came before the decode's dispatch
+    assert any("decode_dispatch" in names and names.index("decode_sync")
+               < names.index("decode_dispatch")
+               for names in _reads_and_dispatches(spans)
+               if "decode_sync" in names)
+    s = eng.stats
+    assert s["decode_ahead"] + s["decode_sync_first"] < s["decode_calls"]
 
 
 def test_a_snapshot_without_the_new_counters_restores():

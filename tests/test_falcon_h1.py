@@ -237,12 +237,22 @@ def test_two_requests_in_one_batch_emit_what_each_emits_alone(model):
 
 
 @pytest.mark.parametrize("kw", [
-    dict(spec_k=2), dict(decode_block=2), dict(double_buffer=True),
+    dict(spec_k=2), dict(decode_block=2),
     dict(role="prefill"), dict(role="decode"), dict(kv_bits=8),
     dict(int8=True)], ids=lambda kw: "-".join(f"{k}" for k in kw))
 def test_what_recurrent_state_refuses_is_a_named_error(model, kw):
     with pytest.raises(MultiGroupUnsupported, match="recurrent state"):
         ServingEngine(model, **dict(ENGINE, **kw))
+
+
+def test_recurrent_state_dispatches_ahead_of_the_read(model):
+    """What was a refused mode is the step: the slab is advanced by the
+    programs in dispatch order and its host mirror at dispatch, so decode
+    N+1 goes out before N is read."""
+    eng, _ = _serve(model, _prompts(8, (20, 9)), (10, 10), check=True)
+    s = eng.stats
+    assert s["decode_ahead"] == s["decode_calls"] - 1 > 0
+    assert s["decode_sync_first"] == 0
 
 
 def test_snapshot_handoff_and_prefix_index_are_refused_for_state(model):

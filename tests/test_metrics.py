@@ -472,9 +472,11 @@ def test_engine_metrics_survive_snapshot_restore():
     _drive_mixed_load(eng, rng, n=3, cancel_one=False)
     for _ in range(4):
         eng.step()
+    snap = eng.snapshot()
+    # read AFTER the capture: it retires the decode in flight, which feeds
+    # the token histograms
     before = eng.metrics.scalars()
     assert before["serving_steps"] == 4
-    snap = eng.snapshot()
     # default-policy engines snapshot the trivial FCFS policy state
     # (v3) and restore across it without disturbance
     assert snap["scheduler"]["policy"] == {"name": "fcfs"}

@@ -370,9 +370,11 @@ def test_engine_wfq_snapshot_restores_virtual_counters():
     for _ in range(3):
         eng.step()
     assert eng.scheduler.n_waiting > 0          # genuinely mid-flight
+    snap = eng.snapshot()
+    # read AFTER the capture: it retires the decode in flight, which bills
+    # the tokens it reads
     vt_before = dict(eng.scheduler.policy.vt)
     assert any(v > 0 for v in vt_before.values())
-    snap = eng.snapshot()
     assert snap["version"] == SNAPSHOT_VERSION == 5
     assert snap["scheduler"]["policy"]["name"] == "wfq"
 
