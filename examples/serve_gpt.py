@@ -263,11 +263,10 @@ def main():
         return
     exporter = None
     if args.metrics_dir is not None and not cluster:
-        from paddle_tpu.serving import MetricsFileExporter, attach_profiler
+        from paddle_tpu.serving import MetricsFileExporter
 
         os.makedirs(args.metrics_dir, exist_ok=True)
         exporter = MetricsFileExporter(eng.metrics, args.metrics_dir)
-        attach_profiler(eng.tracer)   # host RecordEvent spans join the trace
     e0 = replicas[0]
     if cluster:
         print(f"cluster: {args.replicas} replicas "

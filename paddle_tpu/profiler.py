@@ -36,25 +36,6 @@ _state = {
     "order": [],          # first-end-time ordering (reference default sort)
 }
 
-# span sinks: callables (name, t0, t1) invoked at every RecordEvent exit
-# (perf_counter seconds), INDEPENDENT of whether table profiling is on.
-# serving/tracing.attach_profiler registers one so host spans land on the
-# engine's Chrome-trace timeline — the reference fork's "one profiler
-# state" unification, rebuilt as an observer list.
-_span_sinks: list = []
-
-
-def add_span_sink(sink) -> None:
-    """Register a ``(name, t0_s, t1_s)`` observer of RecordEvent spans."""
-    if sink not in _span_sinks:
-        _span_sinks.append(sink)
-
-
-def remove_span_sink(sink) -> None:
-    if sink in _span_sinks:
-        _span_sinks.remove(sink)
-
-
 def is_profiling() -> bool:
     return _state["enabled"]
 
@@ -82,8 +63,6 @@ class RecordEvent:
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         dt = (t1 - self._t0) * 1e3  # ms
-        for sink in _span_sinks:
-            sink(self.name, self._t0, t1)
         if self._ann is not None:
             self._ann.__exit__(*exc)
             self._ann = None

@@ -93,8 +93,7 @@ from .tenancy import (DEFAULT_TENANT, ClusterWFQState, FCFSPolicy,
 from .metrics import (Counter, Gauge, Histogram, MetricsFileExporter,
                       MetricsRegistry, SLOTracker, aggregate_scalars,
                       cluster_prometheus, merge_registries)
-from .tracing import (PID_ENGINE, PID_HOST, PID_REQUESTS, PID_ROUTER,
-                      TraceRecorder, attach_profiler, detach_profiler,
+from .tracing import (PID_ENGINE, PID_REQUESTS, PID_ROUTER, TraceRecorder,
                       flow_id, merge_traces, validate_trace)
 from .drafter import NGramDrafter
 from .flight_recorder import FlightRecorder
@@ -109,8 +108,7 @@ __all__ = ["KVPool", "PrefixIndex", "FCFSScheduler", "Request", "Admission",
            "FaultPlan", "InjectedFault", "snapshot_engine",
            "restore_engine", "handoff_state", "MetricsRegistry", "Counter",
            "Gauge", "Histogram", "MetricsFileExporter", "TraceRecorder",
-           "attach_profiler", "detach_profiler", "PID_ENGINE",
-           "PID_REQUESTS", "PID_HOST", "PID_ROUTER",
+           "PID_ENGINE", "PID_REQUESTS", "PID_ROUTER",
            "SchedulerPolicy", "FCFSPolicy", "WFQPolicy", "TenantConfig",
            "ClusterWFQState", "DEFAULT_TENANT", "ServingFrontend",
            "NGramDrafter", "Router", "make_cluster",

@@ -93,13 +93,24 @@ host at DISPATCH by a count that does not depend on the tokens.  Only an
 dropped at retirement and whose row landed in pages the lane still owned
 (``_retire_decode``).  A preemption whose victim has an unread decode
 retires first; ``spec_k > 0`` reads before it drafts, every step.
+
+What the host's turn is made of (PR 39), always on: ``stats["steps"]``;
+``dispatch_s``, the host seconds inside every program dispatch of a step
+(the ``engine.*_dispatch`` spans, filed as they close, like the phases);
+``device_drained``, steps whose first dispatch found the decode in flight
+already done (device order is dispatch order: the device had run dry and
+waited for this dispatch); ``slow_steps`` / ``slow_step_s``, steps of
+``SLOW_STEP_S`` or more, each described by a ``slow_step`` flight record
+when a recorder is attached (``_note_slow_step``).
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import os
+import resource
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -175,6 +186,48 @@ class MultiGroupUnsupported(NotImplementedError):
 #: feeds ``stats["<phase>_s"]``, the registry and the TraceRecorder
 _PHASE_OF_SPAN = {f"engine.{ph}": ph
                   for ph in ("admit", "prefill", "handoff", "decode")}
+#: the host's calls into the runtime: each span appends its duration to
+#: its entry of the step's ``_calls_s``; the dispatches' sum is
+#: ``stats["dispatch_s"]``, and all three feed the ``slow_step`` record
+_CALL_OF_SPAN = {
+    **{f"engine.{p}_dispatch": "dispatch" for p in
+       ("prefill", "decode", "carry", "cow", "state_reset", "ingest")},
+    "engine.decode_sync": "decode_sync",
+    "engine.first_token_sync": "first_token_sync"}
+_DISPATCH_SPANS = frozenset(n for n, c in _CALL_OF_SPAN.items()
+                            if c == "dispatch")
+#: a step of this many wall seconds or more is a stall (a step takes 10-40
+#: ms in every benchmark cell): counted, and recorded when a flight
+#: recorder is attached
+SLOW_STEP_S = 0.25
+#: the thread's own CPU time, context switches and faults where the
+#: platform keeps them per thread
+_RUSAGE_WHO = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+#: wall seconds of generation-2 garbage collections in this process so
+#: far, and the start of the one under way (``_time_full_gc``)
+_gc_full = {"s": 0.0, "t0": 0.0}
+
+
+def _time_full_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: times generation-2 collections, returns at
+    once for the others.  A step reads ``_gc_full["s"]`` at its start and,
+    when it turns out slow, at its end."""
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_full["t0"] = time.perf_counter()
+    else:
+        _gc_full["s"] += time.perf_counter() - _gc_full["t0"]
+
+
+gc.callbacks.append(_time_full_gc)
+
+
+def _no_calls() -> Dict[str, List[float]]:
+    """A step's ``_calls_s``: the durations of each kind of runtime call."""
+    return {k: [] for k in dict.fromkeys(_CALL_OF_SPAN.values())}
+
+
 #: decode dispatches by the prefill chunks dispatched before them in
 #: the same step (each chunk is device time in front of the decode)
 _DECODE_AFTER = ("decode_calls_after_0_chunks", "decode_calls_after_1_chunk",
@@ -185,15 +238,17 @@ class _Span:
     """The engine's one span site: a ``jax.profiler.TraceAnnotation``
     (the profiler's clock, next to the device trace; an inert TraceMe
     outside a profiler session) that also keeps its ``perf_counter``
-    start ``t0`` and duration ``dur`` for the stats, and files a phase's
-    pair under ``phases``.  Closes under an exception like the
-    ``finally`` it replaces, so an aborted phase still records the time
-    it burned."""
+    start ``t0`` and duration ``dur`` for the stats, files a phase's
+    pair under ``phases`` and a runtime call's duration under ``calls``.
+    Closes under an exception like the ``finally`` it replaces, so an
+    aborted phase still records the time it burned."""
 
-    __slots__ = ("_phases", "_phase", "_ann", "t0", "dur")
+    __slots__ = ("_phases", "_phase", "_calls", "_call", "_ann", "t0", "dur")
 
-    def __init__(self, phases: Dict[str, tuple], name: str, args: dict):
+    def __init__(self, phases: Dict[str, tuple],
+                 calls: Dict[str, List[float]], name: str, args: dict):
         self._phases, self._phase = phases, _PHASE_OF_SPAN.get(name)
+        self._calls, self._call = calls, _CALL_OF_SPAN.get(name)
         self._ann = jax.profiler.TraceAnnotation(name, **args)
 
     def __enter__(self) -> "_Span":
@@ -206,6 +261,8 @@ class _Span:
         self.dur = time.perf_counter() - self.t0
         if self._phase is not None:
             self._phases[self._phase] = (self.t0, self.dur)
+        if self._call is not None:
+            self._calls[self._call].append(self.dur)
 
 
 def _next_pow2(n: int) -> int:
@@ -577,6 +634,13 @@ class ServingEngine:
         self._chunks_this_step = 0
         self._budget_chunks = 1
         self._phase_s: Dict[str, tuple] = {}
+        self._calls_s: Dict[str, List[float]] = _no_calls()
+        # the step's first dispatch is still to come (``_check_drained``),
+        # and what the step found of the decode in flight: at that
+        # dispatch, and when its sync began (None: nothing in flight)
+        self._drain_unchecked = False
+        self._drained: Optional[bool] = None
+        self._ready_at_sync: Optional[bool] = None
         self._slots: List[Optional[_Slot]] = [None] * max_slots
         # the carry tokens live on the device (``_carry``: what the next
         # decode embeds, lane by lane); ``_tok`` mirrors them as of the
@@ -618,6 +682,12 @@ class ServingEngine:
                       "prefix_hit_tokens": 0, "prompt_tokens": 0,
                       "pages_in_use": 0, "queue_depth": 0,
                       "step_wall_s": 0.0, "last_step_s": 0.0,
+                      # the host's turn (PR 39): steps; host seconds inside
+                      # program dispatches; steps whose first dispatch found
+                      # the decode in flight done; steps of SLOW_STEP_S or
+                      # more and their wall seconds
+                      "steps": 0, "dispatch_s": 0.0, "device_drained": 0,
+                      "slow_steps": 0, "slow_step_s": 0.0,
                       # per-phase wall time (r11): cumulative + last-step,
                       # so admit/prefill/decode no longer conflate into
                       # one step_wall_s bucket
@@ -1322,6 +1392,10 @@ class ServingEngine:
             "decode_sync_first": c("serving_decode_sync_first",
                                    "decode dispatches that had to retire "
                                    "the previous decode first"),
+            "slow_steps": c("serving_slow_steps",
+                            f"engine steps of {SLOW_STEP_S} s or more"),
+            "slow_step_s": c("serving_slow_step_s",
+                             "wall seconds of those steps"),
             "preemptions": c("serving_preemptions",
                              "slots evicted for recompute"),
             "recompute": c("serving_recompute_tokens",
@@ -1697,8 +1771,10 @@ class ServingEngine:
             src, _ = adm.cow
             # the first owned page inherits the partial tail's K/V; the
             # source page drops the reference the scheduler pinned for us
-            self.pool.buffers = self._cow_fn(
-                self.pool.buffers, jnp.int32(src), jnp.int32(adm.pages[0]))
+            with self._span("engine.cow_dispatch"):
+                self.pool.buffers = self._cow_fn(
+                    self.pool.buffers, jnp.int32(src),
+                    jnp.int32(adm.pages[0]))
             self.pool.release([src])
         if req.seq is None:
             # first admission fixes the request's age; preemption keeps it
@@ -1715,8 +1791,9 @@ class ServingEngine:
             # whatever the slot's last tenant left is zeroed before this
             # one's first chunk: a first admission and a recompute after
             # preemption alike (nothing of the state was saved)
-            self.slab.buffers = self._state_reset_fn(self.slab.buffers,
-                                                     jnp.int32(idx))
+            with self._span("engine.state_reset_dispatch"):
+                self.slab.buffers = self._state_reset_fn(self.slab.buffers,
+                                                         jnp.int32(idx))
             self.slab.release(idx)
             self.stats["state_resets"] += 1
         self.stats["prefix_hit_tokens"] += adm.matched
@@ -1840,8 +1917,9 @@ class ServingEngine:
                     else:
                         nfull = st.base_len // self.page_size
                         self.pool.prefix.insert(work, st.pages[:nfull])
-                self._carry = self._carry_put_fn(self._carry,
-                                                 jnp.int32(idx), tok)
+                with self._span("engine.carry_dispatch"):
+                    self._carry = self._carry_put_fn(self._carry,
+                                                     jnp.int32(idx), tok)
                 self._len[idx] = st.base_len
                 st.unread = 1
                 self._first_unread.append((idx, st, tok, self._moe_mark()))
@@ -2134,7 +2212,8 @@ class ServingEngine:
             return False
         req = rec["request"]
         base_len = rec["base_len"]
-        self.pool.ingest_pages(rec["payload"], pages)
+        with self._span("engine.ingest_dispatch"):
+            self.pool.ingest_pages(rec["payload"], pages)
         if req.seq is None:      # carried from the prefill replica's
             self._admit_seq += 1  # admission normally; None only if the
             req.seq = self._admit_seq   # sender predates admission seqs
@@ -2152,8 +2231,9 @@ class ServingEngine:
         # carry token is the last sampled one, the device length is the
         # work-prompt length whose K/V the pages hold
         self._tok[slot] = req.generated[-1]
-        self._carry = self._carry_put_fn(self._carry, jnp.int32(slot),
-                                         jnp.int32(req.generated[-1]))
+        with self._span("engine.carry_dispatch"):
+            self._carry = self._carry_put_fn(self._carry, jnp.int32(slot),
+                                             jnp.int32(req.generated[-1]))
         self._len[slot] = base_len
         # adopt the full prompt pages into THIS pool's prefix index —
         # same insert (and same windowed refusal) as local prefill; the
@@ -2204,6 +2284,10 @@ class ServingEngine:
         # time it burned before aborting.  Carried on the instance (not a
         # parameter) so _run_step keeps its r10 signature.
         phase = self._phase_s = {}
+        calls = self._calls_s = _no_calls()
+        self._drain_unchecked = True
+        self._drained = self._ready_at_sync = None
+        ru0, gc0 = resource.getrusage(_RUSAGE_WHO), _gc_full["s"]
         try:
             self._run_step(finished)
         except InjectedFault as e:
@@ -2245,6 +2329,13 @@ class ServingEngine:
             v = start_dur[1] if start_dur is not None else 0.0
             self.stats[f"{ph}_s"] += v
             self.stats[f"last_{ph}_s"] = v
+        self.stats["steps"] += 1
+        self.stats["dispatch_s"] += sum(calls["dispatch"])
+        if dt >= SLOW_STEP_S:
+            self.stats["slow_steps"] += 1
+            self.stats["slow_step_s"] += dt
+            if self.flight is not None and self.faults is None:
+                self._note_slow_step(dt, phase, calls, ru0, gc0)
         if self.tracer is not None:
             for ph, (start, dur) in phase.items():
                 self.tracer.complete(ph, start, dur, self._pid_eng, 0,
@@ -2252,6 +2343,35 @@ class ServingEngine:
         if self.metrics is not None:
             self._sync_metrics(dt, phase)
         return finished
+
+    def _note_slow_step(self, dt: float, phase: Dict[str, tuple],
+                        calls: Dict[str, List[float]], ru0, gc0: float
+                        ) -> None:
+        """The ``slow_step`` flight record: where a stalled step's seconds
+        went.  Its phases; the host's seconds in dispatches and in the two
+        waits for device values, each split into its calls where there
+        were several; whether the decode in flight was done at the step's
+        first dispatch and when its sync began; and what the thread went
+        through meanwhile: generation-2 garbage collection, its own CPU
+        seconds, voluntary and involuntary context switches, major page
+        faults.  Not under a FaultPlan, whose replays compare their black
+        boxes byte for byte: a stall is wall time."""
+        ru1 = resource.getrusage(_RUSAGE_WHO)
+        rec = dict(step_s=dt, drained=self._drained,
+                   ready_at_sync=self._ready_at_sync,
+                   gc_s=_gc_full["s"] - gc0,
+                   cpu_s=(ru1.ru_utime + ru1.ru_stime
+                          - ru0.ru_utime - ru0.ru_stime),
+                   voluntary_switches=ru1.ru_nvcsw - ru0.ru_nvcsw,
+                   involuntary_switches=ru1.ru_nivcsw - ru0.ru_nivcsw,
+                   major_faults=ru1.ru_majflt - ru0.ru_majflt)
+        for ph in ("admit", "prefill", "decode"):
+            rec[f"{ph}_s"] = phase[ph][1] if ph in phase else 0.0
+        for key, durs in calls.items():
+            rec[f"{key}_s"] = sum(durs)
+            if len(durs) > 1:
+                rec[f"{key}_calls_s"] = list(durs)
+        self.flight.record("slow_step", self._step_idx, **rec)
 
     def _sync_metrics(self, dt: float, phase: Dict[str, tuple]) -> None:
         """End-of-step registry feed: monotonic counters sync from the
@@ -2266,6 +2386,8 @@ class ServingEngine:
                                ("decode_calls", "decode_calls"),
                                ("decode_ahead", "decode_ahead"),
                                ("decode_sync_first", "decode_sync_first"),
+                               ("slow_steps", "slow_steps"),
+                               ("slow_step_s", "slow_step_s"),
                                ("preemptions", "preemptions"),
                                ("recompute_tokens", "recompute"),
                                ("prefix_hit_tokens", "prefix_hit"),
@@ -2312,7 +2434,19 @@ class ServingEngine:
             self._slo.sync(self._now())
 
     def _span(self, name: str, **args) -> _Span:
-        return _Span(self._phase_s, name, args)
+        if self._drain_unchecked and name in _DISPATCH_SPANS:
+            self._check_drained()
+        return _Span(self._phase_s, self._calls_s, name, args)
+
+    def _check_drained(self) -> None:
+        """Just before the step's first dispatch: is the decode in flight
+        done already?  The device runs programs in dispatch order, so a
+        finished decode N means it ran out of work and waits for this
+        dispatch.  A step with nothing in flight is neither case."""
+        self._drain_unchecked = False
+        if self._inflight is not None:
+            self._drained = self._inflight[2].is_ready()
+            self.stats["device_drained"] += self._drained
 
     def _run_step(self, finished: List[FinishedRequest]) -> None:
         with self._span("engine.admit"):
@@ -2503,6 +2637,7 @@ class ServingEngine:
         entries, remaining, toks_all, t_c, mark = self._inflight
         self._inflight = None
         self._retired_early = self._retired_early or early
+        self._ready_at_sync = toks_all.is_ready()
         with self._span("engine.decode_sync") as sp:
             toks_all = np.asarray(jax.block_until_ready(toks_all))
         sync_s = sp.dur

@@ -7,9 +7,8 @@ Chrome trace-event recorder (schema-validated: every event carries
 name/ph/ts/pid/tid and B/E spans balance per track), the engine
 integration end-to-end (run(metrics_dir=...) producing all three
 artifacts with terminal counters exactly matching FinishedRequests),
-metrics surviving snapshot/restore, the profiler RecordEvent bridge, and
-the no-new-imports guard keeping ``paddle_tpu.serving`` on
-jax/numpy/stdlib only.
+metrics surviving snapshot/restore, and the no-new-imports guard
+keeping ``paddle_tpu.serving`` on jax/numpy/stdlib only.
 """
 
 import json
@@ -288,32 +287,6 @@ def test_trace_recorder_balance_and_schema(tmp_path):
     b = {(e["name"], e["ph"]): e["ts"] for e in evs if e["ph"] in "BE"}
     assert b[("outer", "B")] <= b[("inner", "B")]
     assert b[("inner", "E")] <= b[("outer", "E")]
-
-
-def test_profiler_record_event_bridge():
-    from paddle_tpu import profiler
-    from paddle_tpu.serving import PID_HOST, attach_profiler, detach_profiler
-
-    tr = TraceRecorder()
-    sink = attach_profiler(tr)
-    try:
-        # idempotent per tracer: a re-attach returns the SAME sink and
-        # must not double every span
-        assert attach_profiler(tr) is sink
-        with profiler.RecordEvent("host_span"):
-            pass
-    finally:
-        detach_profiler(sink)
-    spans = [e for e in tr.events
-             if e["ph"] == "X" and e["name"] == "host_span"]
-    assert len(spans) == 1 and spans[0]["pid"] == PID_HOST
-    # detached: no more forwarding, and the tracer can be re-bridged
-    with profiler.RecordEvent("after_detach"):
-        pass
-    assert not any(e["name"] == "after_detach" for e in tr.events)
-    sink2 = attach_profiler(tr)
-    assert sink2 is not sink
-    detach_profiler(sink2)
 
 
 # ---------------------------------------------------------------------------
